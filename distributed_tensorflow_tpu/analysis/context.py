@@ -30,8 +30,8 @@ __all__ = ["JitSite", "JitRegistry", "mesh_axes_for", "DEFAULT_MESH_AXES",
            "JIT_WRAPPERS", "TRACED_WRAPPERS"]
 
 # Canonical dotted names (post alias expansion) that compile their operand.
-# Bare "shard_map" covers relative imports (``from ._compat import
-# shard_map``) — relative modules have no canonical prefix to expand.
+# Bare "shard_map" covers a relative re-export — relative modules have no
+# canonical prefix to expand.
 JIT_WRAPPERS: Set[str] = {
     "jax.jit",
     "jax.pjit",
@@ -40,7 +40,6 @@ JIT_WRAPPERS: Set[str] = {
     "jax.experimental.shard_map.shard_map",
     "jax.shard_map",
     "shard_map",
-    "distributed_tensorflow_tpu.parallel._compat.shard_map",
 }
 # Wrappers that trace but take axis bindings rather than static/donate args.
 TRACED_WRAPPERS: Set[str] = JIT_WRAPPERS | {"jax.vmap", "jax.checkpoint",

@@ -142,9 +142,11 @@ class Bert:
 
     def __init__(self, config: BertConfig, mesh=None):
         self.config = config
-        # Mesh is only needed for sequence parallelism: with ``seq_axis``
-        # set and a mesh attached, attention runs as a partial-manual ring
-        # over that axis inside the otherwise-auto pjit program.
+        # The mesh the params/batch are sharded over.  With ``seq_axis``
+        # set, attention runs as a partial-manual ring over that axis
+        # inside the otherwise-auto pjit program; without it, on any mesh
+        # of more than one device, the flash kernel runs under shard_map
+        # (XLA cannot partition a Mosaic kernel by itself).
         self.mesh = mesh
 
     # -- init -------------------------------------------------------------
@@ -245,7 +247,7 @@ class Bert:
         elif attn_lib.resolve_use_flash(c.use_flash, x.shape[1]):
             from ..ops.pallas import flash_attention
             attention_fn = lambda q, k, v, mask=None: flash_attention(
-                q, k, v, kv_valid=valid)
+                q, k, v, kv_valid=valid, mesh=self.mesh)
         else:
             attention_fn = attn_lib.dot_product_attention
         return attn_lib.attention_core(

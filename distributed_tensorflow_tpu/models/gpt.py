@@ -201,7 +201,11 @@ class GPT:
 
     def __init__(self, config: GPTConfig, mesh=None):
         self.config = config
-        self.mesh = mesh  # only needed for the ring-attention (SP) path
+        # The mesh the params/batch are sharded over.  Needed by the paths
+        # that run manual collectives or kernels: ring attention (seq_axis),
+        # the pipeline, and — on any mesh of more than one device — the
+        # flash kernel, which XLA cannot partition by itself.
+        self.mesh = mesh
 
     # -- init -------------------------------------------------------------
     def init(self, key) -> Dict[str, Any]:
@@ -355,7 +359,8 @@ class GPT:
             # GQA configs run natively: the kernel maps kv blocks by
             # q_head // group, so no broadcast materialises
             from ..ops.pallas.flash_attention import make_flash_attention_fn
-            attention_fn = make_flash_attention_fn(causal=True)
+            attention_fn = make_flash_attention_fn(causal=True,
+                                                   mesh=self.mesh)
         else:
             attention_fn = attn_lib.dot_product_attention
         return attn_lib.attention_core(
@@ -1315,7 +1320,7 @@ class GPT:
         # additively.
         if kv_valid is None and attn_lib.resolve_use_flash(c.use_flash, s):
             from ..ops.pallas.flash_attention import make_flash_attention_fn
-            flash_fn = make_flash_attention_fn(causal=True)
+            flash_fn = make_flash_attention_fn(causal=True, mesh=self.mesh)
 
             def block_attn(q, k_blk, v_blk, kv, i):
                 del kv, i

@@ -1,7 +1,7 @@
 """Perf ledger: an append-only, versioned JSONL history of bench rows.
 
-The repo's performance record used to be loose ``BENCH_r0x.json`` driver
-blobs compared by filename convention.  The ledger replaces that with a
+The repo's performance record used to be loose per-round JSON blobs
+compared by filename convention.  The ledger replaces that with a
 durable, queryable file: every ``bench.py`` config appends exactly one
 schema-checked row carrying its identity (``run_id``, ``git_sha``, the
 backend/mesh fingerprint), the config knobs it ran under, the measured

@@ -452,18 +452,43 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _mesh_split(mesh, q_shape, k_shape):
+    """(mesh axes for the batch dim, mesh axes for the heads dim) of a
+    kernel call split over ``mesh``: batch over the data-like axes, heads
+    over ``tensor`` — the layout ``partition_rules`` gives activations.  An
+    axis group that does not divide its dimension is left out (every device
+    then computes that dimension whole, which is what the partitioner would
+    have made of a replicated operand)."""
+    def fit(axes, *dims):
+        axes = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+        size = math.prod(mesh.shape[a] for a in axes)
+        return axes if axes and all(d % size == 0 for d in dims) else None
+    return (fit(("data", "fsdp"), q_shape[0]),
+            fit(("tensor",), q_shape[2], k_shape[2]))
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     kv_valid: Optional[jnp.ndarray] = None,
                     causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 1024,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    mesh=None) -> jnp.ndarray:
     """Fused attention.  q: [batch, seq, heads, head_dim] (the
     framework-wide head layout, see ops.attention); k, v:
     [batch, seq_k, kv_heads, head_dim] where heads % kv_heads == 0 —
     GQA/MQA kv heads are shared across their query group by block-index
     mapping, never materialised; kv_valid: optional [batch, seq_k] mask,
     1 = real key.  Returns [batch, seq, heads, head_dim].
+
+    ``mesh``: the mesh the operands live on (the one the model was built
+    with).  XLA cannot partition a Mosaic kernel by itself — a plain
+    ``jit`` over more than one device refuses it — so on such a mesh the
+    call runs under ``jax.shard_map``: batch split over ``data``/``fsdp``,
+    heads over ``tensor``, each device running the kernel on its own
+    block.  Attention mixes neither batch rows nor heads, so no
+    collective is needed.  Traced inside an enclosing ``shard_map`` (the
+    pipeline's) only the axes that are still automatic there are taken.
 
     Off-TPU the kernel runs in Pallas interpret mode, so CPU tests cover the
     identical kernel code.
@@ -481,6 +506,25 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     else:
         valid = kv_valid.astype(jnp.float32)
 
+    if mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+        enclosing = jax.sharding.get_abstract_mesh()
+        if enclosing.manual_axes:
+            mesh = enclosing    # nested: shard_map wants the context's mesh
+        auto = frozenset(mesh.axis_names) - frozenset(enclosing.manual_axes)
+        batch, heads = _mesh_split(mesh, q.shape, k.shape)
+        qkv_spec = P(batch, None, heads, None)
+
+        def local(q, k, v, valid):
+            return flash_attention(q, k, v, kv_valid=valid, causal=causal,
+                                   scale=scale, block_q=block_q,
+                                   block_k=block_k, interpret=interpret)
+
+        return jax.shard_map(
+            local, mesh=mesh, axis_names=auto, check_vma=False,
+            in_specs=(qkv_spec, qkv_spec, qkv_spec, P(batch, None)),
+            out_specs=qkv_spec)(q, k, v, valid)
+
     # [b, s, h, d] -> [b, h, s, d] for per-(batch, head) grid blocking.
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
@@ -491,13 +535,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def make_flash_attention_fn(causal: bool = False, block_q: int = 512,
-                            block_k: int = 1024):
+                            block_k: int = 1024, mesh=None):
     """Adapter matching the ``attention_fn(q, k, v, mask=...)`` slot of
     ``ops.attention.attention_core``.
 
     Accepts ``mask=None`` or a *padding* mask shaped [b, 1, 1, s_k] (the
     output of ``ops.attention.padding_mask``); arbitrary additive masks
-    don't map onto the fused kernel and raise.
+    don't map onto the fused kernel and raise.  ``mesh``: see
+    ``flash_attention``.
     """
     def fn(q, k, v, mask=None, scale=None):
         kv_valid = None
@@ -508,6 +553,7 @@ def make_flash_attention_fn(causal: bool = False, block_q: int = 512,
                     f"[b,1,1,s]; got {mask.shape}")
             kv_valid = (mask[:, 0, 0, :] >= 0.0)
         return flash_attention(q, k, v, kv_valid=kv_valid, causal=causal,
-                               scale=scale, block_q=block_q, block_k=block_k)
+                               scale=scale, block_q=block_q, block_k=block_k,
+                               mesh=mesh)
     fn.supports_gqa = True   # attention_core: skip the kv-head broadcast
     return fn

@@ -28,7 +28,7 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 from ..ops import losses as loss_lib
 from ..ops import metrics as metric_lib

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_apply", "stack_pipeline_params", "pipeline_rules_spec",
            "pipeline_value_and_grad"]

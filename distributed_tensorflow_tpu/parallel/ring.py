@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded"]
 

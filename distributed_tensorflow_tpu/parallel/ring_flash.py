@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 from ..ops.pallas.common import use_interpret as _use_interpret
 from ..ops.pallas.flash_attention import _flash_backward, _flash_forward

@@ -1,10 +1,18 @@
 """CRC32-C (Castagnoli) + TFRecord masking.
 
 Needed for the TensorBoard event-file record framing (each record's length
-and payload carry a masked crc32c).  The native slice-by-8 implementation
-(``native/dttpu_native.cpp``, byte-identical output) is preferred for bulk
-record IO; the table-driven pure-Python version below is the always-available
-fallback and the cross-check oracle in tests.
+and payload carry a masked crc32c), the checkpoint leaf checksums and the
+KV-page wire.  The native slice-by-8 implementation
+(``native/dttpu_native.cpp``, byte-identical output) does the bulk work; the
+table-driven pure-Python version below is the always-available fallback and
+the cross-check oracle in tests.
+
+When the native library is built: never from an import, and not for a small
+record — but a payload of ``_BUILD_NATIVE_AT`` bytes or more (a checkpoint
+leaf) is worth the one-time ~3 s ``make``.  The Python byte loop runs at a
+few MB/s: on a fresh checkout (no ``.so``; it is not committed) it turned
+one save + verified restore of a 1.5 GB GPT-2-small TrainState into ~7
+minutes on the TPU host (PERF.md, PR 22).
 """
 from __future__ import annotations
 
@@ -19,28 +27,36 @@ for _i in range(256):
     _TABLE.append(_c)
 
 
-def crc32c(data: bytes, crc: int = 0) -> int:
+def py_crc32c(data: bytes, crc: int = 0) -> int:
     crc ^= 0xFFFFFFFF
     for b in data:
         crc = _TABLE[(crc ^ b) & 0xFF] ^ (crc >> 8)
     return crc ^ 0xFFFFFFFF
 
 
-def masked_crc32c(data: bytes) -> int:
+def py_masked_crc32c(data: bytes) -> int:
     """The TFRecord mask: rotate right 15 and add a constant."""
-    crc = crc32c(data)
+    crc = py_crc32c(data)
     return (((crc >> 15) | (crc << 17)) + 0xA282EAD8) & 0xFFFFFFFF
 
 
-py_crc32c = crc32c
-py_masked_crc32c = masked_crc32c
+_BUILD_NATIVE_AT = 1 << 20
 
-try:  # prefer the native implementation when it is ALREADY built — never
-    # run a compiler from an import path (build=False).
-    from ..utils import native as _native
 
-    if _native.native_available(build=False):
-        crc32c = _native.crc32c
-        masked_crc32c = _native.masked_crc32c
-except Exception:  # pragma: no cover — fallback stays bound
-    pass
+def _native_for(nbytes: int):
+    """utils.native when its library is loaded — or, for a bulk payload,
+    can be built now; else None (``DTTPU_NO_NATIVE``, no toolchain)."""
+    from ..utils import native
+    ok = native.native_available(build=nbytes >= _BUILD_NATIVE_AT)
+    return native if ok else None
+
+
+def crc32c(data: bytes, crc: int = 0) -> int:
+    native = _native_for(len(data))
+    return native.crc32c(data, crc) if native else py_crc32c(data, crc)
+
+
+def masked_crc32c(data: bytes) -> int:
+    native = _native_for(len(data))
+    return (native.masked_crc32c(data) if native
+            else py_masked_crc32c(data))

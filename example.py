@@ -93,6 +93,8 @@ def main() -> int:
     if FLAGS.device:
         import jax
         jax.config.update("jax_platforms", FLAGS.device)
+    from distributed_tensorflow_tpu.utils import enable_compile_cache
+    enable_compile_cache()
 
     # Cluster bootstrap (replaces ClusterSpec/Server/replica_device_setter,
     # ref :108-143).  CLI flags overlay the environment so

@@ -59,6 +59,8 @@ def main() -> int:
     if FLAGS.device:
         import jax
         jax.config.update("jax_platforms", FLAGS.device)
+    from distributed_tensorflow_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -81,7 +83,7 @@ def main() -> int:
                         mlm_predictions_per_seq=FLAGS.mlm_predictions_per_seq,
                         fused_layernorm=FLAGS.fused_layernorm,
                         remat=FLAGS.remat, remat_policy=FLAGS.remat_policy)
-    model = Bert(config)
+    model = Bert(config, mesh=mesh)
     params = model.init(jax.random.PRNGKey(FLAGS.seed))
     # fine-tune head: fresh [hidden, classes] on top of the pooler
     params["classifier"] = {

@@ -59,6 +59,8 @@ def main() -> int:
     if FLAGS.device:
         import jax
         jax.config.update("jax_platforms", FLAGS.device)
+    from distributed_tensorflow_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -108,7 +110,7 @@ def main() -> int:
         config = GPTConfig(**dims)
     else:
         raise SystemExit(f"--family={FLAGS.family!r}: gpt2|llama")
-    model = GPT(config, mesh=mesh if pp > 1 else None)
+    model = GPT(config, mesh=mesh)
     optimizer = optim.with_ema(optim.adamw(3e-3), decay=0.99)
 
     params = model.init(jax.random.PRNGKey(FLAGS.seed))

@@ -47,6 +47,8 @@ def main() -> int:
     if FLAGS.device:
         import jax
         jax.config.update("jax_platforms", FLAGS.device)
+    from distributed_tensorflow_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -37,6 +37,8 @@ def main() -> int:
     if FLAGS.device:
         import jax
         jax.config.update("jax_platforms", FLAGS.device)
+    from distributed_tensorflow_tpu.utils import enable_compile_cache
+    enable_compile_cache()
 
     from distributed_tensorflow_tpu.parallel import cluster
     cluster.initialize()

@@ -1,17 +1,18 @@
 """Decode operating-point ladder: tokens/s/chip across batch x length.
 
-VERDICT r4 item 4: one decode number (7,017 tok/s at batch 64 / seq 256)
-says nothing about where it sits on the throughput curve.  This sweep
+One decode number (7,017 tok/s at batch 64 / seq 256) says nothing about
+where it sits on the throughput curve.  This sweep
 measures greedy KV-cache generate on the gpt bench model over a
 batch ladder at two sequence lengths, printing a table plus one JSON
 line per cell — so the record shows the achievable ceiling (decode is
 HBM-bandwidth-bound: throughput should rise with batch until the cache
 traffic saturates, then flatten).
 
-Run on TPU (queued in tpu_followups.sh):  python scripts/decode_ladder.py
+Run on TPU:        python scripts/decode_ladder.py
 Full-int8 cells (int8 weights + int8 KV cache — the serving ceiling):
                    python scripts/decode_ladder.py int8
-CPU wiring check:  DTTPU_ABLATION_SMOKE=1 python scripts/decode_ladder.py
+CPU wiring check:  same commands under
+                   JAX_PLATFORMS=cpu DTTPU_ABLATION_SMOKE=1
 """
 from __future__ import annotations
 
@@ -28,9 +29,6 @@ SMOKE = os.environ.get("DTTPU_ABLATION_SMOKE", "").lower() \
 
 
 def main() -> int:
-    if SMOKE:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import dataclasses
 
     import jax

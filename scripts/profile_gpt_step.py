@@ -41,7 +41,7 @@ def build(which):
                             num_heads=12, intermediate_size=3072,
                             max_position=256, dtype=jnp.bfloat16,
                             dropout_rate=0.0, remat=True))
-        model = GPT(config)
+        model = GPT(config, mesh=mesh)
         loss_fn = model.lm_loss_fn()
         b, s = (4, 64) if SMOKE else (48, 256)
         tokens = rng.integers(0, config.vocab_size,
@@ -55,7 +55,7 @@ def build(which):
                              dropout_rate=0.0, remat=True) if SMOKE else
                   BertConfig(max_position=128, dtype=jnp.bfloat16,
                              dropout_rate=0.0, remat=True))
-        model = Bert(config)
+        model = Bert(config, mesh=mesh)
         loss_fn = model.mlm_loss_fn()
         b, s = (4, 64) if SMOKE else (64, 128)
         ids = rng.integers(0, config.vocab_size, (b, s)).astype(np.int32)

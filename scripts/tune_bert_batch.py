@@ -36,7 +36,7 @@ def main():
     for remat in (False, True):
         config = BertConfig(max_position=seq, dtype=jnp.bfloat16,
                             remat=remat)
-        model = Bert(config)
+        model = Bert(config, mesh=mesh)
         params_host = jax.device_get(model.init(jax.random.PRNGKey(0)))
         optimizer = optim.adamw(1e-4)
         step = train.make_custom_train_step(model.mlm_loss_fn(), optimizer,

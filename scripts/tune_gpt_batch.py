@@ -44,7 +44,7 @@ def main():
                            num_heads=12, intermediate_size=3072,
                            max_position=seq, dtype=jnp.bfloat16,
                            dropout_rate=0.0, remat=remat)
-        model = GPT(config)
+        model = GPT(config, mesh=mesh)
         # host copy: the donated train-step state aliases the live params
         # buffers, so each rung rebuilds device state from host
         params_host = jax.device_get(model.init(jax.random.PRNGKey(0)))

@@ -25,7 +25,6 @@ from distributed_tensorflow_tpu import analysis
 from distributed_tensorflow_tpu.analysis import graph as graph_lib
 from distributed_tensorflow_tpu.analysis import spmd as spmd_lib
 from distributed_tensorflow_tpu.analysis import spmd_rules
-from distributed_tensorflow_tpu.parallel import _compat
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -44,10 +43,10 @@ def mesh():
 
 
 def sm(body, mesh, in_specs, out_specs):
-    return _compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=frozenset({"data"}),
-                             check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset({"data"}),
+                         check_vma=False)
 
 
 def run_registry(reg):

@@ -223,7 +223,7 @@ def test_two_process_ragged_eval_matches_single_process(tmp_path):
     batches) run as 2 REAL processes over a 4-device mesh equals the
     1-process means: the tail is padded with a validity mask and fed
     through the masked eval step instead of being dropped
-    (models/sequential.py _evaluate_batches; VERDICT r4 item 5)."""
+    (models/sequential.py _evaluate_batches)."""
     script = tmp_path / "ragged_eval.py"
     script.write_text(textwrap.dedent(f"""
         import json, sys

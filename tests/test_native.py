@@ -24,6 +24,32 @@ def test_crc32c_matches_python_oracle():
         assert native.masked_crc32c(data) == py_masked_crc32c(data)
 
 
+def test_summary_crc_builds_native_only_for_bulk_payloads(monkeypatch):
+    """summary.crc32c never runs a compiler for a small record, but asks
+    for the one-time build when handed a checkpoint-leaf-sized payload
+    (the Python byte loop is a few MB/s); either way the value is the
+    oracle's."""
+    import importlib
+    crc_mod = importlib.import_module(
+        "distributed_tensorflow_tpu.summary.crc32c")
+    asked = []
+    real = native.native_available
+
+    def spy(build=True):
+        asked.append(build)
+        return real(build=build)
+
+    monkeypatch.setattr(native, "native_available", spy)
+    small = bytes(range(256)) * 4
+    assert crc_mod.masked_crc32c(small) == py_masked_crc32c(small)
+    assert asked == [False]
+    bulk = small * (crc_mod._BUILD_NATIVE_AT // len(small))
+    assert crc_mod.crc32c(bulk[:4096]) == py_crc32c(bulk[:4096])
+    got = crc_mod.masked_crc32c(bulk)
+    assert asked == [False, False, True]
+    assert got == native.masked_crc32c(bulk)     # built: native answers
+
+
 def test_crc32c_known_vector():
     # RFC 3720 test vector: crc32c of 32 zero bytes.
     assert native.crc32c(b"\x00" * 32) == 0x8A9136AA

@@ -244,6 +244,69 @@ class TestFlashGQA:
                                    atol=1e-5, rtol=1e-5)
 
 
+class TestFlashOnMesh:
+    """``mesh=``: the kernel call under shard_map (batch over data/fsdp,
+    heads over tensor) — what lets it survive a multi-device ``jit``, where
+    XLA refuses to partition a Mosaic kernel (tests/test_tpu_compile.py
+    compiles the same call for the real chip).  Interpret mode partitions
+    happily, so here only the VALUES are at stake: forward, gradients and
+    output placement against the unsharded kernel."""
+
+    @pytest.mark.parametrize("axes,batch,kv_heads", [
+        ({"data": 2, "fsdp": 2}, 4, 4),
+        ({"data": 2, "tensor": 2}, 4, 2),        # GQA, kv heads split too
+        ({"data": 2, "fsdp": 2}, 3, 4),          # batch the mesh can't split
+    ])
+    def test_matches_unsharded_kernel(self, axes, batch, kv_heads):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from distributed_tensorflow_tpu import parallel
+        mesh = parallel.make_mesh(axes, devices=jax.devices()[:4])
+        q, k, v = _qkv(jax.random.PRNGKey(7), b=batch)
+        k, v = k[:, :, :kv_heads], v[:, :, :kv_heads]
+        valid = jnp.ones((batch, 64), jnp.int32).at[:, -5:].set(0)
+
+        def loss(q, k, v, mesh):
+            out = flash_attention(q, k, v, kv_valid=valid, causal=True,
+                                  block_q=16, block_k=16, mesh=mesh)
+            return jnp.sum(out ** 2), out
+
+        (_, want), want_g = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v, None)
+        shards = parallel.data_shards(mesh)
+        spec = P(tuple(a for a in ("data", "fsdp") if a in axes)
+                 if batch % shards == 0 else None,
+                 None, "tensor" if "tensor" in axes else None, None)
+        put = lambda t: jax.device_put(t, NamedSharding(mesh, spec))
+        (_, got), got_g = jax.jit(jax.value_and_grad(
+            lambda q, k, v: loss(q, k, v, mesh), argnums=(0, 1, 2),
+            has_aux=True))(put(q), put(k), put(v))
+        np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
+        for g, w in zip(got_g, want_g):
+            np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5)
+        assert got.sharding.spec == spec     # no gather on the way out
+
+    def test_inside_the_pipeline_region(self):
+        """Traced inside the pipeline's shard_map (manual over ``pipe``)
+        the kernel takes only the axes still automatic there."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from distributed_tensorflow_tpu import parallel
+        from distributed_tensorflow_tpu.models.gpt import GPT, GPTConfig
+        mesh = parallel.make_mesh({"pipe": 2, "data": 2},
+                                  devices=jax.devices()[:4])
+        ids = jax.device_put(
+            jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 128),
+            NamedSharding(mesh, P("data")))
+        outs = {}
+        for use_flash in (True, False):
+            model = GPT(GPTConfig(
+                vocab_size=128, hidden_size=32, num_layers=2, num_heads=2,
+                intermediate_size=64, max_position=32, dropout_rate=0.0,
+                pipeline_stages=2, use_flash=use_flash), mesh=mesh)
+            params = model.init(jax.random.PRNGKey(0))
+            outs[use_flash] = jax.jit(model.apply)(params, ids)
+        np.testing.assert_allclose(outs[True], outs[False], atol=1e-5)
+
+
 class TestFlashAutoDispatch:
     def test_resolve_use_flash(self, monkeypatch):
         from distributed_tensorflow_tpu.ops import attention as attn_lib

@@ -1,0 +1,155 @@
+"""Compile the main-path Pallas kernels for a TPU v5e that is described, not
+attached (``jax.experimental.topologies``), at GPT-2-small widths.
+
+Interpret mode — what every other kernel test here runs — partitions, tiles
+and allocates happily where Mosaic refuses: a slice off the (8, 128) tiling,
+too much VMEM, a kernel XLA cannot partition over a mesh.  These compiles
+are what the chip's own compiler says, about two seconds each, with no chip
+time.  Nothing executes, so they say nothing about results.
+
+The code under test asks ``jax.default_backend()`` (which is the CPU here)
+whether to interpret; the cases steer that by passing the kernels' own
+``interpret=False`` argument, never through a new option of the program.
+"""
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+# ops/pallas/__init__ re-exports the FUNCTION flash_attention over the
+# module of the same name, so attribute access finds the function
+flash_mod = importlib.import_module(
+    "distributed_tensorflow_tpu.ops.pallas.flash_attention")
+paged_mod = importlib.import_module(
+    "distributed_tensorflow_tpu.ops.pallas.paged_attention")
+
+KERNEL_MARK = "tpu_custom_call"
+
+# GPT-2-small serving shapes, as chip_smoke.py's serve phase builds them
+LAYERS, HEADS, HEAD_DIM = 12, 12, 64
+SLOTS, MAX_LEN, WINDOW = 16, 1024, 32
+# ... and its long-sequence training shape
+FLASH_SHAPE = (6, 2048, HEADS, HEAD_DIM)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu / unknown topology name
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: the next run would warn and
+    compile again.  Off around this file."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _pool(page_size, kv_heads, quantized, sharding):
+    shape = (LAYERS, SLOTS * (MAX_LEN // page_size) + 1, page_size,
+             kv_heads, HEAD_DIM)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
+    if quantized:
+        return {"k": sds(shape, jnp.int8), "v": sds(shape, jnp.int8),
+                "k_scale": sds(shape[:-1] + (1,), jnp.float32),
+                "v_scale": sds(shape[:-1] + (1,), jnp.float32)}
+    return {"k": sds(shape, jnp.bfloat16), "v": sds(shape, jnp.bfloat16)}
+
+
+def _paged_decode(page_size=16, kv_heads=HEADS, quantized=False):
+    def build(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
+        fn = lambda q, pool, layer, tab, valid: \
+            paged_mod.paged_decode_attention(q, pool, layer, tab, valid,
+                                             interpret=False)
+        return fn, (sds((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+                    _pool(page_size, kv_heads, quantized, one),
+                    sds((), jnp.int32),
+                    sds((SLOTS, MAX_LEN // page_size), jnp.int32),
+                    sds((SLOTS, MAX_LEN), jnp.bool_))
+    return build
+
+
+def _paged_window(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
+    fn = lambda q, pool, layer, row, pos: paged_mod.paged_window_attention(
+        q, pool, layer, row, pos, interpret=False)
+    return fn, (sds((1, WINDOW, HEADS, HEAD_DIM), jnp.bfloat16),
+                _pool(16, HEADS, False, one), sds((), jnp.int32),
+                sds((MAX_LEN // 16,), jnp.int32), sds((), jnp.int32))
+
+
+def _flash(grad):
+    def build(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        qkv = (jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16,
+                                    sharding=one),) * 3
+        fwd = lambda q, k, v: flash_mod.flash_attention(
+            q, k, v, causal=True, interpret=False)
+        if not grad:
+            return fwd, qkv
+        loss = lambda q, k, v: jnp.sum(fwd(q, k, v).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2)), qkv
+    return build
+
+
+def _flash_on_mesh(axes, kv_heads=HEADS):
+    """Forward + backward of the kernel on operands sharded over a four-chip
+    mesh: what a data-parallel long-sequence train step contains.  Called
+    directly under ``jit`` this is refused ("Mosaic kernels cannot be
+    automatically partitioned"); ``mesh=`` puts it under shard_map."""
+    def build(topo):
+        from distributed_tensorflow_tpu import parallel
+        mesh = parallel.make_mesh(axes, devices=topo.devices)
+        batch = tuple(a for a in ("data", "fsdp") if a in axes)
+        spec = P(batch, None, "tensor" if "tensor" in axes else None, None)
+        sds = lambda heads: jax.ShapeDtypeStruct(
+            (24, 2048, heads, HEAD_DIM), jnp.bfloat16,
+            sharding=NamedSharding(mesh, spec))
+        loss = lambda q, k, v: jnp.sum(flash_mod.flash_attention(
+            q, k, v, causal=True, interpret=False, mesh=mesh
+        ).astype(jnp.float32))
+        return (jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                (sds(HEADS), sds(kv_heads), sds(kv_heads)))
+    return build
+
+
+CASES = {
+    "paged_decode_bf16_page16": _paged_decode(),
+    "paged_decode_bf16_page128": _paged_decode(page_size=128),
+    "paged_decode_int8_planes": _paged_decode(quantized=True),
+    "paged_decode_gqa4": _paged_decode(kv_heads=4),
+    "paged_window_32": _paged_window,
+    "flash_forward": _flash(grad=False),
+    "flash_backward": _flash(grad=True),
+    "flash_mesh_data4": _flash_on_mesh({"data": 4}),
+    "flash_mesh_data2_fsdp2": _flash_on_mesh({"data": 2, "fsdp": 2}),
+    "flash_mesh_data2_tensor2_gqa4": _flash_on_mesh(
+        {"data": 2, "tensor": 2}, kv_heads=4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(topo, case):
+    fn, args = CASES[case](topo)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert KERNEL_MARK in text, f"{case}: no Mosaic kernel in the program"
