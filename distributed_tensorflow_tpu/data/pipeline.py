@@ -197,10 +197,11 @@ def prefetch_to_device(iterator: Iterable, size: int = 2,
 
     try:
         while True:
-            # goodput "data_stall": the consumer's blocking wait on the
-            # handoff IS the input-starvation time (a full queue returns
-            # immediately and accrues ~nothing); closed before the yield
-            # so the caller's step time never lands here
+            # goodput "data_stall" == the "data.prefetch_wait" span: the
+            # consumer's blocking wait on the handoff IS the
+            # input-starvation time (a full queue returns immediately
+            # and accrues ~nothing); closed before the yield so the
+            # caller's step time never lands here
             with goodput_lib.account("data_stall"):
                 item = handoff.get()     # blocking handoff, no poll
             if item is done:

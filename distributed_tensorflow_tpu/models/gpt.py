@@ -289,17 +289,18 @@ class GPT:
         LlamaRMSNorm numerics)."""
         c = self.config
         from ..ops.pallas import resolve_fused_ln
-        if c.norm == "rmsnorm":
-            if resolve_fused_ln(c.fused_layernorm):
-                from ..ops.pallas import fused_rmsnorm
-                return fused_rmsnorm(x, p["gamma"], c.layer_norm_eps)
-            xf = x.astype(jnp.float32)
-            y = xf * jax.lax.rsqrt(
-                jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-                + c.layer_norm_eps)
-            return (y * p["gamma"]).astype(x.dtype)
-        return _layer_norm(p, x, c.layer_norm_eps,
-                           fused=resolve_fused_ln(c.fused_layernorm))
+        with jax.named_scope("norm"):
+            if c.norm == "rmsnorm":
+                if resolve_fused_ln(c.fused_layernorm):
+                    from ..ops.pallas import fused_rmsnorm
+                    return fused_rmsnorm(x, p["gamma"], c.layer_norm_eps)
+                xf = x.astype(jnp.float32)
+                y = xf * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                    + c.layer_norm_eps)
+                return (y * p["gamma"]).astype(x.dtype)
+            return _layer_norm(p, x, c.layer_norm_eps,
+                               fused=resolve_fused_ln(c.fused_layernorm))
 
     def _rope_transform(self, local_seq_len: int):
         """qk_transform for this forward, or None.  Built ONCE per forward
@@ -363,10 +364,11 @@ class GPT:
                                                    mesh=self.mesh)
         else:
             attention_fn = attn_lib.dot_product_attention
-        return attn_lib.attention_core(
-            p, x, mask=mask, dropout_rate=c.dropout_rate, rng=rng,
-            train=train, attention_fn=attention_fn,
-            qk_transform=qk_transform)
+        with jax.named_scope("attention"):
+            return attn_lib.attention_core(
+                p, x, mask=mask, dropout_rate=c.dropout_rate, rng=rng,
+                train=train, attention_fn=attention_fn,
+                qk_transform=qk_transform)
 
     def _ffn(self, p, x, rng=None, train=False):
         """Pre-LN FFN (dense or MoE): shared by the full-sequence and
@@ -380,17 +382,19 @@ class GPT:
         """
         c = self.config
         h = self._norm(p["ln_2"], x)
-        if "moe" in p:
-            y, m = apply_moe(p["moe"], h, k=c.moe_top_k,
-                             capacity_factor=c.moe_capacity_factor,
-                             train=train, rng=rng)
-            aux = (c.moe_aux_weight * m["aux_loss"]
-                   + c.moe_z_weight * m["router_z_loss"])
-            return y, aux
-        if c.ffn_activation == "swiglu":
-            return (attn_lib.ffn_swiglu_core(p["ffn"], h),
+        with jax.named_scope("mlp"):
+            if "moe" in p:
+                y, m = apply_moe(p["moe"], h, k=c.moe_top_k,
+                                 capacity_factor=c.moe_capacity_factor,
+                                 train=train, rng=rng)
+                aux = (c.moe_aux_weight * m["aux_loss"]
+                       + c.moe_z_weight * m["router_z_loss"])
+                return y, aux
+            if c.ffn_activation == "swiglu":
+                return (attn_lib.ffn_swiglu_core(p["ffn"], h),
+                        jnp.zeros((), jnp.float32))
+            return (attn_lib.ffn_core(p["ffn"], h),
                     jnp.zeros((), jnp.float32))
-        return attn_lib.ffn_core(p["ffn"], h), jnp.zeros((), jnp.float32)
 
     def _block(self, p, x, mask, rng, train, qk_transform=None):
         c = self.config
@@ -408,10 +412,11 @@ class GPT:
         (the gradient parity between them depends on bit-identity here)."""
         c = self.config
         s = input_ids.shape[1]
-        x = jnp.take(emb["word"], input_ids, axis=0)
-        if c.position_embedding == "learned":
-            x = x + emb["position"][None, :s, :]
-        return _dropout(x, c.dropout_rate, r_emb, train).astype(c.dtype)
+        with jax.named_scope("embed"):
+            x = jnp.take(emb["word"], input_ids, axis=0)
+            if c.position_embedding == "learned":
+                x = x + emb["position"][None, :s, :]
+            return _dropout(x, c.dropout_rate, r_emb, train).astype(c.dtype)
 
     def _make_layer_fn(self, seq_len: int):
         """Decoder block fn with the RoPE transform bound and optional
@@ -524,7 +529,9 @@ class GPT:
         """Tied-head projection against an explicit word matrix — ONE
         implementation for logits() and the 1F1B head loss (their
         gradient parity depends on bit-identity)."""
-        return (hidden @ word.T.astype(hidden.dtype)).astype(jnp.float32)
+        with jax.named_scope("head"):
+            return (hidden @ word.T.astype(hidden.dtype)
+                    ).astype(jnp.float32)
 
     def _head_word(self, params):
         """The LM head's [vocab, d] matrix: the tied word embedding, or
@@ -593,29 +600,31 @@ class GPT:
                                      rng=rng, return_aux=True)
             targets = ids[:, 1:]
             mask = batch.get("loss_mask")
-            if c.loss_seq_chunk:
-                nll_sum, hit_sum = self._chunked_lm_stats(
-                    self._head_word(params), hidden, targets, mask,
-                    c.loss_seq_chunk)
-                if mask is None:
-                    count = jnp.asarray(targets.size, jnp.float32)
-                    loss = nll_sum / count
-                    acc = hit_sum / count
+            with jax.named_scope("head_loss"):
+                if c.loss_seq_chunk:
+                    nll_sum, hit_sum = self._chunked_lm_stats(
+                        self._head_word(params), hidden, targets, mask,
+                        c.loss_seq_chunk)
+                    if mask is None:
+                        count = jnp.asarray(targets.size, jnp.float32)
+                        loss = nll_sum / count
+                        acc = hit_sum / count
+                    else:
+                        w = jnp.sum(mask.astype(jnp.float32))
+                        loss = nll_sum / jnp.maximum(w, 1e-9)
+                        acc = hit_sum / jnp.maximum(w, 1.0)
                 else:
-                    w = jnp.sum(mask.astype(jnp.float32))
-                    loss = nll_sum / jnp.maximum(w, 1e-9)
-                    acc = hit_sum / jnp.maximum(w, 1.0)
-            else:
-                logits = self.logits(params, hidden)
-                loss = loss_lib.softmax_cross_entropy_with_integer_labels(
-                    logits, targets, where=mask)
-                hits = (jnp.argmax(logits, -1) == targets
-                        ).astype(jnp.float32)
-                if mask is not None:
-                    acc = (jnp.sum(hits * mask)
-                           / jnp.maximum(jnp.sum(mask), 1.0))
-                else:
-                    acc = jnp.mean(hits)
+                    logits = self.logits(params, hidden)
+                    loss = \
+                        loss_lib.softmax_cross_entropy_with_integer_labels(
+                            logits, targets, where=mask)
+                    hits = (jnp.argmax(logits, -1) == targets
+                            ).astype(jnp.float32)
+                    if mask is not None:
+                        acc = (jnp.sum(hits * mask)
+                               / jnp.maximum(jnp.sum(mask), 1.0))
+                    else:
+                        acc = jnp.mean(hits)
             metrics = {"token_accuracy": acc}
             if mask is not None:
                 # normalizer for exact gradient accumulation (train.step)

@@ -9,13 +9,16 @@ applied *per request*: every retired request's end-to-end wall time
 decomposes into exclusive phases
 
 * ``queue_wait`` — submit until the admission that started its prefill,
-* ``prefill_compute`` — its own prefill windows' wall time,
+* ``prefill_compute`` — its own ``serve.prefill`` spans (obs/trace.py).
+  A MID window's span is dispatch time: the call is asynchronous, and
+  the device's time for the window lands in the tick's next fetch,
 * ``prefill_interference`` — the HOL signal: time this request's decode
   ticks were stretched by *other* requests' prefill windows sharing the
   tick (each co-scheduled decode slot is charged the tick's
   other-requests' window cost in full — every slot experiences the
   stretch in parallel, exactly as the fleet simulator prices it),
-* ``decode_compute`` — its decode ticks' wall time minus interference,
+* ``decode_compute`` — its decode ticks' ``serve.decode_dispatch`` +
+  ``serve.decode_fetch`` spans (dispatch to host sync),
 * ``migration`` — export-to-import gap when the request moved engines,
 * ``backpressure_requeue`` — re-queued wait after an admission bounce
   (adapter table / page pool exhaustion),
@@ -23,7 +26,8 @@ decomposes into exclusive phases
   flush), never accrued directly, so the split stays honest.
 
 The scheduler accrues into a plain per-request dict at its existing
-transition seams (the same places reqtrace hooks) and calls
+transition seams (the same places reqtrace hooks), from the durations
+of the tick's own spans — it keeps no stopwatch of its own — and calls
 :func:`finalize` + :func:`observe` exactly once at retirement (the
 claim-once ``_retire_accounting`` guarantee).  The finished breakdown
 rides the request handle, the reqtrace retirement mark, and — through

@@ -31,6 +31,13 @@ RetraceGuard patch); with nothing active, ``account()`` returns a cached
 no-op context manager — one module-global ``None`` check on the hot
 path.  Measured overhead of an active frame is two ``perf_counter``
 reads and one lock acquire (~1 µs; docs/OBSERVABILITY.md §Goodput).
+
+**One ``with`` per boundary.**  A frame IS the site's span: it opens
+``obs.trace``'s span of the bucket's name (``SPAN_NAMES``) and accrues
+that span's own clock reads, so ``run_step``, the prefetch wait and
+``save`` each keep one context manager and one measurement.  With no
+accountant active ``account()`` hands back the span alone (the cached
+null span when no tracer is active either).
 """
 from __future__ import annotations
 
@@ -41,8 +48,8 @@ from typing import Any, Dict, Optional
 
 from . import trace as trace_lib
 
-__all__ = ["BUCKETS", "GoodputAccountant", "activate", "deactivate",
-           "active", "activated", "account"]
+__all__ = ["BUCKETS", "SPAN_NAMES", "GoodputAccountant", "activate",
+           "deactivate", "active", "activated", "account"]
 
 # The attribution vocabulary.  "other" is derived (wall minus the
 # measured buckets), never accrued directly — it is where untracked time
@@ -52,6 +59,11 @@ BUCKETS = ("step", "compile", "checkpoint_save", "checkpoint_restore",
            "restart_backoff", "data_stall", "fault_recovery", "other")
 
 _MEASURED = tuple(b for b in BUCKETS if b != "other")
+
+# The span each bucket's frame opens (docs/OBSERVABILITY.md span table);
+# a bucket not named here is "goodput.<bucket>".
+SPAN_NAMES = {"step": "train.dispatch", "data_stall": "data.prefetch_wait",
+              "checkpoint_save": "checkpoint"}
 
 
 class GoodputAccountant:
@@ -115,6 +127,11 @@ class GoodputAccountant:
             s = self._tls.stack = []
         return s
 
+    def _read(self, span_s: float) -> float:
+        """A frame's clock read: its span's own (``perf_counter``
+        seconds), unless a test injected another clock."""
+        return span_s if self._clock is time.perf_counter else self._clock()
+
     def _accrue(self, bucket: str, seconds: float) -> None:
         if seconds <= 0.0:
             return
@@ -133,13 +150,14 @@ class GoodputAccountant:
                                   "ts": trace_lib.now_us(),
                                   "cat": "goodput", "args": lane})
 
-    def account(self, bucket: str):
+    def account(self, bucket: str, **span_args: Any):
         """Context manager attributing its body's wall time to ``bucket``
-        (exclusively: an enclosing frame is paused for the duration)."""
+        (exclusively: an enclosing frame is paused for the duration) and
+        recording it as the bucket's span, ``span_args`` included."""
         if bucket not in _MEASURED:
             raise ValueError(f"unknown goodput bucket {bucket!r}; "
                              f"choices: {_MEASURED}")
-        return _Frame(self, bucket)
+        return _Frame(self, bucket, span_args)
 
     def accrue(self, bucket: str, seconds: float) -> None:
         """Attribute an already-measured duration (no pause semantics —
@@ -189,20 +207,32 @@ class GoodputAccountant:
         }
 
 
+def _span_name(bucket: str) -> str:
+    return SPAN_NAMES.get(bucket) or "goodput." + bucket
+
+
 class _Frame:
     """One accounting frame: pauses the enclosing frame on entry, accrues
-    its own exclusive time on exit, resumes the parent."""
+    its own exclusive time on exit, resumes the parent.  The frame's
+    clock reads are its span's (an injected test clock reads its own)."""
 
-    __slots__ = ("_acct", "_bucket", "_t0")
+    __slots__ = ("_acct", "_bucket", "_t0", "_span")
 
-    def __init__(self, acct: GoodputAccountant, bucket: str):
+    def __init__(self, acct: GoodputAccountant, bucket: str,
+                 span_args: Dict[str, Any]):
         self._acct = acct
         self._bucket = bucket
         self._t0 = 0.0
+        self._span = trace_lib.timed(_span_name(bucket), **span_args)
+
+    @property
+    def duration_s(self) -> float:
+        return self._span.duration_s
 
     def __enter__(self) -> "_Frame":
         acct = self._acct
-        now = acct._clock()
+        self._span.__enter__()
+        now = acct._read(self._span.start_s)
         stack = acct._stack()
         if stack:
             parent = stack[-1]
@@ -213,7 +243,8 @@ class _Frame:
 
     def __exit__(self, *exc) -> bool:
         acct = self._acct
-        now = acct._clock()
+        self._span.__exit__(*exc)
+        now = acct._read(self._span.end_s)
         stack = acct._stack()
         acct._accrue(self._bucket, now - self._t0)
         # tolerate misnested exits (a generator frame GC'd out of order):
@@ -226,18 +257,6 @@ class _Frame:
             stack[-1]._t0 = now          # resume the parent's accrual
         return False
 
-
-class _NullFrame:
-    """Cached no-op for the inactive fast path (mirrors trace._NullSpan)."""
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_FRAME = _NullFrame()
 
 # ---------------------------------------------------------------------------
 # Active accountant: the process-wide sink for code without a handle
@@ -266,13 +285,18 @@ def active() -> Optional[GoodputAccountant]:
     return _ACTIVE
 
 
-def account(bucket: str):
-    """Module-level frame: routes to the active accountant, cached no-op
-    when nothing is active (one global read on the disabled path)."""
+def account(bucket: str, measure: bool = False, **span_args: Any):
+    """Module-level frame: routes to the active accountant.  With none
+    active the site is still its span: ``obs.trace.span`` (the cached
+    no-op when no tracer is active either: two global reads on the
+    disabled path), or ``obs.trace.timed`` when the caller reads
+    ``.duration_s`` afterwards (``measure=True``)."""
     a = _ACTIVE
-    if a is None:
-        return _NULL_FRAME
-    return a.account(bucket)
+    if a is not None:
+        return a.account(bucket, **span_args)
+    if measure:
+        return trace_lib.timed(_span_name(bucket), **span_args)
+    return trace_lib.span(_span_name(bucket), **span_args)
 
 
 @contextlib.contextmanager

@@ -47,7 +47,7 @@ from typing import Any, Dict, List, Optional
 from . import trace as trace_lib
 
 __all__ = ["mint", "enabled", "configure", "reset",
-           "submitted", "stage", "mark", "exported", "imported",
+           "submitted", "stage", "mark", "note", "exported", "imported",
            "retired", "tree", "lookup", "live_ids", "completed",
            "forensic_dump", "forensics_log",
            "CAT", "FLOW_CAT"]
@@ -55,13 +55,16 @@ __all__ = ["mint", "enabled", "configure", "reset",
 CAT = "request"          # async-lane category: one track per trace id
 FLOW_CAT = "migration"   # flow-arrow category: export -> import edges
 
+RING = 256               # completed-trace ring capacity, as reset() leaves it
+FORENSICS = 64           # forensic-dump log capacity, likewise
+
 _lock = threading.Lock()
 _seq = 0
 _enabled = True
 _live: Dict[str, Dict[str, Any]] = {}
-_ring: "collections.deque[Dict[str, Any]]" = collections.deque(maxlen=256)
+_ring: "collections.deque[Dict[str, Any]]" = collections.deque(maxlen=RING)
 _forensics: "collections.deque[Dict[str, Any]]" = collections.deque(
-    maxlen=64)
+    maxlen=FORENSICS)
 
 
 def configure(enabled: Optional[bool] = None,
@@ -83,15 +86,17 @@ def configure(enabled: Optional[bool] = None,
 
 
 def reset() -> None:
-    """Drop all live records, the ring, the forensics log, and re-enable
-    minting (test isolation)."""
-    global _enabled, _seq
+    """Drop all live records, the ring and the forensics log, put both
+    buffers back at their default capacities (a ``configure(ring=4)``
+    must not outlive the test that made it), and re-enable minting
+    (test isolation)."""
+    global _enabled, _seq, _ring, _forensics
     with _lock:
         _enabled = True
         _seq = 0
         _live.clear()
-        _ring.clear()
-        _forensics.clear()
+        _ring = collections.deque(maxlen=RING)
+        _forensics = collections.deque(maxlen=FORENSICS)
 
 
 def enabled() -> bool:
@@ -117,7 +122,7 @@ def _record(trace_id: str) -> Dict[str, Any]:
     rec = _live.get(trace_id)
     if rec is None:
         rec = {"trace_id": trace_id, "events": [], "open": [],
-               "hops": 0, "status": None}
+               "hops": 0, "status": None, "counts": {}}
         _live[trace_id] = rec
     return rec
 
@@ -177,6 +182,15 @@ def mark(trace_id: str, name: str, ts_us: Optional[float] = None,
         _emit(trace_id, "n", name, CAT, ts_us, args)
 
 
+def note(trace_id: str, **counts: Any) -> None:
+    """Per-request numbers the scheduler counts at its own boundaries
+    (``queue_wait_s``, ``prefill_ticks``, ``prefill_windows``): kept on
+    the record under ``counts``, not on the timeline, and handed out by
+    ``lookup`` / ``completed``.  A later note of one key replaces it."""
+    with _lock:
+        _record(trace_id)["counts"].update(counts)
+
+
 def exported(trace_id: str, ts_us: Optional[float] = None,
              **args: Any) -> None:
     """The request leaves this replica as a snapshot: close the open
@@ -224,6 +238,14 @@ def retired(trace_id: str, status: str, ts_us: Optional[float] = None,
 
 # ------------------------------------------------------------ forensics
 
+def _public(rec: Dict[str, Any]) -> Dict[str, Any]:
+    """A copy of one record without its open-stage bookkeeping (caller
+    holds ``_lock``)."""
+    return {"trace_id": rec["trace_id"], "events": list(rec["events"]),
+            "hops": rec["hops"], "status": rec["status"],
+            "counts": dict(rec["counts"])}
+
+
 def lookup(trace_id: str) -> Optional[Dict[str, Any]]:
     """The raw span record for a live or ring-resident trace."""
     with _lock:
@@ -233,9 +255,7 @@ def lookup(trace_id: str) -> Optional[Dict[str, Any]]:
                 if r["trace_id"] == trace_id:
                     rec = r
                     break
-        return None if rec is None else {
-            "trace_id": rec["trace_id"], "events": list(rec["events"]),
-            "hops": rec["hops"], "status": rec["status"]}
+        return None if rec is None else _public(rec)
 
 
 def live_ids() -> List[str]:
@@ -246,9 +266,7 @@ def live_ids() -> List[str]:
 def completed() -> List[Dict[str, Any]]:
     """Snapshot of the bounded completed-trace ring, oldest first."""
     with _lock:
-        return [{"trace_id": r["trace_id"], "events": list(r["events"]),
-                 "hops": r["hops"], "status": r["status"]}
-                for r in _ring]
+        return [_public(r) for r in _ring]
 
 
 def tree(trace_id: str) -> Optional[Dict[str, Any]]:

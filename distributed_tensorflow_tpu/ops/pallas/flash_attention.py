@@ -191,6 +191,7 @@ def _flash_forward(q, k, v, valid, scale, causal, block_q, block_k,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="dttpu_flash_fwd",
     )(q, k, v, valid)
     return out[:, :, :sq, :], lse[:, :, :sq, 0]
 
@@ -374,6 +375,7 @@ def _flash_backward(q, k, v, valid, out, lse, do, scale, causal,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="dttpu_flash_dkv",
     )(q_p, k_p, v_p, do_p, lse_p, d_p, valid_p)
 
     dq = pl.pallas_call(
@@ -401,6 +403,7 @@ def _flash_backward(q, k, v, valid, out, lse, do, scale, causal,
                                lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="dttpu_flash_dq",
     )(q_p, k_p, v_p, do_p, lse_p, d_p, valid_p)
 
     if group > 1:

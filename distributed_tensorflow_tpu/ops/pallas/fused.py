@@ -128,6 +128,7 @@ def fused_adam_update(params: jnp.ndarray, grads: jnp.ndarray,
         ],
         out_specs=(tensor_spec, tensor_spec, tensor_spec),
         interpret=interpret,
+        name="dttpu_fused_adam",
     )(scalars, p2, g2, m2, v2)
 
     n = math.prod(orig_shape) if orig_shape else 1
@@ -169,6 +170,7 @@ def _layernorm_forward(x2, gamma, beta, eps, interpret):
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="dttpu_fused_layernorm",
     )(xp, gamma.reshape(1, d), beta.reshape(1, d))
     return out[:rows]
 
@@ -247,6 +249,7 @@ def _rmsnorm_forward(x2, gamma, eps, interpret):
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="dttpu_fused_rmsnorm",
     )(xp, gamma.reshape(1, d))
     return out[:rows]
 

@@ -228,6 +228,9 @@ def _paged_attention(q, kv, layer, page_tab, valid_plane, pos, *,
         out_shape=jax.ShapeDtypeStruct((B, sq, h, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        # the kernel's name in HLO and in a device trace
+        name=("dttpu_paged_window" if window_causal
+              else "dttpu_paged_decode"),
     )
     return call(layer_arr, tab, pos_arr, *inputs)
 

@@ -119,6 +119,21 @@ class ServeMetrics:
             "dttpu_serve_prefix_evictions_total",
             "Radix-cached prefix pages reclaimed by LRU eviction "
             "under allocation pressure.")
+        # what the pump dispatched (scheduler.py counts each where it
+        # happens): over ticks, windows and decode steps a tick
+        self.ticks = reg.counter(
+            "dttpu_serve_ticks_total", "Scheduler ticks completed.")
+        self.prefill_windows = reg.counter(
+            "dttpu_serve_prefill_windows_total",
+            "Prefill window dispatches (mid windows and the admitting "
+            "last window).")
+        self.decode_steps = reg.counter(
+            "dttpu_serve_decode_steps_total",
+            "Decode steps dispatched (tick_steps per decode dispatch).")
+        self.admit_backpressure = reg.counter(
+            "dttpu_serve_admit_backpressure_total",
+            "Admissions bounced back to the queue: every adapter row "
+            "or pool page was pinned by an in-flight request.")
         # prefix-affinity federation (obs/federate.py): the pool's
         # hot-chain fingerprint rendered as labeled gauges so a
         # cross-host router can score prefix affinity from SCRAPED
@@ -133,8 +148,13 @@ class ServeMetrics:
         self._chain_gauges: dict = {}
         # counters render by delta against the stats() snapshot (the
         # exposition forbids decreasing counters; stats are monotonic)
-        self._last_prefix_hits = 0
-        self._last_prefix_evictions = 0
+        self._by_delta = [
+            [self.prefix_hits, "prefix_hits_total", 0],
+            [self.prefix_evictions, "prefix_evictions_total", 0],
+            [self.ticks, "ticks_completed", 0],
+            [self.prefill_windows, "prefill_windows_total", 0],
+            [self.decode_steps, "decode_steps_total", 0],
+            [self.admit_backpressure, "admit_backpressure_total", 0]]
         # per-tenant series, created lazily at first sight of a tenant
         # (cardinality = the tenant set, which admission policy bounds)
         self._tenant_tokens: dict = {}
@@ -199,14 +219,12 @@ class ServeMetrics:
         self.active_slots.set(stats.active)
         self.pages_free.set(stats.pages_free)
         self.pages_per_request.set(stats.pages_per_request)
-        d = stats.prefix_hits_total - self._last_prefix_hits
-        if d > 0:
-            self.prefix_hits.inc(d)
-            self._last_prefix_hits = stats.prefix_hits_total
-        d = stats.prefix_evictions_total - self._last_prefix_evictions
-        if d > 0:
-            self.prefix_evictions.inc(d)
-            self._last_prefix_evictions = stats.prefix_evictions_total
+        for entry in self._by_delta:
+            counter, field, last = entry
+            now = getattr(stats, field)
+            if now > last:
+                counter.inc(now - last)
+                entry[2] = now
         for tenant, n in stats.inflight_per_tenant.items():
             self._tenant_gauge(tenant).set(n)
         for tenant, g in self._tenant_inflight.items():

@@ -92,6 +92,7 @@ import numpy as np
 from ..analysis import graph as graph_lib
 from ..obs import critpath as critpath_lib
 from ..obs import reqtrace
+from ..obs import trace as trace_lib
 from ..resilience import faults as faults_lib
 from ..ops import decoding as dec
 from . import pages as pages_lib
@@ -178,6 +179,11 @@ class Request:
     _cp_wait: Optional[str] = dataclasses.field(default="queue_wait",
                                                 repr=False)
     _cp_t0: float = dataclasses.field(default=0.0, repr=False)
+    # what the pump counts per request at its own boundaries: the tick
+    # that admitted it and the prefill windows dispatched for it
+    # (reported on the reqtrace record at first token)
+    _admit_tick: int = dataclasses.field(default=0, repr=False)
+    _windows: int = dataclasses.field(default=0, repr=False)
 
     @property
     def remaining_budget(self) -> int:
@@ -290,6 +296,11 @@ class EngineStats:
     last_tick_start_s: float = 0.0           # perf_counter at tick entry
     last_tick_end_s: float = 0.0             # perf_counter at tick exit
     last_tick_duration_s: float = 0.0
+    # what the pump dispatched, cumulative, counted where it happens
+    # (over ticks_completed: windows and decode steps a tick)
+    prefill_windows_total: int = 0           # window dispatches, mid + last
+    decode_steps_total: int = 0              # tick_steps per decode dispatch
+    admit_backpressure_total: int = 0        # admissions bounced + requeued
 
     @property
     def inflight(self) -> int:
@@ -387,6 +398,10 @@ class SlotScheduler:
         self._tick_start_t = 0.0
         self._tick_end_t = 0.0
         self._last_tick_s = 0.0
+        # dispatch counters (stats(); written by the pump under _lock)
+        self._prefill_windows = 0
+        self._decode_steps = 0
+        self._admit_backpressure = 0
         self.metrics = metrics if metrics is not None else _NullMetrics()
         self.adapters = adapters
         self.max_queue_depth = max_queue_depth
@@ -864,7 +879,10 @@ class SlotScheduler:
                 ticks_completed=self._ticks_completed,
                 last_tick_start_s=self._tick_start_t,
                 last_tick_end_s=self._tick_end_t,
-                last_tick_duration_s=self._last_tick_s)
+                last_tick_duration_s=self._last_tick_s,
+                prefill_windows_total=self._prefill_windows,
+                decode_steps_total=self._decode_steps,
+                admit_backpressure_total=self._admit_backpressure)
             skipped = self._windows_skipped
         if self.pages is not None:
             p = self.pages.stats()
@@ -904,99 +922,128 @@ class SlotScheduler:
         Each tick is bracketed by a heartbeat (started/completed
         counters + perf_counter stamps in ``stats()``) — the signal the
         fleet ``Watchdog`` reads to tell a wedged or stalled pump from
-        a merely idle one."""
+        a merely idle one.  The heartbeat's stamps ARE the
+        ``serve.tick`` span's two clock reads (obs/trace.py)."""
         with self._pump_lock:
-            start = time.perf_counter()
-            with self._lock:
-                self._ticks_started += 1
-                self._tick_start_t = start
-            plan = faults_lib.active()
-            if plan is not None:
-                # chaos: stall_tick sleeps here, wedge_replica blocks
-                # here — DELIBERATELY inside the pump mutex, because a
-                # real pathological tick holds it too; that held mutex
-                # is exactly what the watchdog's in-progress heartbeat
-                # check and the forced-export path exist to handle
-                plan.on_engine_tick(self.chaos_tag)  # dtlint: disable=DT303 -- see comment
+            tick = trace_lib.timed("serve.tick")
             try:
-                return self._step_locked()
+                with tick:
+                    with self._lock:
+                        self._ticks_started += 1
+                        self._tick_start_t = tick.start_s
+                        tick.set(tick=self._ticks_started)
+                    plan = faults_lib.active()
+                    if plan is not None:
+                        # chaos: stall_tick sleeps here, wedge_replica
+                        # blocks here — DELIBERATELY inside the pump
+                        # mutex, because a real pathological tick holds
+                        # it too; that held mutex is exactly what the
+                        # watchdog's in-progress heartbeat check and the
+                        # forced-export path exist to handle
+                        plan.on_engine_tick(self.chaos_tag)  # dtlint: disable=DT303 -- see comment
+                    return self._step_locked(tick)
             finally:
-                end = time.perf_counter()
                 with self._lock:
                     self._ticks_completed += 1
-                    self._tick_end_t = end
-                    self._last_tick_s = end - start
+                    self._tick_end_t = tick.end_s
+                    self._last_tick_s = tick.duration_s
 
-    def _step_locked(self) -> bool:
+    def _step_locked(self, tick) -> bool:
+        """The tick's layer boundaries, one span each (children of
+        ``serve.tick``; docs/OBSERVABILITY.md has the table).  The spans
+        whose length the critpath ledger accrues are ``timed``: that one
+        measurement is the span's and the phase's."""
         did = False
         outbox: List[tuple] = []     # tick-ordered deliveries/finishes
-        self._harvest_orphans()
-        self._freeze_stale_rows()
-        self._expire_deadlines()
+        with trace_lib.span("serve.housekeeping"):
+            self._harvest_orphans()
+            self._freeze_stale_rows()
+            self._expire_deadlines()
+        admissions = 0
         while True:
             with self._lock:
                 req = None
                 free = sum(r is None for r in self._slots)
                 if self._queue and len(self._prefills) < free:
                     req = self._queue.popleft()
-            if req is None:
+            if req is None or not self._admit(req):
                 break
-            try:
-                st = self._begin_prefill(req)
-            except (AdapterTableFull, pages_lib.PagePoolExhausted):
-                # every adapter row / pool page is pinned by an
-                # in-flight request: leave the request queued (a
-                # retirement frees pins and pages, so this always
-                # drains) and stop admitting this tick; the continued
-                # wait is attributed to backpressure, not queue order
-                if req.phases is not None:
-                    self._cp_close_wait(req, time.perf_counter(),
-                                        reopen="backpressure_requeue")
-                with self._lock:
-                    self._requeue(req)
-                break
-            if req.phases is not None:
-                self._cp_close_wait(req, time.perf_counter())
-            if req.trace_id:
-                reqtrace.stage(req.trace_id, "prefill")
-            with self._lock:
-                self._prefills.append(st)
+            admissions += 1
         with self._lock:
             pending = list(self._prefills)
-        # critpath (obs/critpath.py): with a ledger active, time each
-        # prefill window and the decode dispatch so the tick's wall can
-        # be attributed per request — prefill_s totals this tick's
+        # critpath (obs/critpath.py): prefill_s totals this tick's
         # window cost, win_by_req keys each request's OWN share (and
         # doubles as "prefilled this tick", which exempts a request
         # admitted mid-tick from interference: it was not yet decoding
-        # when the windows ran).  One global read when inactive.
-        cp_on = critpath_lib.active() is not None
+        # when the windows ran).  A mid window's span is its DISPATCH:
+        # the device's time for it lands in the tick's next fetch.
         prefill_s = 0.0
+        windows = 0
         win_by_req: Dict[int, float] = {}
-        if pending:
+        for st in pending:
             did = True
-            for st in pending:
-                if cp_on:
-                    w0 = time.perf_counter()
-                    self._advance_prefill(st, outbox)
-                    dt = time.perf_counter() - w0
-                    prefill_s += dt
-                    win_by_req[id(st[0])] = \
-                        win_by_req.get(id(st[0]), 0.0) + dt
-                    if st[0].phases is not None:
-                        st[0].phases["prefill_compute"] += dt
-                else:
-                    self._advance_prefill(st, outbox)
+            req = st[0]
+            with trace_lib.timed("serve.prefill",
+                                 trace_id=req.trace_id) as window:
+                windows += self._advance_prefill(st, outbox)
+            dt = window.duration_s
+            prefill_s += dt
+            win_by_req[id(req)] = win_by_req.get(id(req), 0.0) + dt
+            if req.phases is not None:
+                req.phases["prefill_compute"] += dt
         with self._lock:
-            active = any(r is not None for r in self._slots)
+            active = sum(r is not None for r in self._slots)
+        decoded = None
         if active:
             did = True
-            self._decode_tick(outbox, prefill_s if cp_on else None,
-                              win_by_req)
-        self._flush(outbox)
+            decoded = self._decode_tick(active)
+        with trace_lib.span("serve.deliver") as deliver:
+            if decoded is not None:
+                self._collect(outbox, decoded, prefill_s, win_by_req)
+            tokens = self._flush(outbox)
+            deliver.set(tokens=tokens)
+        tick.set(windows=windows, admissions=admissions, active=active,
+                 tokens=tokens)
         if did:
             self._report_depth()
         return did
+
+    def _admit(self, req: Request) -> bool:
+        """A popped request -> an in-flight prefill (adapter pin, page
+        lease with its radix lookup).  False when every adapter row /
+        pool page is pinned by an in-flight request: the request goes
+        back to the FRONT of the queue (a retirement frees pins and
+        pages, so this always drains), admission stops for this tick,
+        and the continued wait is attributed to backpressure, not queue
+        order."""
+        with trace_lib.timed("serve.admit",
+                             trace_id=req.trace_id) as admit:
+            try:
+                st = self._begin_prefill(req)
+                admit.set(outcome="ok", skipped_tokens=int(
+                    st[3].skip if self.paged else 0))
+            except (AdapterTableFull, pages_lib.PagePoolExhausted):
+                st = None
+                admit.set(outcome="backpressure")
+        now = admit.end_s
+        if st is None:
+            if req.phases is not None:
+                self._cp_close_wait(req, now,
+                                    reopen="backpressure_requeue")
+            with self._lock:
+                self._admit_backpressure += 1
+                self._requeue(req)
+            return False
+        if req.phases is not None:
+            self._cp_close_wait(req, now)
+        req._admit_tick = self._ticks_started
+        if req.trace_id:
+            reqtrace.stage(req.trace_id, "prefill")
+            reqtrace.note(req.trace_id,
+                          queue_wait_s=now - req.submit_time)
+        with self._lock:
+            self._prefills.append(st)
+        return True
 
     def _harvest_orphans(self) -> None:
         """Recycle the prefill storage of requests cancelled
@@ -1133,10 +1180,11 @@ class SlotScheduler:
                                                     np.int32)
         return self.adapters.arrays, self._adapter_rows
 
-    def _advance_prefill(self, st: list, outbox: List[tuple]) -> None:
+    def _advance_prefill(self, st: list, outbox: List[tuple]) -> int:
         """One window for one in-flight prefill; admits the request into
         its slot on the last window.  Pump-only; delivery of the first
-        token is queued on ``outbox`` (flushed at end of tick).
+        token is queued on ``outbox`` (flushed at end of tick).  Returns
+        the windows dispatched (0 for a request cancelled cross-thread).
 
         Paged mode prefills straight into the request's leased pages
         (``decode_window_paged`` at ``pos = skip + i*W`` — a prefix hit
@@ -1147,32 +1195,39 @@ class SlotScheduler:
         req, windows, i, payload = st
         with self._lock:
             if st not in self._prefills:
-                return       # cancelled cross-thread: harvest recycles it
+                return 0     # cancelled cross-thread: harvest recycles it
         ad, ad_row = self._adapter_args(req)
         skip = payload.skip if self.paged else 0
-        if i < len(windows) - 1:
-            if self.paged:
-                self._cache = self._win_mid(
-                    self.params, self._cache, windows[i], payload.row,
-                    np.int32(skip + i * self.prefill_chunk), ad, ad_row)
-            else:
-                new_cache = self._win_mid(self.params, payload,
-                                          windows[i], ad, ad_row)
-                with self._lock:
-                    st[3] = new_cache
+        last = i == len(windows) - 1
+        if not last:
+            with trace_lib.span("serve.prefill_dispatch",
+                                trace_id=req.trace_id, window=int(i),
+                                last=False):
+                if self.paged:
+                    self._cache = self._win_mid(
+                        self.params, self._cache, windows[i], payload.row,
+                        np.int32(skip + i * self.prefill_chunk), ad,
+                        ad_row)
+                else:
+                    new_cache = self._win_mid(self.params, payload,
+                                              windows[i], ad, ad_row)
+            req._windows += 1
             with self._lock:
+                if not self.paged:
+                    st[3] = new_cache
                 st[2] = i + 1
+                self._prefill_windows += 1
             if req.trace_id:
                 reqtrace.mark(req.trace_id, "prefill_window",
                               window=int(i))
-            return
+            return 1
         ctx = req.context if req.context is not None else req.prompt
         plen = ctx.size
         last_idx = np.int32(plen - skip - 1 - (len(windows) - 1)
                             * self.prefill_chunk)
         with self._lock:
             if st not in self._prefills or req.done.is_set():
-                return
+                return 0
             self._prefills.remove(st)
             slot = self._slots.index(None)
             # reserve before the splice so the free-slot count stays
@@ -1183,46 +1238,57 @@ class SlotScheduler:
             self._stale_rows.discard(slot)
         if self._adapter_rows is not None:
             self._adapter_rows[slot] = req.adapter_row
-        if self.paged:
-            tok, self._cache, self._tokens, self._finished, \
-                self._remaining, self._key = self._last_admit(
-                    self.params, self._cache, windows[-1], payload.row,
-                    np.int32(skip + (len(windows) - 1)
-                             * self.prefill_chunk),
-                    last_idx, self._key, self._tokens, self._finished,
-                    self._remaining, np.int32(slot), np.int32(plen),
-                    np.int32(req.remaining_budget), ad, ad_row)
-        else:
-            tok, self._cache, self._tokens, self._finished, \
-                self._remaining, self._key = self._last_admit(
-                    self.params, payload, windows[-1], last_idx,
-                    self._key, self._cache, self._tokens,
-                    self._finished, self._remaining, np.int32(slot),
-                    np.int32(plen), np.int32(req.remaining_budget), ad,
-                    ad_row)
-        first = int(tok)          # host fetch: the TTFT barrier
-        req.first_token_time = time.perf_counter()
-        if self.paged:
-            # the context's full pages are final now — publish them so
-            # the NEXT request with this prefix skips their windows
-            self.pages.register(payload, ctx)
-        with self._lock:
+        with trace_lib.span("serve.prefill_dispatch",
+                            trace_id=req.trace_id, window=int(i),
+                            last=True):
             if self.paged:
-                self._page_tab[slot] = payload.row
+                tok, self._cache, self._tokens, self._finished, \
+                    self._remaining, self._key = self._last_admit(
+                        self.params, self._cache, windows[-1],
+                        payload.row,
+                        np.int32(skip + (len(windows) - 1)
+                                 * self.prefill_chunk),
+                        last_idx, self._key, self._tokens,
+                        self._finished, self._remaining, np.int32(slot),
+                        np.int32(plen), np.int32(req.remaining_budget),
+                        ad, ad_row)
             else:
-                # the pool entry was not donated — reusable for the
-                # next request
-                self._pool_prefill_cache(payload)
-            cancelled = req.done.is_set()
-            if cancelled and self._slots[slot] is req:
-                self._slots[slot] = None
-                if self._page_tab is not None:
-                    self._page_tab[slot] = 0
+                tok, self._cache, self._tokens, self._finished, \
+                    self._remaining, self._key = self._last_admit(
+                        self.params, payload, windows[-1], last_idx,
+                        self._key, self._cache, self._tokens,
+                        self._finished, self._remaining, np.int32(slot),
+                        np.int32(plen), np.int32(req.remaining_budget),
+                        ad, ad_row)
+        with trace_lib.span("serve.first_token_fetch",
+                            trace_id=req.trace_id):
+            first = int(tok)      # host fetch: the TTFT barrier
+        req.first_token_time = time.perf_counter()
+        req._windows += 1
+        with trace_lib.span("serve.register"):
+            if self.paged:
+                # the context's full pages are final now — publish them
+                # so the NEXT request with this prefix skips their
+                # windows
+                self.pages.register(payload, ctx)
+            with self._lock:
+                self._prefill_windows += 1
+                if self.paged:
+                    self._page_tab[slot] = payload.row
+                else:
+                    # the pool entry was not donated — reusable for the
+                    # next request
+                    self._pool_prefill_cache(payload)
+                cancelled = req.done.is_set()
+                if cancelled and self._slots[slot] is req:
+                    self._slots[slot] = None
+                    if self._page_tab is not None:
+                        self._page_tab[slot] = 0
         if cancelled:
             # cancel() raced the splice: retire the freshly spliced row
             # (frozen rows never perturb the others) and deliver nothing
             self._finished = self._finished.at[slot].set(True)
-            return
+            return 1
         self.metrics.admitted(req)
         if req.trace_id:
             reqtrace.mark(req.trace_id, "prefill_window",
@@ -1230,6 +1296,9 @@ class SlotScheduler:
             reqtrace.mark(req.trace_id, "admitted", slot=int(slot))
             reqtrace.mark(req.trace_id, "first_token",
                           ttft_s=req.first_token_time - req.submit_time)
+            reqtrace.note(
+                req.trace_id, prefill_windows=req._windows,
+                prefill_ticks=self._ticks_started - req._admit_tick + 1)
             reqtrace.stage(req.trace_id, "decode")
         if req.remaining_budget <= 1 or (self.eos_id is not None
                                          and first == self.eos_id):
@@ -1240,6 +1309,7 @@ class SlotScheduler:
             outbox.append(("finish", req))
         else:
             outbox.append(("deliver", req, [first], slot))
+        return 1
 
     # ----------------------------------------------------------- decode
 
@@ -1253,19 +1323,11 @@ class SlotScheduler:
                 if self._page_tab is not None:
                     self._page_tab[r] = 0
 
-    def _decode_tick(self, outbox: List[tuple],
-                     prefill_s: Optional[float] = None,
-                     win_by_req: Optional[Dict[int, float]] = None
-                     ) -> None:
-        """One K-step decode dispatch.  ``prefill_s`` (critpath ledger
-        active) is this tick's total prefill-window wall time:  every
-        slot that was already decoding when those windows ran is
-        charged the FULL amount as ``prefill_interference`` — all
-        decode slots experience the stretch in parallel, which is
-        exactly how the fleet simulator prices the HOL penalty — while
-        requests in ``win_by_req`` (prefilled/admitted this same tick)
-        are exempt.  ``decode_compute`` is the dispatch-to-host-sync
-        wall, identical for every live slot in the batch."""
+    def _decode_tick(self, active: int) -> tuple:
+        """One K-step decode dispatch and the fetch of what it emitted:
+        ``(slots, em, mask, fin, decode_s)`` for ``_collect``.
+        ``decode_s`` is the dispatch-to-host-sync wall — the two spans'
+        own durations — identical for every live slot in the batch."""
         with self._lock:
             slots = list(self._slots)
             # page-table snapshot for this dispatch: host mutations
@@ -1274,32 +1336,50 @@ class SlotScheduler:
             tab = (self._page_tab.copy() if self._page_tab is not None
                    else None)
         ad, ad_rows = self._adapter_args()
-        t0 = time.perf_counter() if prefill_s is not None else 0.0
-        if self.paged:
-            (self._cache, self._tokens, self._finished, self._remaining,
-             self._key), em, mask = self._tick(
-                self.params, self._cache, tab, self._tokens,
-                self._finished, self._remaining, self._key, ad, ad_rows)
-        else:
-            (self._cache, self._tokens, self._finished, self._remaining,
-             self._key), em, mask = self._tick(
-                self.params, self._cache, self._tokens, self._finished,
-                self._remaining, self._key, ad, ad_rows)
-        em = np.asarray(em)                      # [K, S]
-        decode_s = (time.perf_counter() - t0     # includes the host sync
-                    if prefill_s is not None else 0.0)
-        mask = np.asarray(mask)
-        fin = np.asarray(self._finished)
+        with trace_lib.timed("serve.decode_dispatch",
+                             steps=self.tick_steps,
+                             active=active) as dispatch:
+            if self.paged:
+                (self._cache, self._tokens, self._finished,
+                 self._remaining, self._key), em, mask = self._tick(
+                    self.params, self._cache, tab, self._tokens,
+                    self._finished, self._remaining, self._key, ad,
+                    ad_rows)
+            else:
+                (self._cache, self._tokens, self._finished,
+                 self._remaining, self._key), em, mask = self._tick(
+                    self.params, self._cache, self._tokens,
+                    self._finished, self._remaining, self._key, ad,
+                    ad_rows)
+        with trace_lib.timed("serve.decode_fetch") as fetch:
+            em = np.asarray(em)                  # [K, S]: the host sync
+            mask = np.asarray(mask)
+            fin = np.asarray(self._finished)
+        with self._lock:
+            self._decode_steps += self.tick_steps
+        return slots, em, mask, fin, dispatch.duration_s + fetch.duration_s
+
+    def _collect(self, outbox: List[tuple], decoded: tuple,
+                 prefill_s: float, win_by_req: Dict[int, float]) -> None:
+        """Sort a decode dispatch's tokens into ``outbox`` and accrue
+        its critpath phases.  ``prefill_s`` is this tick's total
+        prefill-window wall time: every slot that was already decoding
+        when those windows ran is charged the FULL amount as
+        ``prefill_interference`` — all decode slots experience the
+        stretch in parallel, which is exactly how the fleet simulator
+        prices the HOL penalty — while requests in ``win_by_req``
+        (prefilled/admitted this same tick) are exempt."""
+        slots, em, mask, fin, decode_s = decoded
         for r, req in enumerate(slots):
             if req is None:
                 continue
             with self._lock:
                 if self._slots[r] is not req:
                     continue         # cancelled mid-dispatch: drop tokens
-            if prefill_s is not None and req.phases is not None:
+            if req.phases is not None:
                 ph = req.phases
                 ph["decode_compute"] += decode_s
-                if id(req) not in (win_by_req or {}):
+                if id(req) not in win_by_req:
                     ph["prefill_interference"] += prefill_s
             toks = em[:, r][mask[:, r]]
             if toks.size:
@@ -1308,14 +1388,16 @@ class SlotScheduler:
                 self._drop_slot(r, req)
                 outbox.append(("finish", req))
 
-    def _flush(self, outbox: List[tuple]) -> None:
-        """Deliver tokens and terminal transitions in tick order.  Runs
+    def _flush(self, outbox: List[tuple]) -> int:
+        """Deliver tokens and terminal transitions in tick order;
+        returns the tokens delivered.  Runs
         at the end of the tick: pump mutex held (so streams stay ordered
         per request across concurrently pumping threads) but the state
         lock is NOT — a slow callback never blocks submit/cancel/stats.
         A raising callback fails only its own request (failure
         isolation): its row freezes, every other stream is untouched."""
         poisoned: set = set()
+        delivered = 0
         for ev in outbox:
             kind, req = ev[0], ev[1]
             if id(req) in poisoned or req.done.is_set():
@@ -1324,6 +1406,7 @@ class SlotScheduler:
                 toks, row = ev[2], ev[3]
                 try:
                     self._deliver(req, toks)
+                    delivered += len(toks)
                 except Exception as e:
                     poisoned.add(id(req))
                     if row is not None:
@@ -1332,6 +1415,7 @@ class SlotScheduler:
                     self._abort(req, "failed", error=e)
             else:                    # "finish"
                 self._finish(req)
+        return delivered
 
     # --------------------------------------------- degradation paths
 

@@ -464,53 +464,37 @@ class WatchdogHook(Hook):
 
 
 class TraceHook(Hook):
-    """Host-timeline spans for the training loop (``obs.trace``).
+    """Host-timeline tracing for the training loop (``obs.trace``):
+    activation, lifecycle instants and saving.
 
-    Per step this hook records a ``data_load`` span — the host gap from
-    the previous step's completion to this step's dispatch, which is
-    where batch fetch and hook work live — and a ``step`` span over the
-    whole ``run_step``.  The ``dispatch`` span nested inside comes from
-    ``TrainSession(telemetry=...)`` itself, ``checkpoint`` spans from
-    ``session.save()``, and jit compile/retrace instants from
-    ``analysis.sanitizer.RetraceGuard`` via the active tracer.  The
-    trace file is written at ``end`` AND ``close``, so a crashed run
-    still leaves its timeline on disk.
+    The spans themselves are recorded where the work happens, once
+    each: ``train.step`` (the whole ``run_step``) and ``train.dispatch``
+    (the compiled step's call) by ``TrainSession``,
+    ``data.prefetch_wait`` by ``data.prefetch_to_device``,
+    ``checkpoint`` by ``session.save()``, and jit compile/retrace
+    instants by ``analysis.sanitizer.RetraceGuard`` via the active
+    tracer.  This hook starts the telemetry (which activates its
+    tracer), marks ``session_begin`` / ``session_end``, and writes the
+    trace file at ``end`` AND ``close``, so a crashed run still leaves
+    its timeline on disk.
 
-    Step numbers in span args come from a host-side counter seeded once
-    at ``begin`` — reading ``session.step`` every step would pull the
-    device step scalar and block async dispatch.
+    Step numbers come from a host-side counter seeded once at ``begin``
+    — reading ``session.step`` every step would pull the device step
+    scalar and block async dispatch.
     """
 
     def __init__(self, telemetry, save_every_steps: int = 0):
         self.telemetry = telemetry
         self.save_every_steps = save_every_steps
         self._step = 0
-        self._gap_t0: Optional[float] = None
-        self._step_t0: Optional[float] = None
 
     def begin(self, session) -> None:
-        from ..obs import trace as obs_trace
         self.telemetry.start()
         self._step = session.step
         self.telemetry.tracer.instant("session_begin", step=self._step)
-        self._gap_t0 = obs_trace.now_us()
-
-    def before_step(self, session) -> None:
-        from ..obs import trace as obs_trace
-        now = obs_trace.now_us()
-        if self._gap_t0 is not None:
-            self.telemetry.tracer.add_span("data_load", self._gap_t0, now,
-                                           step=self._step + 1)
-        self._step_t0 = now
 
     def after_step(self, session, metrics) -> None:
-        from ..obs import trace as obs_trace
-        now = obs_trace.now_us()
         self._step += 1
-        if self._step_t0 is not None:
-            self.telemetry.tracer.add_span("step", self._step_t0, now,
-                                           step=self._step)
-        self._gap_t0 = now
         if self.save_every_steps and \
                 self._step % self.save_every_steps == 0:
             self.telemetry.save_trace()

@@ -19,13 +19,13 @@ from __future__ import annotations
 
 import logging
 import os
-import time
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..obs import goodput as goodput_lib
+from ..obs import trace as trace_lib
 from ..parallel import cluster
 from ..resilience import faults as faults_lib
 from . import checkpoint as ckpt_lib
@@ -87,13 +87,17 @@ class TrainSession:
         self.step_fn = step_fn
         self.checkpoint_dir = checkpoint_dir
         self.hooks = list(hooks)
-        # Optional obs.Telemetry: run_step wraps the compiled-step dispatch
-        # in a "dispatch" span and save() in a "checkpoint" span (+ a
-        # save-duration histogram).  Telemetry off = one attr check per
-        # step.  Pair with train.TraceHook/MetricsExportHook for the
-        # host-timeline and /metrics halves; the session never closes a
-        # user-provided telemetry object.
+        # Optional obs.Telemetry: entering the session starts it (its
+        # tracer becomes the active one, so run_step's "train.step" /
+        # "train.dispatch" spans and save()'s "checkpoint" span land on
+        # it) and save() feeds its save-duration histogram.  Pair with
+        # train.TraceHook/MetricsExportHook for the host-timeline and
+        # /metrics halves; the session never closes a user-provided
+        # telemetry object.
         self.telemetry = telemetry
+        # steps this session has dispatched: the spans' step index (the
+        # global step lives on the device; reading it would be a sync)
+        self._steps_run = 0
         self.is_chief = cluster.is_chief() if is_chief is None else is_chief
         self.max_to_keep = max_to_keep
         self.last_saved_step = None
@@ -145,29 +149,28 @@ class TrainSession:
 
     def run_step(self, *args, **kwargs) -> Dict[str, Any]:
         """One training step: hooks, compiled step fn, cursor advance."""
-        plan = faults_lib.active()
-        if plan is not None:
-            # chaos runs only: evaluating a step-indexed fault trigger
-            # reads the device step scalar (a host sync); with no plan
-            # active this is one module-global None check.
-            args = plan.on_step(self.step, args)
-        for hook in self.hooks:
-            hook.before_step(self)
-        # goodput "step" frame: with an active accountant this is where
-        # productive time accrues (a retrace inside the dispatch lands in
-        # "compile" instead — frames are exclusive); inactive = a cached
-        # no-op context manager
-        with goodput_lib.account("step"):
-            if self.telemetry is not None:
-                with self.telemetry.tracer.span("dispatch"):
-                    new_state, metrics = self.step_fn(self.state, *args,
-                                                      **kwargs)
-            else:
+        self._steps_run += 1
+        with trace_lib.span("train.step", step=self._steps_run):
+            plan = faults_lib.active()
+            if plan is not None:
+                # chaos runs only: evaluating a step-indexed fault
+                # trigger reads the device step scalar (a host sync);
+                # with no plan active this is one module-global None
+                # check.
+                args = plan.on_step(self.step, args)
+            for hook in self.hooks:
+                hook.before_step(self)
+            # goodput "step" frame == the "train.dispatch" span: with an
+            # active accountant this is where productive time accrues (a
+            # retrace inside the dispatch lands in "compile" instead —
+            # frames are exclusive); the call is async, so the span is
+            # the host's cost of a step, not the device's
+            with goodput_lib.account("step"):
                 new_state, metrics = self.step_fn(self.state, *args,
                                                   **kwargs)
-        self.state = new_state
-        for hook in self.hooks:
-            hook.after_step(self, metrics)
+            self.state = new_state
+            for hook in self.hooks:
+                hook.after_step(self, metrics)
         return metrics
 
     # -- checkpointing ----------------------------------------------------
@@ -176,15 +179,15 @@ class TrainSession:
         example.py:74-76); non-chief calls are no-ops — except in sharded
         mode, where EVERY process writes the chunks it owns and only the
         manifest is chief-only (inside save_sharded)."""
-        with goodput_lib.account("checkpoint_save"):
-            if self.telemetry is None:
-                return self._save_impl()
-            t0 = time.perf_counter()
-            with self.telemetry.tracer.span("checkpoint", step=self.step):
-                path = self._save_impl()
-            self.telemetry.checkpoint_seconds().observe(
-                time.perf_counter() - t0)
-            return path
+        # one frame: the goodput bucket, the "checkpoint" span and the
+        # save-duration histogram all read its one measurement
+        with goodput_lib.account("checkpoint_save",
+                                 measure=self.telemetry is not None,
+                                 step=self.step) as frame:
+            path = self._save_impl()
+        if self.telemetry is not None:
+            self.telemetry.checkpoint_seconds().observe(frame.duration_s)
+        return path
 
     def _save_impl(self) -> Optional[str]:
         if not self.checkpoint_dir:

@@ -343,9 +343,10 @@ def make_custom_train_step(loss_fn, optimizer: opt_lib.Optimizer,
         if grad_clip_norm is not None:
             grads, gnorm = opt_lib.clip_by_global_norm(grads, grad_clip_norm)
             metrics["grad_norm"] = gnorm
-        updates, new_opt_state = optimizer.update(grads, state.opt_state,
-                                                  state.params)
-        new_params = opt_lib.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = opt_lib.apply_updates(state.params, updates)
         if sn_finite is not None:
             # In-graph rollback: the NaN-contaminated candidates are
             # computed then discarded by the select — where() never
@@ -409,9 +410,10 @@ def make_1f1b_train_step(model, optimizer: opt_lib.Optimizer,
         if grad_clip_norm is not None:
             grads, gnorm = opt_lib.clip_by_global_norm(grads, grad_clip_norm)
             metrics["grad_norm"] = gnorm
-        updates, new_opt_state = optimizer.update(grads, state.opt_state,
-                                                  state.params)
-        new_params = opt_lib.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = opt_lib.apply_updates(state.params, updates)
         return TrainState(step=state.step + 1, params=new_params,
                           opt_state=new_opt_state,
                           model_state=state.model_state), metrics

@@ -228,6 +228,23 @@ def _resource_ledger_marker(request):
 
 
 # ---------------------------------------------------------------------------
+# No test leaves a tracer active behind it: an active ``obs.trace`` tracer
+# turns on request ids and span recording for whatever runs next in the
+# worker (the benchmark's traced rehearsals activate one by importing
+# ``harness/program_spans.py``, as a real ``--trace 1`` run does).
+
+@pytest.fixture(autouse=True)
+def _restore_active_tracer():
+    from distributed_tensorflow_tpu.obs import trace as obs_trace
+    before = obs_trace.active_tracer()
+    yield
+    if obs_trace.active_tracer() is not before:
+        obs_trace.deactivate()
+        if before is not None:
+            obs_trace.activate(before)
+
+
+# ---------------------------------------------------------------------------
 # Fault injection (resilience/faults.py, docs/RESILIENCE.md): chaos tests
 # activate a deterministic FaultPlan for their extent via
 #

@@ -114,8 +114,12 @@ class TestAccountant:
 
     def test_module_account_is_noop_when_inactive(self):
         goodput_lib.deactivate()
+        trace_lib.deactivate()
         frame = goodput_lib.account("step")
-        assert frame is goodput_lib._NULL_FRAME    # cached, zero alloc
+        # no accountant, no tracer: the site's span, which is the cached
+        # null span (zero alloc)
+        assert frame is trace_lib.span("train.dispatch")
+        assert frame is trace_lib._NULL_SPAN
         with frame:
             pass
 

@@ -397,16 +397,16 @@ def test_session_telemetry_end_to_end(tmp_path):
     names = {}
     for e in events:
         names[e["name"]] = names.get(e["name"], 0) + 1
-    assert names["dispatch"] == 4        # one per run_step, from session
-    assert names["step"] == 4            # TraceHook host-step spans
-    assert names["data_load"] == 4       # inter-step host gap spans
+    assert names["train.dispatch"] == 4  # one per run_step, from session
+    assert names["train.step"] == 4      # the whole run_step, likewise
+    assert "step" not in names and "data_load" not in names  # no seconds
     assert names["jit_compile"] >= 1     # first trace instant
     assert names["retrace"] == 1         # the shape-change recompile
     retrace = next(e for e in events if e["name"] == "retrace")
     assert "arg_diff" in retrace["args"]         # actionable, not forensic
     assert "[30,64]" in retrace["args"]["arg_diff"]
     steps_args = sorted(e["args"]["step"] for e in events
-                        if e["name"] == "step")
+                        if e["name"] == "train.step")
     assert steps_args == [1, 2, 3, 4]
 
     # (b) the live scrape carried the step counter + step-time histogram

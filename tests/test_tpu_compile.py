@@ -13,6 +13,7 @@ whether to interpret; the cases steer that by passing the kernels' own
 """
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
 
@@ -148,8 +149,25 @@ CASES = {
 }
 
 
+# every pallas_call carries a stable ``name=``: it names the kernel's
+# instruction in the compiled program (``%dttpu_paged_decode.1 = ...
+# custom-call``), which is the name its events carry in a device trace
+KERNEL_NAMES = {
+    "paged_decode": ("dttpu_paged_decode",),
+    "paged_window": ("dttpu_paged_window",),
+    "flash_forward": ("dttpu_flash_fwd",),
+    "flash_backward": ("dttpu_flash_fwd", "dttpu_flash_dkv",
+                       "dttpu_flash_dq"),
+    "flash_mesh": ("dttpu_flash_fwd", "dttpu_flash_dkv", "dttpu_flash_dq"),
+}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(topo, case):
     fn, args = CASES[case](topo)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert KERNEL_MARK in text, f"{case}: no Mosaic kernel in the program"
+    names = next(v for k, v in KERNEL_NAMES.items() if case.startswith(k))
+    for name in names:     # under grad: %jvp_<name>_.1, %transpose_jvp_<name>__.1
+        assert re.search(rf"%\S*{name}\S* = ", text), \
+            f"{case}: no instruction named after {name}"
