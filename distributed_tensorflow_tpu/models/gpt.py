@@ -32,7 +32,7 @@ from ..ops import attention as attn_lib
 from ..ops import initializers as init_lib
 from ..ops import losses as loss_lib
 from ..ops.moe import apply_moe, init_moe, moe_partition_rules
-from ..parallel.sharding import PartitionRules
+from ..parallel.sharding import PartitionRules, constrain_batch
 from .bert import _dropout, _layer_norm
 
 __all__ = ["GPTConfig", "GPT", "gpt_small", "gpt_tiny"]
@@ -283,6 +283,17 @@ class GPT:
         return params
 
     # -- blocks -----------------------------------------------------------
+    def _pin(self, x):
+        """The ``[b, s, d]`` stream of the full-sequence forward, pinned
+        batch-sharded over the mesh's data-parallel axes
+        (``parallel.sharding.constrain_batch``): ``fsdp`` divides the batch,
+        and the weights it stores are gathered at use.  Identity without a
+        mesh; pipeline stage bodies run under ``shard_map`` and are left
+        alone."""
+        if self.config.pipeline_stages > 1:
+            return x
+        return constrain_batch(x, self.mesh, seq_axis=self.config.seq_axis)
+
     def _norm(self, p, x):
         """Config-dispatched block norm: LayerNorm (GPT-2) or RMSNorm
         (Llama: f32 rms, gamma scale, no centering — matches HF
@@ -398,6 +409,7 @@ class GPT:
 
     def _block(self, p, x, mask, rng, train, qk_transform=None):
         c = self.config
+        x = self._pin(x)    # inside the remat: the recomputed forward too
         r_attn, r_res, r_moe, r_drop = jax.random.split(rng, 4)
         attn_out = self._attention(
             p["attention"], self._norm(p["ln_1"], x),
@@ -444,7 +456,8 @@ class GPT:
             rng = jax.random.PRNGKey(0)
         s = input_ids.shape[1]
         r_emb, r_layers = jax.random.split(rng)
-        x = self._embed(params["embeddings"], input_ids, r_emb, train)
+        x = self._pin(
+            self._embed(params["embeddings"], input_ids, r_emb, train))
         layer_fn = self._make_layer_fn(s)
         layer_keys = jax.random.split(r_layers, c.num_layers)
         if c.pipeline_stages > 1:
@@ -468,7 +481,7 @@ class GPT:
             x, aux_per_layer = lax.scan(body, x,
                                         (params["decoder"], layer_keys))
             aux_total = jnp.sum(aux_per_layer)
-        hidden = self._norm(params["ln_f"], x)
+        hidden = self._pin(self._norm(params["ln_f"], x))
         if return_aux:
             return hidden, aux_total
         return hidden

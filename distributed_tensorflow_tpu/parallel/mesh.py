@@ -28,12 +28,16 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["MeshConfig", "make_mesh", "data_parallel_mesh", "AXIS_ORDER",
-           "named_sharding", "replicated", "local_batch_size"]
+           "BATCH_AXES", "named_sharding", "replicated", "local_batch_size"]
 
 # Fixed major-to-minor order: pipe outermost (cross-slice / DCN friendly),
 # then the data-like axes, with tensor parallelism innermost so it rides the
 # fastest ICI links (scaling-book recipe: TP wants the tightest torus links).
 AXIS_ORDER: Sequence[str] = ("pipe", "data", "fsdp", "expert", "seq", "tensor")
+
+# The data-parallel axes: both divide the batch.  ``fsdp`` is a BATCH axis
+# for activations and a storage axis for parameters (ZeRO-3).
+BATCH_AXES: Sequence[str] = ("data", "fsdp")
 
 
 class MeshConfig(dict):
@@ -96,7 +100,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
 
 
-def data_shards(mesh: Mesh, axes: Sequence[str] = ("data", "fsdp")) -> int:
+def data_shards(mesh: Mesh, axes: Sequence[str] = BATCH_AXES) -> int:
     """Number of ways the batch dim is split on this mesh."""
     shard = 1
     for a in axes:
@@ -106,7 +110,7 @@ def data_shards(mesh: Mesh, axes: Sequence[str] = ("data", "fsdp")) -> int:
 
 
 def round_batch_to_mesh(global_batch: int, mesh: Mesh,
-                        axes: Sequence[str] = ("data", "fsdp")) -> int:
+                        axes: Sequence[str] = BATCH_AXES) -> int:
     """Smallest batch >= global_batch divisible by the mesh's data shards.
 
     The reference's batch of 50 (example.py:13) does not shard over 8 chips;
@@ -117,12 +121,9 @@ def round_batch_to_mesh(global_batch: int, mesh: Mesh,
 
 
 def local_batch_size(global_batch: int, mesh: Mesh,
-                     axes: Sequence[str] = ("data", "fsdp")) -> int:
+                     axes: Sequence[str] = BATCH_AXES) -> int:
     """Per-process batch share for building host-local input pipelines."""
-    shard = 1
-    for a in axes:
-        if a in mesh.shape:
-            shard *= mesh.shape[a]
+    shard = data_shards(mesh, axes)
     if global_batch % shard:
         raise ValueError(
             f"global batch {global_batch} not divisible by data shards {shard}")

@@ -15,13 +15,15 @@ Conventions (scaling-book recipe):
 from __future__ import annotations
 
 import re
-from typing import Any, List, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .mesh import BATCH_AXES, data_shards
+
 __all__ = ["PartitionRules", "tree_paths", "shard_pytree",
-           "logical_to_mesh", "prune_spec"]
+           "logical_to_mesh", "prune_spec", "constrain_batch"]
 
 Rules = Sequence[Tuple[str, P]]
 
@@ -44,6 +46,37 @@ def prune_spec(spec: P, mesh: Mesh) -> P:
         return kept if kept else None
 
     return P(*(keep(e) for e in spec))
+
+
+def constrain_batch(x, mesh: Optional[Mesh], seq_axis: Optional[str] = None):
+    """Pin activation ``x`` ``[batch, seq, ...]`` to batch-sharded over the
+    mesh's data-parallel axes, every other dim replicated (``seq`` over
+    ``seq_axis`` where the model runs ring attention).
+
+    Parameters stored ``fsdp``-sharded on a contracting dim leave GSPMD two
+    consistent layouts for an activation: batch on ``fsdp`` (from the input)
+    or hidden on ``fsdp`` (from the weight).  Unpinned it picks the second:
+    the batch is replicated, every chip runs all of attention, and matmul
+    outputs are all-reduced at whole-batch size.  Pinned, the only
+    resolution left is ZeRO-3: all-gather each layer's weights at use,
+    reduce-scatter their gradients, each chip computing its own sequences.
+
+    Follows from what it can observe, no knob: axes the mesh lacks or holds
+    at size 1 drop out, so without a batch axis > 1 (or without a mesh) this
+    is the identity and the traced program is unchanged; a batch the axes do
+    not divide (a 2-sequence eval on four chips) is left to propagation.
+    """
+    if mesh is None:
+        return x
+    batch = tuple(a for a in BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if not batch or x.shape[0] % data_shards(mesh):
+        return x
+    seq = None
+    if (seq_axis is not None and mesh.shape.get(seq_axis, 1) > 1
+            and x.shape[1] % mesh.shape[seq_axis] == 0):
+        seq = seq_axis
+    spec = P(batch, seq, *([None] * (x.ndim - 2)))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def tree_paths(tree) -> List[str]:

@@ -171,3 +171,54 @@ def test_kernel_compiles_for_v5e(topo, case):
     for name in names:     # under grad: %jvp_<name>_.1, %transpose_jvp_<name>__.1
         assert re.search(rf"%\S*{name}\S* = ", text), \
             f"{case}: no instruction named after {name}"
+
+
+# GPT-2-small widths, the benchmark's four-chip train deployment in small
+FSDP_BATCH, FSDP_SEQ = 8, 1024
+
+
+def test_fsdp_train_step_is_zero3_on_v5e(topo):
+    """The fsdp train step (``shard_train_state`` placements, batch over
+    ``("data", "fsdp")``, plain-jit ``make_custom_train_step``) compiled for
+    the described four chips: weights are all-gathered at use, no all-reduce
+    carries a whole-batch activation, attention runs at the local batch."""
+    from hlo_collectives import activation_allreduces, collectives
+
+    from distributed_tensorflow_tpu import optim, parallel, train
+    from distributed_tensorflow_tpu.models.gpt import GPT, GPTConfig
+
+    mesh = parallel.make_mesh({"data": 1, "fsdp": 4}, devices=topo.devices)
+    model = GPT(GPTConfig(max_position=FSDP_SEQ, dropout_rate=0.0,
+                          dtype=jnp.bfloat16, remat=True), mesh=mesh)
+    optimizer = optim.adamw(1e-4)
+
+    def make_state(key):
+        p = model.init(key)
+        return train.TrainState.create(p, optimizer.init(p))
+
+    abstract = jax.eval_shape(make_state, jax.random.PRNGKey(0))
+    param_sh = model.partition_rules(fsdp=True).tree_shardings(
+        mesh, abstract.params)
+    replicated = NamedSharding(mesh, P())
+    moments = {k: param_sh for k in abstract.opt_state.inner}
+    shardings = jax.tree.map(lambda _: replicated, abstract)._replace(
+        params=param_sh, opt_state=abstract.opt_state._replace(
+            count=replicated, inner=moments))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, shardings)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (FSDP_BATCH, FSDP_SEQ + 1), jnp.int32,
+        sharding=NamedSharding(mesh, P(("data", "fsdp"))))}
+    step = train.make_custom_train_step(model.lm_loss_fn(), optimizer,
+                                        grad_clip_norm=1.0)
+    text = step.lower(state, batch).compile().as_text()
+
+    assert not activation_allreduces(text, FSDP_BATCH, FSDP_SEQ)
+    gathered = [s for op, shapes in collectives(text) if op == "all-gather"
+                for s in shapes]
+    assert any(s[-2:] == (768, 3072) for s in gathered), gathered  # w_in
+    local = FSDP_BATCH // 4
+    assert re.search(rf"\[{local},{HEADS},{FSDP_SEQ},{FSDP_SEQ}\]", text)
+    assert not re.search(
+        rf"\[{FSDP_BATCH},{HEADS},{FSDP_SEQ},{FSDP_SEQ}\]", text)
