@@ -1,4 +1,6 @@
-"""The plain reference: GPT-2's forward pass and LM loss in float32.
+"""The plain reference of family ``gpt2``: GPT-2's forward pass and LM loss
+in float32 (``families/gpt2.py`` names this file; the drivers reach it through
+the family and nowhere else).
 
 Straightforward ``jax.numpy`` following Radford et al. 2019 / the public
 ``GPT2LMHeadModel``: learned token + position embeddings, pre-LN blocks of
@@ -51,8 +53,11 @@ def _block(p, x, eps):
     return x + h @ f["w_out"]["kernel"] + f["w_out"]["bias"]
 
 
-def logits(params, input_ids, eps: float = 1e-5):
-    """``[b, s]`` token ids -> ``[b, s, vocab]`` float32 logits."""
+def logits(params, input_ids, config):
+    """``[b, s]`` token ids -> ``[b, s, vocab]`` float32 logits.  ``config``
+    is the configuration file: of it the reference reads the one number the
+    weights do not carry, ``layer_norm_epsilon``."""
+    eps = config["layer_norm_epsilon"]
     with jax.default_matmul_precision("highest"):
         emb = params["embeddings"]
         word = emb["word"].astype(F32)
@@ -70,3 +75,28 @@ def token_losses(lg, targets):
     ``ids[:, :-1]`` and its targets ``ids[:, 1:]``."""
     logp = jax.nn.log_softmax(lg.astype(F32), axis=-1)
     return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
+def tail_logits(params, input_ids, config, count: int):
+    """The logits of the last ``count`` positions, as a host array
+    ``[b, count, vocab]``: what the serving probe is compared with.  The
+    whole ``[b, s, vocab]`` is computed and cut on the host (200 positions
+    here); a family with long contexts applies its head to the tail alone."""
+    import numpy as np
+    whole = jax.jit(lambda p, ids: logits(p, ids, config))(params, input_ids)
+    return np.asarray(whole)[:, -count:]
+
+
+def top2(params, input_ids, config):
+    """At every position the two largest logits ``[b, s, 2]`` and the id of
+    the largest ``[b, s]``, as host arrays: what the emitted tokens are
+    compared with, and how far apart the reference's first two choices
+    lie."""
+    import numpy as np
+
+    def both(p, ids):
+        values, indices = jax.lax.top_k(logits(p, ids, config), 2)
+        return values, indices[..., 0]
+
+    values, best = jax.jit(both)(params, input_ids)
+    return np.asarray(values), np.asarray(best)

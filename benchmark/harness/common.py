@@ -30,13 +30,16 @@ class Run:
 @dataclasses.dataclass
 class Outcome:
     """What a driver returns: the end-to-end values it took itself, the
-    record the per-layer readers read, and the facts of the last line."""
+    record the per-layer readers read, and the facts of the last line.
+    ``compared`` is every number that decided ``correct`` beside its limit:
+    ``{name: {"value": v, "limit": l, "holds": "<=" or ">="}}``."""
     correct: bool
     attempted: int
     failed: int
     end_to_end: Dict[str, Optional[float]]
     record: Dict[str, Any]
     memory: Dict[str, Any]
+    compared: Dict[str, Dict[str, float]]
     reduced: Optional[trace_lib.Reduced] = None
     per_layer: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
@@ -47,27 +50,6 @@ def prng_key(seed: int):
     import jax
     return jax.random.fold_in(jax.random.PRNGKey(seed >> 31),
                               seed & 0x7FFFFFFF)
-
-
-def gpt_config(config: Dict[str, Any]):
-    """The program's ``GPTConfig`` from a configuration file's HF GPT-2
-    keys.  Only what defines the model is passed: every tuning knob the
-    program has a default for (flash thresholds, loss chunking, fused
-    norms, remat policy) keeps that default, so a later PR that finds a
-    better one is measured."""
-    import jax.numpy as jnp
-    from distributed_tensorflow_tpu.models.gpt import GPTConfig
-    if config["activation_function"] != "gelu_new":
-        raise ValueError("the reference implements GPT-2's gelu_new only")
-    assumed = config["assumed"]
-    return GPTConfig(
-        vocab_size=config["vocab_size"], hidden_size=config["n_embd"],
-        num_layers=config["n_layer"], num_heads=config["n_head"],
-        intermediate_size=config.get("n_inner") or 4 * config["n_embd"],
-        max_position=config["n_positions"],
-        layer_norm_eps=config["layer_norm_epsilon"],
-        dtype=jnp.dtype(assumed["compute_dtype"]),
-        dropout_rate=assumed["dropout"], remat=assumed["remat"])
 
 
 def parts(t0: float, marks) -> Dict[str, float]:

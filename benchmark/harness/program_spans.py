@@ -12,7 +12,8 @@ The driver's ``record`` carries durations, not instants, so the window is
 found by its shape: the ``len(record["tick_seconds"])`` consecutive
 ``serve.tick`` spans before the traced segment's ticks (the trailing ticks
 whose extent fits ``trace.window_s``) — or, training, the readings' worth of
-``train.step`` spans before the traced readings — and then VERIFIED one by
+``train.step`` spans before the ``record["traced_steps"]`` steps the driver
+dispatched in the traced segment — and then VERIFIED one by
 one against the driver's own list: the program's tick lies inside the
 benchmark's, shorter by under 2 ms; a reading's first step starts where the
 reading before it ended.  Ticks differ 2.8-fold by what they hold, so a
@@ -149,13 +150,24 @@ def _reading_anchor(spans: Sequence, waits: Sequence[int], step: int,
 
 
 def select_steps(spans: Sequence, reading_seconds: Sequence[float],
-                 steps_per_reading: int, traced_window_s: float
+                 steps_per_reading: int, traced_steps: int
                  ) -> Tuple[Optional[List[int]], str]:
     """Indices of the ``train.step`` spans of the benchmark's window, or
     (None, why).  Readings follow one another without a gap, so reading
     k+1's first step starts ``reading_seconds[k]`` after reading k's did
     (to TOLERANCE_S), and the traced segment's first step no earlier than
-    that after the last one's."""
+    that after the last one's.
+
+    The first choice skips the ``traced_steps`` steps the driver says it
+    dispatched after the window.  It was the trailing steps whose extent
+    fits the traced window; but that window ends with the fetch of a step
+    dispatched a reading earlier, so it reaches a reading's length past the
+    last dispatch, and counted back from there it holds the window's last
+    reading too unless the profiler took longer to start than the steps are
+    apart: true on the chip (by twice that start-up), not of a cell whose
+    steps last milliseconds.  Steady readings are alike to under TOLERANCE_S,
+    so the verification cannot tell a choice one reading early from the
+    right one; the first choice has to be right, and a count is."""
     steps = _closed(spans, STEP)
     waits = _closed(spans, PREFETCH_WAIT)
     per = int(steps_per_reading)
@@ -187,8 +199,7 @@ def select_steps(spans: Sequence, reading_seconds: Sequence[float],
                         f"{inside:.6f} s, benchmark {outside:.6f} s")
         return None
 
-    skip, why = _search(len(steps), need,
-                        _trailing(spans, steps, traced_window_s * 1e6), check)
+    skip, why = _search(len(steps), need, int(traced_steps), check)
     if skip is None:
         return None, why
     lo = len(steps) - skip - need
@@ -226,7 +237,7 @@ def window(record: Dict[str, Any], trace) -> Optional[Window]:
         else:
             units, why = select_steps(spans, record["reading_seconds"],
                                       record["steps_per_reading"],
-                                      trace.window_s)
+                                      record["traced_steps"])
         if units is None:
             _say(f"window NOT verified, no metric reported: {why}")
         else:

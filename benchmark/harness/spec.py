@@ -3,9 +3,12 @@
 A cell is ``{name, config, traffic, chips}``.  Its configuration is the
 ``file`` of the ``configs`` entry; its traffic mix is ``traffic/<traffic>.json``,
 whose ``generator`` names ``generators/<generator>.py``; a per-layer metric
-``m`` is read by ``layer_metrics/<m>.py``.  The three directories are looked
-for under each of ``paths`` in turn, so a later PR adds a mix, a generator or
-a metric by adding a file and an entry, and edits nothing.
+``m`` is read by ``layer_metrics/<m>.py``; the configuration file's ``family``
+names ``families/<family>.py``, which holds everything about the cell that
+depends on the model and names the file of its plain reference beside it.
+The directories are looked for under each of ``paths`` in turn, so a later PR
+adds a mix, a generator, a metric or a model family by adding files and
+entries, and edits nothing.
 """
 from __future__ import annotations
 
@@ -35,6 +38,26 @@ class Cell:
     traffic: Dict[str, Any]         # the traffic file
     end_to_end: List[Dict[str, Any]]   # metric entries this cell reports
     per_layer: List[Dict[str, Any]]
+
+
+# What a family file has to define (``families/gpt2.py`` says what each is).
+FAMILY_NAMES = ("REFERENCE", "TOLERANCES", "build_model", "vocab_size",
+                "forward_logits", "shard_witness", "kernel_expected",
+                "train_flops_per_token", "serve_flops_per_token",
+                "serve_probe")
+REFERENCE_NAMES = ("logits", "token_losses", "tail_logits", "top2")
+TOLERANCE_NAMES = ("logit", "min_agreement", "loss", "token_loss")
+
+
+class Family:
+    """``families/<name>.py`` and the reference file it names: attribute
+    access falls through to the family's module."""
+
+    def __init__(self, name: str, module, reference):
+        self.name, self.module, self.reference = name, module, reference
+
+    def __getattr__(self, attr: str):
+        return getattr(self.module, attr)
 
 
 class Benchmark:
@@ -103,9 +126,32 @@ class Benchmark:
     def generator(self, cell: Cell):
         return self.load_module("generators", cell.traffic["generator"])
 
+    def family(self, cell: Cell) -> Family:
+        """The model family the cell's configuration file names."""
+        name = cell.config.get("family")
+        if not isinstance(name, str):
+            raise SpecError(f"the configuration {cell.config_name!r} names "
+                            "no \"family\"")
+        module = self.load_module("families", name)
+        _require(module, FAMILY_NAMES, f"families/{name}.py")
+        _require(module.TOLERANCES, TOLERANCE_NAMES,
+                 f"TOLERANCES of families/{name}.py")
+        reference = self.load_module("families", module.REFERENCE)
+        _require(reference, REFERENCE_NAMES,
+                 f"families/{module.REFERENCE}.py")
+        return Family(name, module, reference)
+
     def layer_reader(self, metric_name: str):
         """The ``read(record, trace) -> float | None`` of one metric."""
         return self.load_module("layer_metrics", metric_name).read
+
+
+def _require(holder, names, where: str) -> None:
+    has = holder.__contains__ if isinstance(holder, dict) else (
+        lambda n: hasattr(holder, n))
+    missing = [n for n in names if not has(n)]
+    if missing:
+        raise SpecError(f"{where} lacks {', '.join(missing)}")
 
 
 def select(entries: List[Dict[str, Any]], values: Dict[str, Optional[float]]

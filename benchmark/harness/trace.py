@@ -7,7 +7,9 @@ Two halves, kept apart so the arithmetic can be checked on hand-made lists:
   the same clock, and the window.  It gives busy time (the union of the
   operations' intervals, so nested and overlapping events count once), the
   idle share, the time in collectives and in custom calls (Mosaic kernels),
-  the operations that took most time (self time: a ``while`` that contains
+  the time and the event count of EVERY operation the program named as one
+  of its kernels (``dttpu_*``, by kernel name), the operations that took
+  most time (self time: a ``while`` that contains
   its body's operations is charged only what they leave), and the idle gaps
   bucketed by the host span that covers most of each.
 * ``from_profile(profile)`` — the adapter from ``jax.profiler.ProfileData``:
@@ -30,6 +32,7 @@ Span = Tuple[str, float, float]          # name, start_ns, end_ns
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 CUSTOM_CALL = "custom-call"
+KERNEL_PREFIX = "dttpu_"      # the program's kernels carry their names (PR 26)
 SHORT_GAP_NS = 20_000.0
 SHORT_GAP_BUCKET = "between_ops_under_20us"
 WINDOW_SPAN = "trace_window"
@@ -53,6 +56,10 @@ class Reduced:
     device_ops: List[Tuple[str, float]]  # name, seconds (mean over devices)
     idle_gaps: List[Tuple[str, float]]   # bucket, seconds (mean over devices)
     devices: int
+    # every ``dttpu_*`` kernel, not only those among ``device_ops``: self
+    # seconds and events in the window by kernel name, mean over devices
+    kernel_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    kernel_calls: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def idle_share(self) -> Optional[float]:
@@ -68,6 +75,18 @@ def is_collective(name: str) -> bool:
 
 def is_custom_call(name: str) -> bool:
     return CUSTOM_CALL in name.lower()
+
+
+_INSTANCE = re.compile(r"\.\d+$")
+
+
+def kernel_name(name: str) -> Optional[str]:
+    """``dttpu_paged_decode.4 custom-call bf16[8,1,25,64]`` ->
+    ``dttpu_paged_decode``: the kernel an operation is an instance of, or
+    None for an operation that is not one of the program's kernels."""
+    if not name.startswith(KERNEL_PREFIX):
+        return None
+    return _INSTANCE.sub("", name.split(" ", 1)[0])
 
 
 def _clip(ops: Sequence[Op], lo: float, hi: float) -> List[Op]:
@@ -125,6 +144,8 @@ def reduce(trace: Trace) -> Reduced:
     n = max(1, len(trace.devices))
     busy = coll = custom = 0.0
     by_name: Dict[str, float] = {}
+    kernel_ns: Dict[str, float] = {}
+    kernel_events: Dict[str, int] = {}
     gaps: Dict[str, float] = {}
     host = [s for s in trace.host_spans if s[0] != WINDOW_SPAN]
     for ops in trace.devices.values():
@@ -137,6 +158,10 @@ def reduce(trace: Trace) -> Reduced:
                 coll += ns
             elif is_custom_call(name):
                 custom += ns
+            kernel = kernel_name(name)
+            if kernel is not None:
+                kernel_ns[kernel] = kernel_ns.get(kernel, 0.0) + ns
+                kernel_events[kernel] = kernel_events.get(kernel, 0) + 1
         edge = lo
         for a, b in merged + [(hi, hi)]:
             if a > edge:
@@ -152,7 +177,11 @@ def reduce(trace: Trace) -> Reduced:
                    collective_s=coll / n / 1e9,
                    custom_call_s=custom / n / 1e9,
                    device_ops=top(by_name), idle_gaps=top(gaps),
-                   devices=len(trace.devices))
+                   devices=len(trace.devices),
+                   kernel_s={k: v / n / 1e9
+                             for k, v in sorted(kernel_ns.items())},
+                   kernel_calls={k: v / n
+                                 for k, v in sorted(kernel_events.items())})
 
 
 # ------------------------------------------------------------- the adapter

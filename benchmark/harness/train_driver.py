@@ -25,24 +25,11 @@ from typing import Any, Dict, List
 
 from . import common
 from . import device as device_lib
-from . import flops as flops_lib
 from . import readings as readings_lib
-from . import reference
 
-# The program's bf16 compute against the float32 reference on 2 sequences of
-# the batch, two ways.  (1) The mean loss of ``lm_loss_fn``, the function the
-# step differentiates: rounding errors average out over 2048 tokens, and the
-# largest |diff| of 28 runs over 6 seeds on the v5e was 2.1e-4 at GPT-2-medium
-# and 4.9e-4 at GPT-2-XL (my chip runs, PR 24); the tolerance is four times
-# that.  (2) Every token's own loss from the program's forward pass and head
-# (``GPT.apply``, ``GPT.logits``), max-abs over the 2 x seq positions: a mean
-# near ln(vocab) hides a wrong mask or position table, a single position
-# cannot.  On the float32 reference at GPT-2-medium, 2 x 256 tokens, dropping
-# the causal mask moves the mean by 0.0099 and single positions by up to
-# 1.49; a position table shifted by one row 0.0005 and 0.93; bf16 rounding
-# 0.00004 and 0.030 (CPU arithmetic, PR 24).
-LOSS_TOL = 2e-3
-TOKEN_LOSS_TOL = 0.1
+# Sequences of the batch on which the program's loss and every token's loss
+# are compared with the family's plain reference; the tolerances, with the
+# measurements they were set from, are the family's.
 CHECK_SEQUENCES = 2
 
 
@@ -74,20 +61,20 @@ def _state_shardings(abstract_state, mesh, rules):
     return place(abstract_state)
 
 
-def run(run: common.Run, cell, generator) -> common.Outcome:
+def run(run: common.Run, cell, generator, family) -> common.Outcome:
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from distributed_tensorflow_tpu import data, optim, parallel, train
-    from distributed_tensorflow_tpu.models.gpt import GPT
 
     config, params_t = cell.config, cell.traffic["params"]
     deployment = config["train"]
+    tol, reference = family.TOLERANCES, family.reference
     mesh = parallel.make_mesh(dict(deployment["mesh"]), devices=run.devices)
     fsdp = mesh.shape.get("fsdp", 1) > 1
-    model = GPT(common.gpt_config(config), mesh=mesh)
+    model = family.build_model(config, mesh=mesh)
     optimizer = optim.adamw(deployment["learning_rate"])
     rules = model.partition_rules(fsdp=fsdp)
     batch, seq = params_t["global_batch"], params_t["seq_len"]
@@ -111,7 +98,8 @@ def run(run: common.Run, cell, generator) -> common.Outcome:
         grad_clip_norm=deployment["grad_clip_norm"])
 
     # ---- inputs: a host array from the seed, through the input pipeline
-    tokens = generator.generate(params_t, run.seed, config["vocab_size"])
+    tokens = generator.generate(params_t, run.seed,
+                                family.vocab_size(config))
     dataset = data.Dataset([tokens], batch_size=batch, shuffle=True,
                            seed=run.seed % (2 ** 32))
     batch_sharding = NamedSharding(
@@ -124,20 +112,19 @@ def run(run: common.Run, cell, generator) -> common.Outcome:
     # on the cell's own weights, before the first step donates them
     sample = tokens[:CHECK_SEQUENCES]
     loss_fn = model.lm_loss_fn()
-    eps = config["layer_norm_epsilon"]
 
     def check(p, ids):
         system = loss_fn(p, (), {"input_ids": ids}, None, False)[0]
         system_tokens = reference.token_losses(
-            model.logits(p, model.apply(p, ids[:, :-1])), ids[:, 1:])
+            family.forward_logits(model, p, ids[:, :-1]), ids[:, 1:])
         reference_tokens = reference.token_losses(
-            reference.logits(p, ids[:, :-1], eps), ids[:, 1:])
+            reference.logits(p, ids[:, :-1], config), ids[:, 1:])
         return (system, jnp.mean(reference_tokens),
                 jnp.max(jnp.abs(system_tokens - reference_tokens)))
 
     system_loss, reference_loss, token_loss_err = (
         float(x) for x in jax.jit(check)(state.params, sample))
-    kernel = state.params["decoder"]["attention"]["query"]["kernel"]
+    kernel = family.shard_witness(state.params)
     shard_devices = len({s.device for s in kernel.addressable_shards})
     shard_elems = math.prod(kernel.addressable_shards[0].data.shape)
     sharded_ok = (shard_devices == len(run.devices)
@@ -213,15 +200,16 @@ def run(run: common.Run, cell, generator) -> common.Outcome:
     run.emit({"memory": memory})
 
     checks = {
-        "loss_matches_reference": abs(system_loss - reference_loss) <= LOSS_TOL,
-        "token_losses_match_reference": token_loss_err <= TOKEN_LOSS_TOL,
+        "loss_matches_reference": (abs(system_loss - reference_loss)
+                                   <= tol["loss"]),
+        "token_losses_match_reference": token_loss_err <= tol["token_loss"],
         "window_losses_finite": bool(np.all(np.isfinite(window_losses))),
         "every_device_holds_a_param_shard": sharded_ok,
     }
     run.emit({"checks": checks, "system_loss": system_loss,
-              "reference_loss": reference_loss, "loss_tol": LOSS_TOL,
+              "reference_loss": reference_loss, "loss_tol": tol["loss"],
               "token_loss_max_abs_err": token_loss_err,
-              "token_loss_tol": TOKEN_LOSS_TOL,
+              "token_loss_tol": tol["token_loss"],
               "param_shard_devices": shard_devices})
 
     record: Dict[str, Any] = {
@@ -233,13 +221,26 @@ def run(run: common.Run, cell, generator) -> common.Outcome:
         "steps_per_reading": steps_per_reading,
         "span_seconds": span_s, "window_s": measured_end - window_start,
         "tokens_per_s": rate,
-        "flops_per_token": flops_lib.train_flops_per_token(config, seq),
+        "flops_per_token": family.train_flops_per_token(config, seq),
+        "traced_steps": (params_t["trace_readings"] * steps_per_reading
+                         if run.trace else 0),
         "memory": memory,
+    }
+    failed = sum(1 for x in window_losses if not math.isfinite(x))
+    compared = {
+        "loss_abs_diff": {"value": abs(system_loss - reference_loss),
+                          "limit": tol["loss"], "holds": "<="},
+        "token_loss_max_abs_err": {"value": token_loss_err,
+                                   "limit": tol["token_loss"], "holds": "<="},
+        "window_losses_not_finite": {"value": failed, "limit": 0,
+                                     "holds": "<="},
+        "param_shard_devices": {"value": shard_devices,
+                                "limit": len(run.devices), "holds": ">="},
     }
     return common.Outcome(
         correct=all(checks.values()),
         attempted=len(measured) * steps_per_reading,
-        failed=sum(1 for x in window_losses if not math.isfinite(x)),
+        failed=failed,
         end_to_end={"train_tokens_per_s": rate,
                     "setup_s": window_start - run.t0},
-        record=record, memory=memory, reduced=reduced)
+        record=record, memory=memory, compared=compared, reduced=reduced)

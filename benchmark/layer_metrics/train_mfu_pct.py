@@ -1,5 +1,6 @@
-"""Model FLOP/s utilisation: ``train_tokens_per_s`` x (6N + 12 L h s) over
-chips x the published bf16 peak.  Recomputation is not counted.  A CPU has
+"""Model FLOP/s utilisation: ``train_tokens_per_s`` x the family's
+``train_flops_per_token`` (gpt2: 6N + 12 L h s) over chips x the published
+bf16 peak.  Recomputation is not counted.  A CPU has
 no row in the table of peaks and reports nothing; an accelerator that is
 not in the table is an error."""
 from harness import peaks

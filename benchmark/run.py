@@ -4,8 +4,9 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything about the cell is data: ``BENCHMARK.json`` names the cell's
-configuration and traffic mix and the metrics it reports, and the files under
-``benchmark/`` are found by those names (``harness/spec.py``).  One process
+configuration and traffic mix and the metrics it reports, the configuration
+file names its model family, and the files under ``benchmark/`` are found by
+those names (``harness/spec.py``).  One process
 runs the cell, on the machine it is started on; JAX is imported here and
 nowhere before.  Human text and the program's own output go to stderr;
 stdout carries JSON lines only, the last of which is the result.  Without an
@@ -55,7 +56,8 @@ def log(msg: str) -> None:
 def result_line(cell, outcome: common.Outcome, devices, trace: bool) -> dict:
     """The contract's last line.  ``--trace 0``: the cell's end-to-end
     metrics; ``--trace 1``: its per-layer metrics, the device's busy time
-    and the breakdown."""
+    and the breakdown.  ``compared`` comes last: every number that decided
+    ``correct`` beside its limit."""
     described = device.describe(devices, outcome.memory["memory_peak_bytes"])
     line = {"correct": bool(outcome.correct),
             "attempted": int(outcome.attempted),
@@ -63,6 +65,7 @@ def result_line(cell, outcome: common.Outcome, devices, trace: bool) -> dict:
     if not trace:
         line["metrics"] = spec.select(cell.end_to_end, outcome.end_to_end)
         line["device"] = described
+        line["compared"] = outcome.compared
         return line
     line["metrics"] = outcome.per_layer
     reduced = outcome.reduced
@@ -72,6 +75,7 @@ def result_line(cell, outcome: common.Outcome, devices, trace: bool) -> dict:
     line["breakdown"] = {
         "device_ops": [[n, s] for n, s in reduced.device_ops],
         "idle_gaps": [[n, s] for n, s in reduced.idle_gaps]}
+    line["compared"] = outcome.compared
     return line
 
 
@@ -91,6 +95,7 @@ def main(argv, out, *, root: str = ROOT, rehearse_on_cpu: bool = False,
     cell = bench.cell(args.workload)
     kind = cell.traffic["kind"]
     generator = bench.generator(cell)
+    family = bench.family(cell)
     readers = {m["name"]: bench.layer_reader(m["name"])
                for m in cell.per_layer} if args.trace else {}
 
@@ -117,18 +122,23 @@ def main(argv, out, *, root: str = ROOT, rehearse_on_cpu: bool = False,
             compiles=compiles, emit=emit, trace_dir=trace_dir)
         emit({"workload": cell.name, "seed": args.seed,
               "seconds": args.seconds, "trace": args.trace,
+              "family": family.name,
               "device_kind": devices[0].device_kind,
               "devices": len(devices)})
-        outcome = driver.run(run, cell, generator)
+        outcome = driver.run(run, cell, generator, family)
     shutil.rmtree(trace_dir, ignore_errors=True)
 
     if args.trace:
         if outcome.reduced is None or not outcome.reduced.devices:
             raise RuntimeError("the traced segment recorded no device plane")
+        emit({"kernel_s": outcome.reduced.kernel_s,
+              "kernel_calls": outcome.reduced.kernel_calls})
         values = {name: read(outcome.record, outcome.reduced)
                   for name, read in readers.items()}
         outcome.per_layer = spec.select(cell.per_layer, values)
     emit(result_line(cell, outcome, devices, bool(args.trace)))
+    for name, c in outcome.compared.items():      # stderr's last lines
+        log(f"compared {name}: {c['value']!r} {c['holds']} {c['limit']!r}")
     return 0
 
 

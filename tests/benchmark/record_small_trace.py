@@ -2,13 +2,18 @@
 """Record the small trace kept beside the tests (run on the chip, by hand).
 
     chiprun --chips 1 -- python3 tests/benchmark/record_small_trace.py
+    chiprun --chips 1 -- python3 tests/benchmark/record_small_trace.py \
+        dttpu_small_double
 
 A few calls of a tiny program — matmuls inside a ``fori_loop`` (so the trace
 has a ``while`` that contains its body's operations) and one Pallas kernel (a
 custom call) — under the harness's own spans and profiler settings.  Writes
 ``chiprun_out/small_trace/small_trace.xplane.pb`` (tens of KB) and prints
 what the reducer makes of it; the numbers pinned in
-``test_benchmark_trace.py`` are that output.
+``test_benchmark_trace.py`` are that output.  With a kernel name as its
+argument the Pallas kernel carries that name, as the program's kernels carry
+theirs (``dttpu_*``), and the file is ``named_kernel_trace.xplane.pb``: the
+trace on which ``kernel_s`` has something to sum.
 """
 import glob
 import json
@@ -32,11 +37,17 @@ def _double_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...] * 2.0
 
 
+KERNEL_NAME = sys.argv[1] if len(sys.argv) > 1 else None
+FILE_NAME = ("named_kernel_trace" if KERNEL_NAME else "small_trace") \
+    + ".xplane.pb"
+
+
 @jax.jit
 def program(x):
     y = jax.lax.fori_loop(0, 4, lambda _, a: jnp.tanh(a @ a) * 0.5, x)
     return pl.pallas_call(
-        _double_kernel, out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype))(y)
+        _double_kernel, out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        name=KERNEL_NAME)(y)
 
 
 def main() -> int:
@@ -59,7 +70,7 @@ def main() -> int:
                 float(y[0, 0])
     trace_lib.stop()
     src = glob.glob(os.path.join(work, "**", "*.xplane.pb"), recursive=True)[0]
-    dst = os.path.join(out_dir, "small_trace.xplane.pb")
+    dst = os.path.join(out_dir, FILE_NAME)
     shutil.copy(src, dst)
     shutil.rmtree(work)
     trace = trace_lib.load(out_dir)
@@ -72,6 +83,7 @@ def main() -> int:
         "window_s": reduced.window_s, "busy_s": reduced.busy_s,
         "collective_s": reduced.collective_s,
         "custom_call_s": reduced.custom_call_s,
+        "kernel_s": reduced.kernel_s, "kernel_calls": reduced.kernel_calls,
         "device_ops": reduced.device_ops, "idle_gaps": reduced.idle_gaps}))
     return 0
 

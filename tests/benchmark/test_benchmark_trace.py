@@ -89,9 +89,56 @@ def test_names_collectives_and_custom_calls():
     assert trace_lib.short_name("fusion.12") == "fusion.12"
 
 
+def test_every_named_kernel_is_summed_not_only_the_ten_longest():
+    """Twelve fusions outlast the kernels, so no kernel is among the ten
+    ``device_ops`` rows; ``kernel_s`` / ``kernel_calls`` carry them all the
+    same, by kernel name without the instance suffix, mean over devices."""
+    fusions = [(f"fusion.{i} bf16[8,8]", i * 5 * MS, 4 * MS)
+               for i in range(12)]
+    dev0 = fusions + [
+        ("dttpu_paged_decode.4 custom-call bf16[8,1,25,64]", 61 * MS, 1 * MS),
+        ("dttpu_paged_decode.4 custom-call bf16[8,1,25,64]", 63 * MS, 1 * MS),
+        ("dttpu_paged_window.5 custom-call bf16[1,32,25,64]", 65 * MS,
+         0.5 * MS),
+        ("dttpu_paged_window.7 custom-call bf16[1,32,25,64]", 67 * MS,
+         0.25 * MS),
+        ("dttpu_flash_fwd custom-call f32[4]", 99.5 * MS, 1 * MS),  # clipped
+        ("closed_call.9 custom-call bf16[8]", 70 * MS, 0.125 * MS)]
+    dev1 = fusions + [
+        ("dttpu_paged_decode.4 custom-call bf16[8,1,25,64]", 61 * MS, 3 * MS)]
+    r = trace_lib.reduce(_trace({0: dev0, 1: dev1}))
+    assert not any(n.startswith("dttpu_") for n, _ in r.device_ops)
+    assert r.kernel_s == pytest.approx({
+        "dttpu_flash_fwd": 0.0005 / 2, "dttpu_paged_decode": 0.005 / 2,
+        "dttpu_paged_window": 0.00075 / 2})
+    assert r.kernel_calls == {"dttpu_flash_fwd": 0.5,
+                              "dttpu_paged_decode": 1.5,
+                              "dttpu_paged_window": 1.0}
+    # additive: the accepted sums read what they read
+    assert r.custom_call_s == pytest.approx(
+        (0.005 + 0.00075 + 0.0005 + 0.000125) / 2)
+    assert trace_lib.kernel_name("fusion.4 bf16[8,8]") is None
+    assert trace_lib.kernel_name("dttpu_fused_adam.12 custom-call") == \
+        "dttpu_fused_adam"
+
+
+def test_kernel_rows_among_the_ten_equal_the_kernels_sum():
+    ops = [("dttpu_paged_decode.4 custom-call bf16[8,1,25,64]", 0.0, 7 * MS),
+           ("dttpu_paged_decode.4 custom-call bf16[8,1,25,64]", 10 * MS,
+            6 * MS),
+           ("fusion.1 bf16[8]", 20 * MS, 2 * MS)]
+    r = trace_lib.reduce(_trace({0: ops}))
+    rows = dict(r.device_ops)
+    for kernel, seconds in r.kernel_s.items():
+        assert seconds == pytest.approx(sum(
+            s for n, s in rows.items() if trace_lib.kernel_name(n) == kernel))
+    assert r.kernel_s == pytest.approx({"dttpu_paged_decode": 0.013})
+
+
 def test_empty_trace_reduces_to_nothing():
     r = trace_lib.reduce(_trace({}, window=(0.0, 0.0)))
     assert r.busy_s == 0 and r.idle_share is None and r.devices == 0
+    assert r.kernel_s == {} and r.kernel_calls == {}
 
 
 # ------------------------------------------------- the recorded chip trace
@@ -125,3 +172,53 @@ def test_adapter_on_the_trace_recorded_on_the_chip():
     assert sum(s for _, s in r.idle_gaps) + r.busy_s == pytest.approx(
         r.window_s)
     assert {"dispatch", "fetch"} & {n for n, _ in r.idle_gaps}
+
+
+def test_the_recorded_trace_reads_what_it_read_and_its_kernels_add_up():
+    """``kernel_s`` is additive: on the recorded trace every older field is
+    what it was before the field existed (the numbers are PR 24's reducer on
+    this file), and every ``kernel_s`` entry equals the ``device_ops`` rows
+    of that kernel (this trace's one kernel, ``program.1``, carries no
+    ``dttpu_`` name, so it has none)."""
+    from jax.profiler import ProfileData
+    r = trace_lib.reduce(trace_lib.from_profile(ProfileData.from_file(SMALL)))
+    assert (r.window_s, r.busy_s, r.collective_s, r.custom_call_s) == (
+        0.102327414, 1.8929e-05, 0.0, 2.124e-06)
+    assert r.device_ops == [
+        ("fusion.8 bf16[512,512]", 1.1861e-05),
+        ("copy-done bf16[512,512]", 3.156e-06),
+        ("program.1 custom-call bf16[512,512]", 2.124e-06),
+        ("dynamic_slice.1 dynamic-slice bf16[1,1]", 9.36e-07),
+        ("copy.11 bf16[512,512]", 7.35e-07),
+        ("while s32[]", 6.8e-08),
+        ("copy-start bf16[512,512]", 4.9e-08)]
+    assert r.idle_gaps == [("fetch", 0.10230624),
+                           ("between_ops_under_20us", 2.245e-06)]
+    rows = dict(r.device_ops)
+    for kernel, seconds in r.kernel_s.items():
+        assert seconds == pytest.approx(sum(
+            s for n, s in rows.items() if trace_lib.kernel_name(n) == kernel))
+    assert r.kernel_s == {} and r.kernel_calls == {}
+
+
+def test_kernel_seconds_on_the_trace_recorded_with_a_named_kernel():
+    """``record_small_trace.py dttpu_small_double`` on one v5e (PR 28): the
+    same program with its Pallas kernel named as the program names its own.
+    The kernel's row is among ``device_ops`` here, so its ``kernel_s`` entry
+    equals that row; its three calls are three events, two of them inside
+    the window."""
+    from jax.profiler import ProfileData
+    named = os.path.join(bench_paths.DATA_DIR, "named_kernel_trace.xplane.pb")
+    assert os.path.getsize(named) < 1_000_000
+    trace = trace_lib.from_profile(ProfileData.from_file(named))
+    kernel_events = [o for o in trace.devices[0]
+                     if trace_lib.kernel_name(o[0]) == "dttpu_small_double"]
+    assert len(kernel_events) == 3
+    r = trace_lib.reduce(trace)
+    assert r.kernel_calls == {"dttpu_small_double": 2.0}
+    rows = dict(r.device_ops)
+    assert r.kernel_s == {
+        "dttpu_small_double":
+        rows["dttpu_small_double.1 custom-call bf16[512,512]"]}
+    assert r.kernel_s["dttpu_small_double"] == r.custom_call_s == 2.126e-06
+    assert (r.window_s, r.busy_s) == (0.102015683, 1.9026e-05)

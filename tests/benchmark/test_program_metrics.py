@@ -5,15 +5,15 @@ when the driver's list is the run's own, None when it is tampered with."""
 import contextlib
 import io
 import json
-import os
 from types import SimpleNamespace
 
 import pytest
 
 import bench_paths
 import run as bench_run
+import tiny_bench
 from harness import program_spans as ps
-from harness import serve_driver, spec
+from harness import spec
 from distributed_tensorflow_tpu.obs import reqtrace
 from distributed_tensorflow_tpu.obs import trace as trace_lib
 from distributed_tensorflow_tpu.obs.trace import SpanRecord
@@ -192,7 +192,7 @@ def train_case(monkeypatch):
     window_s = sum(reading_ms[2:5]) / 1e3
     record = {"kind": "train", "platform": "tpu", "steps_per_reading": 2,
               "reading_seconds": [ms / 1e3 for ms in reading_ms[2:5]],
-              "window_s": window_s}
+              "window_s": window_s, "traced_steps": 2}
     return rows, record, SimpleNamespace(window_s=reading_ms[5] / 1e3)
 
 
@@ -231,70 +231,24 @@ def test_a_program_without_the_spine_reads_nothing(serve_case, monkeypatch):
 
 # ------------------------------------------- a rehearsed cell on the CPU
 
-def _load(name):
-    with open(os.path.join(bench_paths.BENCH_DIR, name)) as f:
-        return json.load(f)
-
-
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    """A tiny benchmark of new files only beside a link to the real one,
-    as in test_benchmark_run.py, plus a reader that keeps what the driver
-    handed it."""
-    root = tmp_path_factory.mktemp("tiny_program_metrics")
-    os.symlink(bench_paths.BENCH_DIR, root / "benchmark")
-    for sub in ("configs", "traffic", "layer_metrics"):
-        (root / "tiny" / sub).mkdir(parents=True)
-    config = _load("configs/gpt2-xl.json")
-    config.update(n_embd=64, n_head=2, n_layer=2, n_positions=128, n_ctx=128,
-                  vocab_size=512)
-    config["serve"].update(num_slots=4, max_len=128)
-    (root / "tiny/configs/tiny.json").write_text(json.dumps(config))
-    train = _load("traffic/train_fsdp_16x1k.json")
-    train["params"].update(global_batch=8, seq_len=32, pool_batches=4,
-                           trace_readings=2)
-    (root / "tiny/traffic/tiny_train.json").write_text(json.dumps(train))
-    chat = _load("traffic/chat_sessions.json")
-    chat["params"].update(
-        clients=4, system_prompt_tokens=24, session_token_limit=120,
-        user_message_tokens={"dist": "lognormal", "median": 8, "sigma": 0.5,
-                             "min": 4, "max": 16, "points": 8},
-        output_tokens={"dist": "lognormal", "median": 8, "sigma": 0.4,
-                       "min": 6, "max": 12, "points": 8},
-        reading_seconds=0.3, trace_seconds=0.5)
-    (root / "tiny/traffic/tiny_chat.json").write_text(json.dumps(chat))
-    (root / "tiny/layer_metrics/kept_record.py").write_text(
+    """The tiny benchmark of new files only (``tiny_bench.py``) with a
+    reader that keeps what the driver handed it."""
+    return tiny_bench.build(
+        tmp_path_factory.mktemp("tiny_program_metrics"), "kept_record",
         "from harness import program_spans\n"
         "def read(record, trace):\n"
         "    program_spans.kept = (record, trace)\n"
-        "    return 1.0\n")
-    doc = json.load(open(os.path.join(bench_paths.ROOT, "BENCHMARK.json")))
-    doc["paths"] = ["tiny", "benchmark"]
-    doc["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
-                       "file": "tiny/configs/tiny.json", "why": "test"}]
-    doc["workloads"] = [
-        {"name": "tiny.train", "config": "tiny", "traffic": "tiny_train",
-         "chips": 4, "why": "test"},
-        {"name": "tiny.chat", "config": "tiny", "traffic": "tiny_chat",
-         "chips": 1, "why": "test"}]
-    for metric in doc["end_to_end"] + doc["per_layer"]:
-        if "workloads" in metric:
-            metric["workloads"] = sorted({
-                "tiny.train" if "train" in cell else "tiny.chat"
-                for cell in metric["workloads"]})
-    doc["per_layer"].append({
-        "name": "kept_record", "unit": "count", "better": "higher",
-        "source": "program_counter", "layer": "entry",
-        "moves": "setup_s"})
-    (root / "BENCHMARK.json").write_text(json.dumps(doc))
-    return str(root)
+        "    return 1.0\n",
+        {"unit": "count", "better": "higher", "source": "program_counter",
+         "layer": "entry", "moves": "setup_s"})
 
 
 @contextlib.contextmanager
 def _rehearse(root, workload, seconds, monkeypatch):
     """One traced run of a tiny cell under a tracer of our own, which stays
     active for the body -> (tracer, record, reduced trace, lines)."""
-    monkeypatch.setattr(serve_driver, "LOGIT_TOL", 0.01)
     # a CPU shared with the other test workers can lose many milliseconds
     # between two clock reads (seen: 5 ms under six workers), and the tiny
     # ticks are that short themselves: here the tolerance only has to tell
@@ -363,8 +317,11 @@ def test_window_selection_on_a_rehearsed_train_cell(tiny_root, monkeypatch):
         spans = tracer.spans()
         per = record["steps_per_reading"]
         need = len(record["reading_seconds"]) * per
+        # two traced readings follow the window: the driver's count of them
+        # is the selection's first choice, whatever their extent
+        assert record["traced_steps"] == 2 * per
         units, why = ps.select_steps(spans, record["reading_seconds"], per,
-                                     reduced.window_s)
+                                     record["traced_steps"])
         assert why == "" and units is not None
         # one compile step and two warm-up readings come before the window
         first = 1 + 2 * per + 1
