@@ -883,6 +883,22 @@ class GPT:
         return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype),
                 "pos": jnp.zeros((), jnp.int32)}
 
+    def paged_cache_spec(self) -> Dict[str, Any]:
+        """What a slot's paged cache is made of (``serve/pages.py`` builds
+        the pool from it): K/V for every layer, int8 scale planes
+        included, and no recurrent state."""
+        c = self.config
+        token = (c.kv_heads, c.head_dim)
+        if c.kv_cache_dtype == "int8":
+            scale = ((c.kv_heads, 1), jnp.dtype(jnp.float32))
+            kv = {"k": (token, jnp.dtype(jnp.int8)),
+                  "v": (token, jnp.dtype(jnp.int8)),
+                  "k_scale": scale, "v_scale": scale}
+        else:
+            kv = {"k": (token, jnp.dtype(c.dtype)),
+                  "v": (token, jnp.dtype(c.dtype))}
+        return {"kv_layers": c.num_layers, "kv": kv, "state": {}}
+
     @staticmethod
     def _cache_kv(cache):
         """The scan-carried K/V subtree of a cache dict (everything but

@@ -119,6 +119,22 @@ class ServeMetrics:
             "dttpu_serve_prefix_evictions_total",
             "Radix-cached prefix pages reclaimed by LRU eviction "
             "under allocation pressure.")
+        # recurrent-state snapshots (serve/pages.py; flat zero for a
+        # model that caches keys and values only)
+        self.state_snapshots = reg.counter(
+            "dttpu_serve_state_snapshots_total",
+            "Recurrent-state snapshots taken (prompt end, turn end, "
+            "where a prompt met a cached chain).")
+        self.state_restores = reg.counter(
+            "dttpu_serve_state_restores_total",
+            "Admissions whose prefill resumed from a state snapshot.")
+        self.state_snapshots_evicted = reg.counter(
+            "dttpu_serve_state_snapshots_evicted_total",
+            "State snapshots evicted: every row taken, or their chain "
+            "reclaimed.")
+        self.state_snapshot_bytes = reg.gauge(
+            "dttpu_serve_state_snapshot_bytes",
+            "Bytes of recurrent state held in snapshots.")
         # what the pump dispatched (scheduler.py counts each where it
         # happens): over ticks, windows and decode steps a tick
         self.ticks = reg.counter(
@@ -151,6 +167,10 @@ class ServeMetrics:
         self._by_delta = [
             [self.prefix_hits, "prefix_hits_total", 0],
             [self.prefix_evictions, "prefix_evictions_total", 0],
+            [self.state_snapshots, "state_snapshots_total", 0],
+            [self.state_restores, "state_restores_total", 0],
+            [self.state_snapshots_evicted,
+             "state_snapshots_evicted_total", 0],
             [self.ticks, "ticks_completed", 0],
             [self.prefill_windows, "prefill_windows_total", 0],
             [self.decode_steps, "decode_steps_total", 0],
@@ -218,6 +238,7 @@ class ServeMetrics:
         self.queue_depth.set(stats.queued)
         self.active_slots.set(stats.active)
         self.pages_free.set(stats.pages_free)
+        self.state_snapshot_bytes.set(stats.state_snapshot_bytes)
         self.pages_per_request.set(stats.pages_per_request)
         for entry in self._by_delta:
             counter, field, last = entry

@@ -43,6 +43,24 @@ evicts stale chains page by page, and only gives up —
 ``PagePoolExhausted``, the scheduler requeues the request — when every
 remaining page is pinned by an in-flight request.
 
+**State snapshots** (a model whose slot cache holds recurrent state
+beside K/V, ``paged_cache_spec()["state"]``).  K/V pages of a shared
+prefix are useless to such a model without the recurrent state at exactly
+the prefix's last token, so the pool keeps, beside the chains, SNAPSHOTS:
+each names a row of the scheduler's device snapshot arrays, hangs off the
+radix node of its last full page, and carries the tokens past that page
+boundary (``tail``) with a pool page of its own holding their K/V (a hit
+COPIES that partial page, it never shares it).  ``begin`` returns as a
+hit the deepest snapshot whose chain and tail match the prompt — pages
+matched beyond it are a miss, and are asked for as ``lease.snap_at``: the
+depth where this prompt met an existing chain, which is where the NEXT
+request with that prefix wants a snapshot.  Snapshots are taken at three
+depths (serve/scheduler.py): the end of a prompt's prefill, a turn's end
+(``handoff``), and such a meeting point.  They count against a fixed
+number of rows (the budget), are evicted least-recently-used when the rows
+run out, and go with their node when the page LRU evicts it: none outlives
+its chain, and a chain whose snapshot is gone is a miss at that depth.
+
 **Prefix fingerprint.**  The pool also maintains a BOUNDED digest of
 its hot radix chains — at most ``fingerprint_k`` entries mapping a
 chain hash (the incremental blake2b of the chunk bytes from the root,
@@ -63,14 +81,16 @@ lock -> pool lock, never the reverse).
 from __future__ import annotations
 
 import hashlib
+import heapq
 import threading
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 __all__ = ["FINGERPRINT_K", "PageLease", "PagePool", "PagePoolExhausted",
-           "auto_page_size", "decode_paged_step", "init_paged_cache",
-           "paged_kv_valid", "prompt_chain_keys"]
+           "StateSnapshot", "auto_page_size", "decode_paged_step",
+           "init_paged_cache", "init_state_snapshots", "paged_kv_valid",
+           "prompt_chain_keys", "state_bytes_per_slot"]
 
 # default bound on the hot-chain fingerprint (entries, not pages): big
 # enough for a handful of system prompts at every chunk depth, small
@@ -136,27 +156,47 @@ def auto_page_size(max_len: int, target: int = 16,
 
 def init_paged_cache(model, num_slots: int, num_pages: int,
                      page_size: int):
-    """Device state for a paged slot cache: a page-pool K/V subtree
-    (``[L, num_pages, page_size, kv_heads, ...]`` leaves, int8 scale
-    planes included) plus the same per-slot column state the contiguous
-    cache carries (serve/slots.py) — ``start_col``/``write_col``/
-    ``positions`` stay LOGICAL columns; only the storage under them is
-    paged."""
+    """Device state for a paged slot cache, built from what the model
+    says a slot's cache is made of (``model.paged_cache_spec()``:
+    ``kv_layers``, ``kv`` {leaf: (per-token shape, dtype)}, ``state``
+    {leaf: (layers, per-slot shape, dtype)}, empty for a model that
+    caches keys and values only): a page-pool K/V subtree (``[kv_layers,
+    num_pages, page_size, ...]`` leaves, int8 scale planes included)
+    plus the same per-slot column state the contiguous cache carries
+    (serve/slots.py) — ``start_col``/``write_col``/``positions`` stay
+    LOGICAL columns; only the storage under them is paged — and, for a
+    model with recurrent state, one ``[layers, num_slots, ...]`` block
+    per state leaf under ``"state"`` (absent otherwise, so a K/V-only
+    model's programs are what they were)."""
     import jax.numpy as jnp
-    c = model.config
-    shape = (c.num_layers, num_pages, page_size, c.kv_heads, c.head_dim)
-    if c.kv_cache_dtype == "int8":
-        kv = {"k": jnp.zeros(shape, jnp.int8),
-              "v": jnp.zeros(shape, jnp.int8),
-              "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-              "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32)}
-    else:
-        kv = {"k": jnp.zeros(shape, c.dtype),
-              "v": jnp.zeros(shape, c.dtype)}
-    return {"kv": kv,
-            "start_col": jnp.zeros((num_slots,), jnp.int32),
-            "write_col": jnp.zeros((num_slots,), jnp.int32),
-            "positions": jnp.zeros((num_slots,), jnp.int32)}
+    spec = model.paged_cache_spec()
+    cache = {"kv": {name: jnp.zeros(
+                 (spec["kv_layers"], num_pages, page_size) + tuple(tail),
+                 dtype) for name, (tail, dtype) in spec["kv"].items()},
+             "start_col": jnp.zeros((num_slots,), jnp.int32),
+             "write_col": jnp.zeros((num_slots,), jnp.int32),
+             "positions": jnp.zeros((num_slots,), jnp.int32)}
+    if spec["state"]:
+        cache["state"] = init_state_snapshots(model, num_slots)
+    return cache
+
+
+def init_state_snapshots(model, rows: int):
+    """``rows`` blocks of the model's per-slot recurrent state:
+    ``{leaf: [layers, rows, ...]}`` zeros.  The slot cache's ``"state"``
+    and the scheduler's snapshot arrays have this one layout, so a
+    snapshot or a restore is a row copy."""
+    import jax.numpy as jnp
+    return {name: jnp.zeros((layers, rows) + tuple(shape), dtype)
+            for name, (layers, shape, dtype)
+            in model.paged_cache_spec()["state"].items()}
+
+
+def state_bytes_per_slot(model) -> int:
+    """Bytes of recurrent state one slot (or one snapshot) holds."""
+    return sum(int(layers) * int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for layers, shape, dtype
+               in model.paged_cache_spec()["state"].values())
 
 
 def paged_kv_valid(cache, view_len: int):
@@ -184,18 +224,21 @@ def decode_paged_step(model, params, cache, page_tab, tokens, live,
     import jax.numpy as jnp
     page_size = cache["kv"]["k"].shape[2]
     view_len = page_tab.shape[1] * page_size
-    logits, kv = model.decode_step_slots_paged(
+    # a model with recurrent state advances it here, row by row, and
+    # leaves the rows that are not live exactly as they were
+    stateful = ({"state": cache["state"], "live": live}
+                if "state" in cache else {})
+    logits, *new = model.decode_step_slots_paged(
         params, cache["kv"], tokens, page_tab, cache["write_col"],
         paged_kv_valid(cache, view_len), cache["positions"],
         adapters=adapters, adapter_rows=adapter_rows,
-        use_kernel=use_kernel)
+        use_kernel=use_kernel, **stateful)
     live = live.astype(jnp.int32)
-    return logits, {
-        "kv": kv,
-        "start_col": cache["start_col"],
-        "write_col": cache["write_col"] + live,
-        "positions": cache["positions"] + live,
-    }
+    return logits, dict(
+        zip(("kv", "state"), new),
+        start_col=cache["start_col"],
+        write_col=cache["write_col"] + live,
+        positions=cache["positions"] + live)
 
 
 class _RadixNode:
@@ -205,10 +248,11 @@ class _RadixNode:
     replay deterministically)."""
 
     __slots__ = ("page", "parent", "children", "refcount", "stamp",
-                 "key", "chain")
+                 "key", "chain", "snap")
 
     def __init__(self, page: int, parent: Optional["_RadixNode"],
                  key: bytes, stamp: int):
+        self.snap: Optional["StateSnapshot"] = None
         self.page = page
         self.parent = parent
         self.children: Dict[bytes, "_RadixNode"] = {}
@@ -222,6 +266,21 @@ class _RadixNode:
                       if parent is not None else b"")
 
 
+class StateSnapshot:
+    """One recurrent-state snapshot (module doc): ``row`` of the
+    scheduler's device snapshot arrays holds the state after exactly
+    ``depth`` tokens — the chain down to ``node`` plus ``tail``, the
+    tokens past that page boundary, whose K/V sit in ``page`` (0 when the
+    depth is a page boundary).  ``stamp`` orders the row LRU."""
+
+    __slots__ = ("row", "node", "tail", "page", "stamp", "depth")
+
+    def __init__(self, row: int, node: "_RadixNode", tail: np.ndarray,
+                 page: int, stamp: int, depth: int):
+        self.row, self.node, self.tail = row, node, tail
+        self.page, self.stamp, self.depth = page, stamp, depth
+
+
 class PageLease:
     """One request's page holdings: the page-table row it decodes
     through, which of those pages are shared radix nodes vs private,
@@ -230,16 +289,21 @@ class PageLease:
     at admission, released (idempotently) at retirement/cancel."""
 
     __slots__ = ("row", "n_pages", "skip", "shared", "private",
-                 "released")
+                 "released", "restore", "snap_at")
 
     def __init__(self, row: np.ndarray, n_pages: int, skip: int,
                  shared: List[_RadixNode], private: List[int]):
         self.row = row                   # [pages_per_slot] int32
         self.n_pages = n_pages           # mapped entries (shared+private)
-        self.skip = skip                 # prefix tokens mapped shared
+        self.skip = skip                 # prefix tokens the prefill skips
         self.shared = shared             # radix nodes we hold a ref on
         self.private = private           # pool pages we own outright
         self.released = False
+        # recurrent-state pools only: the device copy a hit starts from,
+        # (snapshot row, its partial page, this lease's page for it), and
+        # the depth at which this prompt met a chain without a snapshot
+        self.restore: Optional[Tuple[int, int, int]] = None
+        self.snap_at = 0
 
 
 class PagePool:
@@ -249,7 +313,9 @@ class PagePool:
 
     def __init__(self, num_pages: int, page_size: int,
                  pages_per_slot: int, prefix_cache: bool = True,
-                 fingerprint_k: int = FINGERPRINT_K):
+                 fingerprint_k: int = FINGERPRINT_K,
+                 state_rows: Optional[int] = None,
+                 state_row_bytes: int = 0):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1; got {page_size}")
         if fingerprint_k < 0:
@@ -268,10 +334,26 @@ class PagePool:
         # or registration — the ablation arm bench.py measures the
         # reuse win against
         self.prefix_cache = bool(prefix_cache)
-        self.fingerprint_k = int(fingerprint_k)
         # hot-chain digest: chain hash -> (cached tokens, recency
-        # stamp), bounded to fingerprint_k entries (see module doc)
+        # stamp), bounded to fingerprint_k entries (see module doc).  A
+        # recurrent-state pool keeps none: cached PAGES do not say what
+        # such a model can reuse (a hit needs a snapshot), and touching
+        # the digest at every depth of 250-page chains was the largest
+        # part of a tick's host time (PERF.md, PR 30)
+        self.fingerprint_k = 0 if state_rows is not None \
+            else int(fingerprint_k)
         self._fingerprint: Dict[bytes, Tuple[int, int]] = {}
+        # recurrent-state snapshots (module doc): ``state_rows`` is the
+        # budget in rows of the scheduler's snapshot arrays, None for a
+        # model that caches keys and values only
+        self.stateful = state_rows is not None
+        self.state_rows = int(state_rows or 0)
+        self.state_row_bytes = int(state_row_bytes)
+        self._snap_free: List[int] = list(range(self.state_rows))
+        self._snaps: Dict[int, StateSnapshot] = {}
+        self.state_snapshots = 0
+        self.state_restores = 0
+        self.state_snapshots_evicted = 0
         self._lock = threading.Lock()
         # page 0 is the reserved trash page — never allocated
         self._free: List[int] = list(range(1, num_pages))
@@ -330,15 +412,31 @@ class PagePool:
                     break
                 shared.append(child)
                 node = child
-            if len(shared) * pg >= plen:
-                # the whole prompt is a cached chain, but its last page
-                # must take this request's decode writes: split it off
-                # as a fresh private copy, re-prefilled rather than
-                # device-copied (bit-identical — same tokens, same
-                # executable).  This is the COW case.
-                shared.pop()
-                self.cow_splits += 1
-            skip = len(shared) * pg
+            hit = None
+            snap_at = 0
+            if self.stateful:
+                # pages are a hit only as deep as a snapshot of the state
+                # after exactly that many tokens (module doc)
+                skip, keep = 0, 0
+                for j, n in enumerate([self._root] + shared):
+                    sn = n.snap
+                    if sn is not None and sn.depth <= plen - 1 \
+                            and np.array_equal(prompt[j * pg:sn.depth],
+                                               sn.tail):
+                        hit, skip, keep = sn, sn.depth, j
+                met = min(len(shared), (plen - 1) // pg) * pg
+                snap_at = met if met > skip else 0
+                del shared[keep:]
+            else:
+                if len(shared) * pg >= plen:
+                    # the whole prompt is a cached chain, but its last
+                    # page must take this request's decode writes: split
+                    # it off as a fresh private copy, re-prefilled rather
+                    # than device-copied (bit-identical — same tokens,
+                    # same executable).  This is the COW case.
+                    shared.pop()
+                    self.cow_splits += 1
+                skip = len(shared) * pg
             stamp = self._next_stamp()
             for n in shared:
                 n.refcount += 1
@@ -361,6 +459,12 @@ class PagePool:
                 row[j] = n.page
             row[len(shared):total] = private
             lease = PageLease(row, total, skip, shared, private)
+            lease.snap_at = snap_at
+            if hit is not None:
+                hit.stamp = stamp
+                self.state_restores += 1
+                lease.restore = (hit.row, hit.page,
+                                 int(row[len(shared)]) if hit.page else 0)
             self._lease_count += 1
             self._lease_pages += total
             return lease
@@ -379,9 +483,13 @@ class PagePool:
         with self._lock:
             if lease.released:
                 return               # cancelled before admission landed
-            node = self._root
             stamp = self._next_stamp()
-            for j in range(prompt.size // pg):
+            chunks = prompt.size // pg
+            node, done = self._pinned_prefix_locked(lease, chunks)
+            for j, pinned in enumerate(done):
+                pinned.stamp = stamp
+                self._fp_touch_locked(pinned.chain, (j + 1) * pg, stamp)
+            for j in range(len(done), chunks):
                 key = prompt[j * pg:(j + 1) * pg].tobytes()
                 child = node.children.get(key)
                 if child is not None:
@@ -404,6 +512,23 @@ class PagePool:
                 # while followers match at the shared shallow depths
                 self._fp_touch_locked(child.chain, (j + 1) * pg, stamp)
 
+    def _pinned_prefix_locked(self, lease: PageLease, chunks: int
+                              ) -> Tuple[_RadixNode, List[_RadixNode]]:
+        """The lease's pinned nodes that ARE its chain's first chunks, in
+        order — ``begin`` matched them against this prompt and
+        ``register`` appends as it publishes — and the node the walk goes
+        on from: a 250-page history is walked without hashing it again.
+        The run ends at the first pinned node whose parent is not the one
+        before it (a chunk another request registered first lies
+        between)."""
+        node, done = self._root, []
+        for pinned in lease.shared[:chunks]:
+            if pinned.parent is not node:
+                break
+            done.append(pinned)
+            node = pinned
+        return node, done
+
     def handoff(self, lease: PageLease, context: np.ndarray) -> int:
         """Export-path lease handoff (docs/RESILIENCE.md §migration):
         publish the lease's FINAL full-chunk pages for ``context`` (the
@@ -422,6 +547,65 @@ class PagePool:
             published = len(lease.shared) - before
         self.release(lease)
         return published
+
+    def snapshot(self, lease: PageLease, context: np.ndarray
+                 ) -> Optional[Tuple[int, int, int]]:
+        """Book a snapshot of the recurrent state after exactly
+        ``context`` (the tokens the lease's slot has consumed): it hangs
+        off the radix node of ``context``'s last full page — registered
+        by this or another request; if the chain does not reach that far
+        there is nothing to hang it on — and owns a fresh pool page for
+        the tokens past that boundary.  Returns ``(snapshot row, the
+        lease's page holding the partial K/V, the snapshot's page)`` for
+        the scheduler's device copy (pages 0, 0 at a page boundary), or
+        None when no snapshot was booked: not a recurrent-state pool, the
+        prefix cache off, no rows, no chain, or no page to be had.  A
+        node keeps one snapshot, the newest; with every row taken the
+        least recently used snapshot makes room."""
+        if not (self.stateful and self.prefix_cache and self.state_rows):
+            return None
+        context = np.asarray(context, np.int32).reshape(-1)
+        pg = self.page_size
+        chunks, tail = divmod(context.size, pg)
+        if context.size < 1:
+            return None
+        with self._lock:
+            if lease.released:
+                return None
+            page = 0
+            if tail:
+                # before the walk: making room may evict the very node
+                try:
+                    page = self._allocate_locked(1)[0]
+                except PagePoolExhausted:
+                    return None
+            node, done = self._pinned_prefix_locked(lease, chunks)
+            for j in range(len(done), chunks):
+                node = node.children.get(
+                    context[j * pg:(j + 1) * pg].tobytes())
+                if node is None:
+                    if page:
+                        self._free.append(page)
+                    return None
+            if node.snap is not None:
+                self._drop_snapshot_locked(node.snap)
+            if not self._snap_free:
+                self._drop_snapshot_locked(min(
+                    self._snaps.values(), key=lambda sn: sn.stamp))
+                self.state_snapshots_evicted += 1
+            sn = StateSnapshot(self._snap_free.pop(), node,
+                               context[chunks * pg:].copy(), page,
+                               self._next_stamp(), context.size)
+            node.snap = self._snaps[sn.row] = sn
+            self.state_snapshots += 1
+            return sn.row, int(lease.row[chunks]) if tail else 0, page
+
+    def _drop_snapshot_locked(self, sn: StateSnapshot) -> None:
+        sn.node.snap = None
+        del self._snaps[sn.row]
+        self._snap_free.append(sn.row)
+        if sn.page:
+            self._free.append(sn.page)
 
     def chain_pages(self, context: np.ndarray) -> list:
         """Snapshot the radix chain covering ``context``'s full chunks:
@@ -473,8 +657,8 @@ class PagePool:
         return self._stamp
 
     def _allocate_locked(self, n: int) -> List[int]:
-        while len(self._free) < n and self._evict_one_locked():
-            pass
+        if len(self._free) < n:
+            self._evict_locked(n - len(self._free))
         if len(self._free) < n:
             raise PagePoolExhausted(
                 f"need {n} pages, {len(self._free)} free and no "
@@ -482,27 +666,42 @@ class PagePool:
         pages, self._free = self._free[:n], self._free[n:]
         return pages
 
-    def _evict_one_locked(self) -> bool:
-        """Evict the least-recently-used refcount-0 LEAF node (chains
-        evict tail-first, so an interior page is never freed while a
-        descendant still chains through it; pinned nodes are
-        untouchable)."""
-        best: Optional[_RadixNode] = None
+    def _evict_locked(self, pages: int) -> None:
+        """Evict least-recently-used refcount-0 LEAF nodes until ``pages``
+        more pages are free or none is left (chains evict tail-first, so
+        an interior page is never freed while a descendant still chains
+        through it; pinned nodes are untouchable).  ONE walk of the tree
+        finds the leaves; a heap hands them out oldest first, and a node
+        whose last child went joins it — the same order as choosing the
+        oldest leaf afresh for every page, without a walk of 8,000 nodes
+        for each of the 80 pages a new session needs (130 ms of a tick on
+        the chip's host; PERF.md, PR 30)."""
+        heap: list = []
+        seen = 0                          # ties: the walk's order
         stack = list(self._root.children.values())
         while stack:
             node = stack.pop()
             if node.children:
                 stack.extend(node.children.values())
-            elif node.refcount == 0 and (best is None
-                                         or node.stamp < best.stamp):
-                best = node
-        if best is None:
-            return False
-        del best.parent.children[best.key]
-        self._free.append(best.page)
-        self._fingerprint.pop(best.chain, None)
-        self.evictions += 1
-        return True
+            elif node.refcount == 0:
+                heap.append((node.stamp, seen, node))
+                seen += 1
+        heapq.heapify(heap)
+        target = len(self._free) + pages
+        while heap and len(self._free) < target:
+            _, _, node = heapq.heappop(heap)
+            parent = node.parent
+            del parent.children[node.key]
+            if node.snap is not None:     # a snapshot goes with its chain
+                self._drop_snapshot_locked(node.snap)
+                self.state_snapshots_evicted += 1
+            self._free.append(node.page)
+            self._fingerprint.pop(node.chain, None)
+            self.evictions += 1
+            if parent is not self._root and not parent.children \
+                    and parent.refcount == 0:
+                heapq.heappush(heap, (parent.stamp, seen, parent))
+                seen += 1
 
     # ------------------------------------------------------- fingerprint
 
@@ -548,4 +747,10 @@ class PagePool:
                 "page_size": self.page_size,
                 "prefix_fingerprint": {
                     k: v[0] for k, v in self._fingerprint.items()},
+                "state_snapshots_total": self.state_snapshots,
+                "state_restores_total": self.state_restores,
+                "state_snapshots_evicted_total":
+                    self.state_snapshots_evicted,
+                "state_snapshot_bytes":
+                    len(self._snaps) * self.state_row_bytes,
             }

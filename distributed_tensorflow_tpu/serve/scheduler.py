@@ -277,6 +277,11 @@ class EngineStats:
     prefix_tokens_reused_total: int = 0
     prefix_evictions_total: int = 0          # radix pages reclaimed
     cow_splits_total: int = 0                # whole-chain prompts resplit
+    # recurrent-state models only (serve/pages.py "State snapshots")
+    state_snapshots_total: int = 0           # snapshots booked + copied
+    state_restores_total: int = 0            # admissions resumed from one
+    state_snapshots_evicted_total: int = 0   # lost to row or page pressure
+    state_snapshot_bytes: int = 0            # held now (gauge)
     prefill_windows_skipped_total: int = 0   # window dispatches avoided
     # prefix-affinity placement inputs (fleet/router.py): the pool's
     # bounded hot-chain digest (chain hash -> cached tokens, already a
@@ -340,6 +345,28 @@ class _NullMetrics:
         pass
 
 
+_WIRE_DECLINED = (
+    "the page wire ships K/V pages only: for a model with recurrent state "
+    "a chain without the state snapshot after it would be a wrong prefix "
+    "hit, so this engine neither exports nor adopts wire pages and a "
+    "migrated request re-prefills")
+
+
+def _consumed(req: "Request") -> int:
+    """Tokens an ACTIVE request's slot has consumed: its context and all
+    but the newest token generated here (fed at the next step)."""
+    ctx = req.context if req.context is not None else req.prompt
+    return ctx.size + max(0, len(req.tokens) - req.resumed - 1)
+
+
+def _written_context(req: "Request") -> np.ndarray:
+    """Those tokens themselves."""
+    ctx = req.context if req.context is not None else req.prompt
+    fresh = req.tokens[req.resumed:]
+    return (np.concatenate([ctx, np.asarray(fresh[:-1], np.int32)])
+            if len(fresh) > 1 else ctx)
+
+
 class SlotScheduler:
     """Drive a slot cache for a GPT-family ``model``/``params`` pair.
 
@@ -372,7 +399,8 @@ class SlotScheduler:
         if tick_steps < 1:
             raise ValueError(f"tick_steps must be >= 1; got {tick_steps}")
         max_len = max_len or c.max_position
-        if max_len > c.max_position and c.position_embedding == "learned":
+        if max_len > c.max_position and getattr(
+                c, "position_embedding", None) == "learned":
             raise ValueError(f"max_len {max_len} exceeds max_position "
                              f"{c.max_position}")
         self.model = model
@@ -416,6 +444,21 @@ class SlotScheduler:
         self._page_tab = None
         self._windows_skipped = 0
         self.use_paged_kernel = False
+        # a model whose slot cache holds recurrent state beside K/V
+        # (``paged_cache_spec()["state"]``: models/hybrid.py).  Its slots
+        # are reserved when a prefill STARTS (the windows build the state
+        # in the slot's row), its prefix hits resume from state snapshots
+        # (serve/pages.py), and it serves paged only.
+        self._stateful = bool(model.paged_cache_spec()["state"])
+        if self._stateful and not self.paged:
+            raise ValueError("a model with recurrent state serves from the "
+                             "paged cache only (paged=True)")
+        if not getattr(model, "paged_kernel_ok", True):
+            if use_paged_kernel is True:
+                raise ValueError(
+                    f"{type(model).__name__} cannot read its pages through "
+                    "the paged-attention kernel (paged_kernel_ok is False)")
+            use_paged_kernel = False
         if self.paged:
             from ..ops import attention as attn_lib
             from ..ops.pallas import paged_attention as paged_kernel_lib
@@ -470,9 +513,16 @@ class SlotScheduler:
                 num_pages = max(num_slots * pps + 1, pps + 2)
             self.page_size = page_size
             self.num_pages = int(num_pages)
-            self.pages = pages_lib.PagePool(self.num_pages, page_size,
-                                            pps,
-                                            prefix_cache=prefix_cache)
+            # snapshot budget: a row for every slot's turn end and eight
+            # for shared prefixes (a system prompt's, a chain met without
+            # one), each the size of one slot's state: the deployment's
+            # configuration counts them with its bytes.  None where the
+            # model caches keys and values only
+            snapshot_rows = num_slots + 8 if self._stateful else None
+            self.pages = pages_lib.PagePool(
+                self.num_pages, page_size, pps, prefix_cache=prefix_cache,
+                state_rows=snapshot_rows,
+                state_row_bytes=pages_lib.state_bytes_per_slot(model))
             self._page_tab = np.zeros((num_slots, pps), np.int32)
         # duck-typed admission policy (fleet.tenancy.TenantPolicy):
         # checked under the state lock so quota decisions are atomic
@@ -496,7 +546,9 @@ class SlotScheduler:
         # admissible request", the policy decides whose turn it is
         self._queue = queue if queue is not None else collections.deque()
         self._slots: List[Optional[Request]] = [None] * num_slots
-        # in-flight prefills: [req, windows [n, 1, W], next index, cache]
+        # in-flight prefills: [req, windows [n, 1, W], next index, cache
+        # or lease, window plan [(pos, real, snapshot depth)], the slot of a
+        # recurrent-state model (else None), this tick's window ran ahead]
         self._prefills: List[list] = []
         # spare batch-1 prefill caches, reused across requests (stale
         # columns are masked by the slot validity window, never read)
@@ -513,6 +565,11 @@ class SlotScheduler:
                        if self.paged
                        else slots_lib.init_slot_cache(model, num_slots,
                                                       max_len))
+        # state snapshots: the slot state's layout, one row a snapshot
+        # (empty dict for a K/V-only model)
+        self._snaps = (pages_lib.init_state_snapshots(
+                           model, max(self.pages.state_rows, 1))
+                       if self._stateful else {})
         self._tokens = jnp.zeros((num_slots,), jnp.int32)
         self._finished = jnp.ones((num_slots,), bool)   # empty = finished
         self._remaining = jnp.zeros((num_slots,), jnp.int32)
@@ -572,40 +629,80 @@ class SlotScheduler:
         # build REPLACES the gather build (same 3 programs, DT405-pinned)
         use_kernel = self.use_paged_kernel
 
+        def paged_window(params, cache, window, page_row, pos, head,
+                         ad, ad_row, slot, valid):
+            """One prefill window through the model -> (logits, cache).
+            ``slot``/``valid`` are None for a K/V-only model (empty
+            pytrees: its programs are what they always were); a model
+            with recurrent state reads the slot's state row, advances it
+            over the window's ``valid`` real tokens and writes it back."""
+            if slot is None:
+                logits, kv = model.decode_window_paged(
+                    params, cache["kv"], window, page_row, pos,
+                    head=head, adapters=ad, adapter_rows=ad_row,
+                    use_kernel=use_kernel)
+                return logits, dict(cache, kv=kv)
+            logits, kv, state = model.decode_window_paged(
+                params, cache["kv"], window, page_row, pos, head=head,
+                state=cache["state"], slot=slot, valid=valid,
+                adapters=ad, adapter_rows=ad_row, use_kernel=use_kernel)
+            return logits, dict(cache, kv=kv, state=state)
+
         def paged_win_mid(params, cache, window, page_row, pos, ad,
-                          ad_row):
+                          ad_row, slot=None, valid=None):
             """Mid prefill window straight into the request's pages —
             the whole cache (pool + slot state) is donated and flows
             through so win/admit/tick chain on one buffer set."""
-            _, kv = model.decode_window_paged(
-                params, cache["kv"], window, page_row, pos,
-                head="none", adapters=ad, adapter_rows=ad_row,
-                use_kernel=use_kernel)
-            return dict(cache, kv=kv)
+            return paged_window(params, cache, window, page_row, pos,
+                                "none", ad, ad_row, slot, valid)[1]
 
         def paged_last_admit(params, cache, window, page_row, pos,
                              last_idx, key, tokens, finished, remaining,
-                             slot_idx, length, budget, ad, ad_row):
+                             slot_idx, length, budget, ad, ad_row,
+                             valid=None):
             """Last prefill window + first-token sample + slot arm in
             ONE dispatch.  No splice: the prompt's K/V already live in
             the request's pages — admission just points the slot's
             column state at them (the page-table row is host state,
             handed to the next tick)."""
-            logits, kv = model.decode_window_paged(
-                params, cache["kv"], window, page_row, pos,
-                head="all", adapters=ad, adapter_rows=ad_row,
-                use_kernel=use_kernel)
+            logits, cache = paged_window(
+                params, cache, window, page_row, pos, "all", ad, ad_row,
+                None if valid is None else slot_idx, valid)
             tok, key, tokens, finished, remaining = first_token(
                 logits, last_idx, key, tokens, finished, remaining,
                 slot_idx, budget)
-            cache = {
-                "kv": kv,
-                "start_col": cache["start_col"].at[slot_idx].set(
+            cache = dict(
+                cache,
+                start_col=cache["start_col"].at[slot_idx].set(
                     jnp.int32(0)),
-                "write_col": cache["write_col"].at[slot_idx].set(length),
-                "positions": cache["positions"].at[slot_idx].set(length),
-            }
+                write_col=cache["write_col"].at[slot_idx].set(length),
+                positions=cache["positions"].at[slot_idx].set(length))
             return tok, cache, tokens, finished, remaining, key
+
+        def copy_page(kv, src, dst):
+            return {k: v.at[:, dst].set(v[:, src]) for k, v in kv.items()}
+
+        def state_snapshot(cache, snaps, where):
+            """Slot ``slot``'s recurrent state -> snapshot row ``row``,
+            and the page holding the tokens past its last full page ->
+            the snapshot's own page (trash onto trash when there are
+            none); ``where`` = [slot, row, source page, target page], one
+            small array because every scalar argument is a transfer of
+            its own.  A device copy in the tick's stream; both donated."""
+            slot, row, src_page, dst_page = where
+            snaps = {k: v.at[:, row].set(cache["state"][k][:, slot])
+                     for k, v in snaps.items()}
+            return dict(cache, kv=copy_page(cache["kv"], src_page,
+                                            dst_page)), snaps
+
+        def state_restore(cache, snaps, where):
+            """The reverse: snapshot row ``row`` -> slot ``slot``'s state,
+            the snapshot's partial page COPIED into the request's own."""
+            slot, row, src_page, dst_page = where
+            state = {k: v.at[:, slot].set(snaps[k][:, row])
+                     for k, v in cache["state"].items()}
+            return dict(cache, state=state,
+                        kv=copy_page(cache["kv"], src_page, dst_page))
 
         def paged_tick(params, cache, page_tab, tokens, finished,
                        remaining, key, ad, ad_rows):
@@ -685,6 +782,12 @@ class SlotScheduler:
             self._wire_gather = jax.jit(wire_gather)
             self._wire_splice = jax.jit(wire_splice,
                                         donate_argnums=(0,))
+            # recurrent-state models: two more pinned programs, both row
+            # copies dispatched in the tick's stream (census: 3 + 2)
+            self._state_snapshot = jax.jit(state_snapshot,
+                                           donate_argnums=(0, 1))
+            self._state_restore = jax.jit(state_restore,
+                                          donate_argnums=(0,))
         else:
             self._win_mid = jax.jit(win_mid, donate_argnums=(1,))
             self._last_admit = jax.jit(last_admit,
@@ -696,8 +799,8 @@ class SlotScheduler:
     def graph_targets(self, hbm_budget: Optional[int] = None) -> list:
         """The three hot executables as dtlint graph-tier trace targets
         (``analysis/graph.py``): abstract shape/dtype specs matching
-        exactly what ``_advance_prefill``/``_decode_tick`` pass, so the
-        DT4xx rules and the DT405 census lint the REAL programs.  Kept
+        exactly what ``_advance_prefill``/``_decode_dispatch`` pass, so
+        the DT4xx rules and the DT405 census lint the REAL programs.  Kept
         in this file so the specs cannot drift from the call sites
         without the diff showing both.  Serializes against the pump
         (shape/dtype reads of buffers a running tick donates)."""
@@ -714,6 +817,7 @@ class SlotScheduler:
             params, cache = sds(self.params), sds(self._cache)
             toks, fin = sds(self._tokens), sds(self._finished)
             rem, key = sds(self._remaining), sds(self._key)
+            snaps = sds(self._snaps)
             ad, ad_rows = self._adapter_args()
         ad = sds(ad) if ad is not None else None
         row1 = (jax.ShapeDtypeStruct((1,), np.int32)
@@ -723,21 +827,36 @@ class SlotScheduler:
             pps = self.max_len // self.page_size
             prow = jax.ShapeDtypeStruct((pps,), np.int32)
             tab = jax.ShapeDtypeStruct((self.num_slots, pps), np.int32)
-            return [
+            st = (i32,) if self._stateful else ()
+            targets = [
                 graph_lib.Target(
                     "prefill_window", self._win_mid,
-                    (params, cache, win, prow, i32, ad, row1),
+                    (params, cache, win, prow, i32, ad, row1) + st + st,
                     hbm_budget=hbm_budget),
                 graph_lib.Target(
                     "admit", self._last_admit,
                     (params, cache, win, prow, i32, i32, key, toks,
-                     fin, rem, i32, i32, i32, ad, row1),
+                     fin, rem, i32, i32, i32, ad, row1) + st,
                     hbm_budget=hbm_budget),
                 graph_lib.Target(
                     "decode_tick", self._tick,
                     (params, cache, tab, toks, fin, rem, key, ad, rows),
                     hbm_budget=hbm_budget),
             ]
+            if self._stateful:
+                # the snapshot copies are programs of their own (a turn's
+                # end is known only after the tick's fetch, so the copy
+                # cannot ride inside the tick); listed here so that they
+                # are warmed, analysed and censused with the three
+                copy = (cache, snaps,
+                        jax.ShapeDtypeStruct((4,), np.int32))
+                targets += [
+                    graph_lib.Target("state_snapshot",
+                                     self._state_snapshot, copy,
+                                     hbm_budget=hbm_budget),
+                    graph_lib.Target("state_restore", self._state_restore,
+                                     copy, hbm_budget=hbm_budget)]
+            return targets
         pf = sds(jax.eval_shape(
             lambda: self.model.init_cache(1, self.max_len)))
         return [
@@ -895,6 +1014,11 @@ class SlotScheduler:
                 prefix_tokens_reused_total=p["prefix_tokens_reused_total"],
                 prefix_evictions_total=p["prefix_evictions_total"],
                 cow_splits_total=p["cow_splits_total"],
+                state_snapshots_total=p["state_snapshots_total"],
+                state_restores_total=p["state_restores_total"],
+                state_snapshots_evicted_total=p[
+                    "state_snapshots_evicted_total"],
+                state_snapshot_bytes=p["state_snapshot_bytes"],
                 prefill_windows_skipped_total=skipped,
                 page_size=p["page_size"],
                 prefix_fingerprint=p["prefix_fingerprint"])
@@ -975,28 +1099,60 @@ class SlotScheduler:
         # window cost, win_by_req keys each request's OWN share (and
         # doubles as "prefilled this tick", which exempts a request
         # admitted mid-tick from interference: it was not yet decoding
-        # when the windows ran).  A mid window's span is its DISPATCH:
-        # the device's time for it lands in the tick's next fetch.
+        # when the windows ran).  A window's span is its DISPATCH: the
+        # device's time for it lands in the tick's reads and its fetch.
         prefill_s = 0.0
         windows = 0
         win_by_req: Dict[int, float] = {}
-        for st in pending:
-            did = True
-            req = st[0]
-            with trace_lib.timed("serve.prefill",
-                                 trace_id=req.trace_id) as window:
-                windows += self._advance_prefill(st, outbox)
-            dt = window.duration_s
+
+        def charge(req: Request, dt: float) -> None:
+            nonlocal prefill_s
             prefill_s += dt
             win_by_req[id(req)] = win_by_req.get(id(req), 0.0) + dt
             if req.phases is not None:
                 req.phases["prefill_compute"] += dt
+
+        def window_of(st: list) -> int:
+            with trace_lib.timed("serve.prefill",
+                                 trace_id=st[0].trace_id) as window:
+                n = self._advance_prefill(st, firsts)
+            charge(st[0], window.duration_s)
+            return n
+
+        # The tick keeps the device's queue from running empty: admitting
+        # windows go last of the windows, their tokens are read only after
+        # everything of the tick is dispatched, and the mid windows the NEXT
+        # tick would open with are dispatched behind the decode program
+        # (``st[6]``), so deliveries, admissions and the caller's own work
+        # between ticks run beside a busy device.  A request still gets one
+        # window a tick, in the same place of the device's stream.
+        firsts: List[tuple] = []     # admitting windows, tokens unread
+        pending.sort(key=lambda st: st[2] == len(st[1]) - 1)
+        for st in pending:
+            did = True
+            if st[6]:
+                st[6] = False        # ran ahead, behind the last decode
+                continue
+            windows += window_of(st)
         with self._lock:
             active = sum(r is not None for r in self._slots)
         decoded = None
         if active:
             did = True
-            decoded = self._decode_tick(active)
+            decoded = self._decode_dispatch(active)
+            with self._lock:
+                ahead = [st for st in self._prefills
+                         if st[2] < len(st[1]) - 1]
+            for st in ahead:
+                windows += window_of(st)
+                st[6] = True
+        for st, tok, slot in firsts:
+            with trace_lib.timed("serve.prefill",
+                                 trace_id=st[0].trace_id) as read:
+                self._first_token(st, tok, slot, outbox)
+            charge(st[0], read.duration_s)
+        if decoded is not None:
+            decoded = self._decode_fetch(*decoded)
         with trace_lib.span("serve.deliver") as deliver:
             if decoded is not None:
                 self._collect(outbox, decoded, prefill_s, win_by_req)
@@ -1022,6 +1178,9 @@ class SlotScheduler:
                 st = self._begin_prefill(req)
                 admit.set(outcome="ok", skipped_tokens=int(
                     st[3].skip if self.paged else 0))
+                if self._stateful:
+                    # it holds a ``serve.state_restore`` span iff True
+                    admit.set(resumed=st[3].restore is not None)
             except (AdapterTableFull, pages_lib.PagePoolExhausted):
                 st = None
                 admit.set(outcome="backpressure")
@@ -1128,6 +1287,7 @@ class SlotScheduler:
             req.adapter_row = self.adapters.acquire(req.adapter_id)
         try:
             if self.paged:
+                slot = self._reserve_state_row() if self._stateful else None
                 # page lease: map any cached prefix chain read-only and
                 # allocate private pages for the rest of the request's
                 # whole footprint (context + remaining decode budget —
@@ -1135,15 +1295,41 @@ class SlotScheduler:
                 lease = self.pages.begin(
                     ctx, plen + req.remaining_budget - 1)
                 req._lease = lease
-                remaining = ctx[lease.skip:]
-                n_win = -(-remaining.size // w)
-                padded = np.zeros((n_win * w,), np.int32)
-                padded[:remaining.size] = remaining
+                if lease.restore is not None:
+                    # the hit's state, and its partial page, into the
+                    # slot's row and the request's own page: a device copy
+                    # ahead of the first window in the same stream
+                    row, src, dst = lease.restore
+                    with trace_lib.span(
+                            "serve.state_restore", trace_id=req.trace_id,
+                            slot=int(slot), depth=int(lease.skip),
+                            bytes=self.pages.state_row_bytes):
+                        self._cache = self._state_restore(
+                            self._cache, self._snaps,
+                            np.asarray([slot, row, src, dst], np.int32))
+                # the windows: W tokens each from ``skip`` on, cut where
+                # the pool asked for a snapshot (``snap_at``: the prompt
+                # met a chain there), each stretch's last one padded
+                cuts = [c for c in (lease.snap_at,)
+                        if lease.skip < c < plen] + [plen]
+                plan, rows, start = [], [], lease.skip
+                for cut in cuts:
+                    for pos in range(start, cut, w):
+                        real = min(w, cut - pos)
+                        row_ = np.zeros((w,), np.int32)
+                        row_[:real] = ctx[pos:pos + real]
+                        rows.append(row_)
+                        plan.append((pos, real,
+                                     cut if pos + real == cut < plen
+                                     else 0))
+                    start = cut
+                n_win = len(plan)
                 with self._lock:
                     # window dispatches avoided by the prefix hit — the
                     # measured TTFT/FLOPs saving, reported via stats()
-                    self._windows_skipped += -(-plen // w) - n_win
-                return [req, padded.reshape(n_win, 1, w), 0, lease]
+                    self._windows_skipped += max(0, -(-plen // w) - n_win)
+                return [req, np.stack(rows).reshape(n_win, 1, w), 0, lease,
+                        plan, slot, False]
             n_win = -(-plen // w)
             padded = np.zeros((n_win * w,), np.int32)
             padded[:plen] = ctx
@@ -1153,7 +1339,8 @@ class SlotScheduler:
             if kv is None:
                 kv = slots_lib.strip_pos(self.model.init_cache(
                     1, self.max_len))
-            return [req, windows, 0, dict(kv, pos=np.int32(0))]
+            return [req, windows, 0, dict(kv, pos=np.int32(0)), None, None,
+                    False]
         except BaseException:
             # admission failed after the pin: pool exhaustion is the
             # common case, but begin() also raises ValueError for a
@@ -1169,6 +1356,34 @@ class SlotScheduler:
                 req.adapter_row = None
             raise
 
+    def _reserve_state_row(self) -> int:
+        """The slot a recurrent-state model's prefill builds its state in:
+        free, reserved by no other prefill, and with no freeze pending (a
+        row cancelled cross-thread decodes on until the next tick's
+        housekeeping freezes it).  None to be had is backpressure."""
+        with self._lock:
+            taken = {st[5] for st in self._prefills} | self._stale_rows
+            for r, holder in enumerate(self._slots):
+                if holder is None and r not in taken:
+                    return r
+        raise pages_lib.PagePoolExhausted("no slot's state row is free")
+
+    def _snapshot_state(self, req: Request, lease, context, slot: int,
+                        at: str) -> None:
+        """Book a snapshot of ``slot``'s recurrent state after exactly
+        ``context`` (serve/pages.py ``snapshot``) and dispatch its device
+        copy; nothing when the pool books none."""
+        booked = self.pages.snapshot(lease, context)
+        if booked is None:
+            return
+        row, src, dst = booked
+        with trace_lib.span("serve.state_snapshot", trace_id=req.trace_id,
+                            slot=int(slot), depth=int(len(context)),
+                            bytes=self.pages.state_row_bytes, at=at):
+            self._cache, self._snaps = self._state_snapshot(
+                self._cache, self._snaps,
+                np.asarray([slot, row, src, dst], np.int32))
+
     def _adapter_args(self, req: Optional[Request] = None):
         """(table arrays, rows) for the executables — (None, None) when
         adapters are off, so the compiled programs are identical to an
@@ -1180,10 +1395,11 @@ class SlotScheduler:
                                                     np.int32)
         return self.adapters.arrays, self._adapter_rows
 
-    def _advance_prefill(self, st: list, outbox: List[tuple]) -> int:
+    def _advance_prefill(self, st: list, firsts: List[tuple]) -> int:
         """One window for one in-flight prefill; admits the request into
-        its slot on the last window.  Pump-only; delivery of the first
-        token is queued on ``outbox`` (flushed at end of tick).  Returns
+        its slot on the last window, whose token stays on the device:
+        ``firsts`` gains ``(st, token, slot)`` for ``_first_token`` to read
+        once the tick's other work is dispatched.  Pump-only.  Returns
         the windows dispatched (0 for a request cancelled cross-thread).
 
         Paged mode prefills straight into the request's leased pages
@@ -1192,13 +1408,18 @@ class SlotScheduler:
         dispatched), so admission is column-state arming plus a host
         page-table write, not a cache splice; the request's full prompt
         pages are published to the radix cache right after."""
-        req, windows, i, payload = st
+        req, windows, i, payload, plan, reserved, _ = st
         with self._lock:
             if st not in self._prefills:
                 return 0     # cancelled cross-thread: harvest recycles it
         ad, ad_row = self._adapter_args(req)
-        skip = payload.skip if self.paged else 0
         last = i == len(windows) - 1
+        if self.paged:
+            pos, real, snap_depth = plan[i]
+            # a recurrent-state model's windows also name the slot whose
+            # state they advance and how many of their tokens are real
+            state_args = ((np.int32(reserved), np.int32(real))
+                          if self._stateful else ())
         if not last:
             with trace_lib.span("serve.prefill_dispatch",
                                 trace_id=req.trace_id, window=int(i),
@@ -1206,8 +1427,7 @@ class SlotScheduler:
                 if self.paged:
                     self._cache = self._win_mid(
                         self.params, self._cache, windows[i], payload.row,
-                        np.int32(skip + i * self.prefill_chunk), ad,
-                        ad_row)
+                        np.int32(pos), ad, ad_row, *state_args)
                 else:
                     new_cache = self._win_mid(self.params, payload,
                                               windows[i], ad, ad_row)
@@ -1220,16 +1440,22 @@ class SlotScheduler:
             if req.trace_id:
                 reqtrace.mark(req.trace_id, "prefill_window",
                               window=int(i))
+            if self.paged and snap_depth:
+                ctx = req.context if req.context is not None else req.prompt
+                self._snapshot_state(req, payload, ctx[:snap_depth],
+                                     reserved, "chain_met")
             return 1
         ctx = req.context if req.context is not None else req.prompt
         plen = ctx.size
-        last_idx = np.int32(plen - skip - 1 - (len(windows) - 1)
+        last_idx = np.int32(real - 1 if self.paged else
+                            plen - 1 - (len(windows) - 1)
                             * self.prefill_chunk)
         with self._lock:
             if st not in self._prefills or req.done.is_set():
                 return 0
             self._prefills.remove(st)
-            slot = self._slots.index(None)
+            slot = (reserved if reserved is not None
+                    else self._slots.index(None))
             # reserve before the splice so the free-slot count stays
             # consistent for concurrent admissions and stats(); the
             # splice overwrites the row, so a leftover freeze mark from
@@ -1245,13 +1471,11 @@ class SlotScheduler:
                 tok, self._cache, self._tokens, self._finished, \
                     self._remaining, self._key = self._last_admit(
                         self.params, self._cache, windows[-1],
-                        payload.row,
-                        np.int32(skip + (len(windows) - 1)
-                                 * self.prefill_chunk),
+                        payload.row, np.int32(pos),
                         last_idx, self._key, self._tokens,
                         self._finished, self._remaining, np.int32(slot),
                         np.int32(plen), np.int32(req.remaining_budget),
-                        ad, ad_row)
+                        ad, ad_row, *state_args[1:])
             else:
                 tok, self._cache, self._tokens, self._finished, \
                     self._remaining, self._key = self._last_admit(
@@ -1260,22 +1484,44 @@ class SlotScheduler:
                         self._finished, self._remaining, np.int32(slot),
                         np.int32(plen), np.int32(req.remaining_budget),
                         ad, ad_row)
-        with trace_lib.span("serve.first_token_fetch",
+        if self._stateful:
+            # publish the context's pages and snapshot the state after its
+            # last token now, behind the admitting window in the device's
+            # stream
+            with trace_lib.span("serve.register"):
+                self.pages.register(payload, ctx)
+                self._snapshot_state(req, payload, ctx, slot, "prompt_end")
+        if self.paged:
+            with self._lock:
+                # before the tick's decode dispatch copies the table
+                self._page_tab[slot] = payload.row
+        firsts.append((st, tok, slot))
+        return 1
+
+    def _first_token(self, st: list, tok, slot: int,
+                     outbox: List[tuple]) -> None:
+        """Read an admitting window's token off the device and finish the
+        admission on the host; delivery is queued on ``outbox`` (flushed at
+        end of tick).  The tick's decode program and the next tick's first
+        windows are queued behind the window by now, so the read waits
+        beside a busy device: it is no barrier, and its span is not named
+        as the fetches that are (``serve.decode_fetch``)."""
+        req, windows, payload = st[0], st[1], st[3]
+        ctx = req.context if req.context is not None else req.prompt
+        with trace_lib.span("serve.first_token_read",
                             trace_id=req.trace_id):
-            first = int(tok)      # host fetch: the TTFT barrier
+            first = int(tok)      # returns as the admitting window ends
         req.first_token_time = time.perf_counter()
         req._windows += 1
         with trace_lib.span("serve.register"):
-            if self.paged:
+            if self.paged and not self._stateful:
                 # the context's full pages are final now — publish them
                 # so the NEXT request with this prefix skips their
                 # windows
                 self.pages.register(payload, ctx)
             with self._lock:
                 self._prefill_windows += 1
-                if self.paged:
-                    self._page_tab[slot] = payload.row
-                else:
+                if not self.paged:
                     # the pool entry was not donated — reusable for the
                     # next request
                     self._pool_prefill_cache(payload)
@@ -1288,7 +1534,7 @@ class SlotScheduler:
             # cancel() raced the splice: retire the freshly spliced row
             # (frozen rows never perturb the others) and deliver nothing
             self._finished = self._finished.at[slot].set(True)
-            return 1
+            return
         self.metrics.admitted(req)
         if req.trace_id:
             reqtrace.mark(req.trace_id, "prefill_window",
@@ -1309,7 +1555,6 @@ class SlotScheduler:
             outbox.append(("finish", req))
         else:
             outbox.append(("deliver", req, [first], slot))
-        return 1
 
     # ----------------------------------------------------------- decode
 
@@ -1323,11 +1568,10 @@ class SlotScheduler:
                 if self._page_tab is not None:
                     self._page_tab[r] = 0
 
-    def _decode_tick(self, active: int) -> tuple:
-        """One K-step decode dispatch and the fetch of what it emitted:
-        ``(slots, em, mask, fin, decode_s)`` for ``_collect``.
-        ``decode_s`` is the dispatch-to-host-sync wall — the two spans'
-        own durations — identical for every live slot in the batch."""
+    def _decode_dispatch(self, active: int) -> tuple:
+        """One K-step decode dispatch over the slots; what ``_decode_fetch``
+        takes: ``(slots, emitted, mask, dispatch_s)``, the tokens still on
+        the device."""
         with self._lock:
             slots = list(self._slots)
             # page-table snapshot for this dispatch: host mutations
@@ -1339,6 +1583,11 @@ class SlotScheduler:
         with trace_lib.timed("serve.decode_dispatch",
                              steps=self.tick_steps,
                              active=active) as dispatch:
+            if trace_lib.active_tracer() is not None:
+                # tokens the live slots have in their caches as the
+                # dispatch starts: what a byte count of the step is made of
+                dispatch.set(cached_tokens=sum(
+                    _consumed(r) for r in slots if r is not None))
             if self.paged:
                 (self._cache, self._tokens, self._finished,
                  self._remaining, self._key), em, mask = self._tick(
@@ -1351,13 +1600,23 @@ class SlotScheduler:
                     self.params, self._cache, self._tokens,
                     self._finished, self._remaining, self._key, ad,
                     ad_rows)
+        return slots, em, mask, self._finished, dispatch.duration_s
+
+    def _decode_fetch(self, slots, em, mask, finished,
+                      dispatch_s: float) -> tuple:
+        """The host sync on a decode dispatch: ``(slots, em, mask, fin,
+        decode_s)`` for ``_collect``.  ``decode_s`` is the dispatch-to-
+        host-sync wall — the two spans' own durations — identical for
+        every live slot in the batch."""
         with trace_lib.timed("serve.decode_fetch") as fetch:
             em = np.asarray(em)                  # [K, S]: the host sync
             mask = np.asarray(mask)
-            fin = np.asarray(self._finished)
+            fin = np.asarray(finished)
+            # (slot, step) pairs that were live: what the steps computed
+            fetch.set(live_steps=int(mask.sum()))
         with self._lock:
             self._decode_steps += self.tick_steps
-        return slots, em, mask, fin, dispatch.duration_s + fetch.duration_s
+        return slots, em, mask, fin, dispatch_s + fetch.duration_s
 
     def _collect(self, outbox: List[tuple], decoded: tuple,
                  prefill_s: float, win_by_req: Dict[int, float]) -> None:
@@ -1386,7 +1645,7 @@ class SlotScheduler:
                 outbox.append(("deliver", req, [int(t) for t in toks], r))
             if fin[r]:
                 self._drop_slot(r, req)
-                outbox.append(("finish", req))
+                outbox.append(("finish", req, r))
 
     def _flush(self, outbox: List[tuple]) -> int:
         """Deliver tokens and terminal transitions in tick order;
@@ -1414,8 +1673,25 @@ class SlotScheduler:
                         self._finished = self._finished.at[row].set(True)
                     self._abort(req, "failed", error=e)
             else:                    # "finish"
+                if self._stateful and len(ev) > 2:
+                    self._snapshot_turn_end(req, ev[2])
                 self._finish(req)
         return delivered
+
+    def _snapshot_turn_end(self, req: Request, row: int) -> None:
+        """A finished turn of a recurrent-state model: publish the pages
+        of everything the slot consumed (the context and all but the
+        newest generated token, which was never fed) and snapshot the
+        frozen row's state at exactly that depth, so the session's next
+        turn resumes after its own history, reply included.  Runs in the
+        tick's flush, before any admission can reserve the row."""
+        lease = req._lease
+        if lease is None or lease.released:
+            return
+        written = _written_context(req)
+        with trace_lib.span("serve.register"):
+            self.pages.register(lease, written)
+        self._snapshot_state(req, lease, written, row, "turn_end")
 
     # --------------------------------------------- degradation paths
 
@@ -1619,9 +1895,11 @@ class SlotScheduler:
                 "nothing to export")
         ctx = req.context if req.context is not None else req.prompt
         with self._lock:
-            windows_done = next((st[2] for st in self._prefills
-                                 if st[0] is req), None)
-            active = any(r is req for r in self._slots)
+            prefill = next((st for st in self._prefills
+                            if st[0] is req), None)
+            row = next((r for r, other in enumerate(self._slots)
+                        if other is req), None)
+        active = row is not None
         generated = list(req.tokens)
         now = time.perf_counter()
         snap = RequestSnapshot(
@@ -1667,10 +1945,19 @@ class SlotScheduler:
                             [ctx, np.asarray(fresh, np.int32)])
                         if fresh else ctx)
             else:
-                done = windows_done or 0
-                written = lease.skip + done * self.prefill_chunk
+                # the windows a prefill has completed end where its next
+                # one starts (the current one may be mid-dispatch)
+                written = (prefill[4][prefill[2]][0] if prefill is not None
+                           else lease.skip)
                 full = ctx
             published_ctx = full[:written]
+            if self._stateful and active and clean:
+                # pages without the state after them would be a miss on
+                # re-import: snapshot the row too (clean = pump mutex
+                # held, so a device copy may be dispatched)
+                self.pages.register(lease, published_ctx)
+                self._snapshot_state(req, lease, published_ctx, row,
+                                     "export")
             self.pages.handoff(lease, published_ctx)
             # page-wire manifest: the chain keys just handed off — the
             # fleet's wire (fleet/pagewire.py) may ship those pages so
@@ -1678,8 +1965,10 @@ class SlotScheduler:
             # re-verified against the live radix tree at capture time
             # (``chain_pages``), so eviction between now and then only
             # shrinks what ships, never corrupts it.
-            keys = pages_lib.prompt_chain_keys(published_ctx,
-                                               self.page_size)
+            # (none for a recurrent-state model: the wire ships no state
+            # snapshots, and pages alone would be a wrong hit)
+            keys = (() if self._stateful else pages_lib.prompt_chain_keys(
+                published_ctx, self.page_size))
             if keys:
                 snap.shipped_pages = keys
                 snap.page_size = self.page_size
@@ -1710,6 +1999,8 @@ class SlotScheduler:
         never correctness."""
         import jax
 
+        if self._stateful:
+            raise ValueError(_WIRE_DECLINED)
         if self.pages is None or not self.pages.prefix_cache:
             return []
         if timeout_s is None:
@@ -1753,6 +2044,8 @@ class SlotScheduler:
         (0 = adopt nothing: wrong page size, alien leaf layout, chain
         mismatch, pool exhausted, or pump busy past ``timeout_s`` —
         all degrade to plain re-prefill)."""
+        if self._stateful:
+            raise ValueError(_WIRE_DECLINED)
         if self.pages is None or not self.pages.prefix_cache \
                 or not records:
             return 0
@@ -2063,3 +2356,30 @@ def _graph_entries():
                           prefill_chunk=8, tick_steps=2,
                           temperature=0.0)
     return sched.graph_targets(hbm_budget=2 << 20)
+
+
+# A model with recurrent state beside K/V (models/hybrid.py) pins FIVE:
+# the same three and the two state-snapshot copies.  They are programs of
+# their own because a turn's end is known only after the tick's fetch, so
+# its snapshot cannot ride inside the tick, and a restore precedes the
+# first window of a prefill whose windows are otherwise all alike; both are
+# row copies listed by ``graph_targets()`` with the three, so they are
+# warmed before a serving window and can never compile inside one.
+graph_lib.expect_census("serve-hot-state", 5)
+
+
+@graph_lib.trace_entry("serve-state", group="serve-hot-state",
+                       hbm_budget=4 << 20)
+def _graph_entries_state():
+    """The same registry-scale build over a toy state-space / attention
+    decoder: the three hot programs carrying the slot state, and the
+    snapshot and restore copies."""
+    import jax
+    from ..models.hybrid import hybrid_tiny
+
+    model = hybrid_tiny(vocab_size=64, max_position=32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    sched = SlotScheduler(model, params, num_slots=2, max_len=32,
+                          prefill_chunk=8, tick_steps=2,
+                          temperature=0.0)
+    return sched.graph_targets(hbm_budget=4 << 20)

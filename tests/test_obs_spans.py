@@ -26,7 +26,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TICK_CHILDREN = {"serve.housekeeping", "serve.admit", "serve.prefill",
                  "serve.decode_dispatch", "serve.decode_fetch",
                  "serve.deliver"}
-PREFILL_CHILDREN = {"serve.prefill_dispatch", "serve.first_token_fetch",
+PREFILL_CHILDREN = {"serve.prefill_dispatch", "serve.first_token_read",
                     "serve.register"}
 
 
@@ -267,6 +267,55 @@ def test_counters_and_request_counts_repeat_exactly(model_params, tracer):
     assert decode_steps % 2 == 0 and decode_steps >= 8
 
 
+def test_a_decoding_tick_leaves_the_device_no_empty_queue(model_params,
+                                                         tracer):
+    """Beside a decoding request, a four-window prompt: each tick reads an
+    admitting window's token only after its decode dispatch and before its
+    decode fetch, and dispatches the mid window the NEXT tick would open
+    with behind the decode program, so that tick dispatches none for the
+    request: still one window a tick (``prefill_ticks`` is the windows)."""
+    engine = _engine(model_params)
+    first = engine.submit(_prompt(5, seed=21), 20)
+    while not first.tokens:
+        engine.step()
+    mark = len(tracer.spans())
+    second = engine.submit(_prompt(14, seed=22), 4)
+    engine.drain()
+    assert first.status == second.status == "ok"
+    rows = tracer.spans()
+    kids = {}
+    for i, r in enumerate(rows):
+        if r.parent is not None:
+            kids.setdefault(r.parent, []).append(i)
+    windows_by_tick = []
+    for t in [i for i in range(mark, len(rows))
+              if rows[i].name == "serve.tick"]:
+        named = {rows[c].name: rows[c] for c in kids[t]}
+        if "serve.decode_dispatch" not in named:
+            continue
+        lo = named["serve.decode_dispatch"].end_us
+        hi = named["serve.decode_fetch"].start_us
+        grand = [rows[g] for c in kids[t] for g in kids.get(c, ())
+                 if rows[c].args.get("trace_id") == second._req.trace_id]
+        for g in grand:
+            if g.name == "serve.first_token_read":
+                assert lo <= g.start_us and g.end_us <= hi
+        dispatched = [g for g in grand if g.name == "serve.prefill_dispatch"]
+        windows_by_tick.append(
+            ["ahead" if lo <= g.start_us <= hi else "opening"
+             for g in dispatched])
+        assert all(g.end_us <= hi for g in dispatched)
+    # window 0 opens its tick and window 1 runs ahead in it, window 2 runs
+    # ahead in the next, whose own turn is spent; the admitting window is
+    # never ahead
+    assert [w for w in windows_by_tick if w][:3] == [
+        ["opening", "ahead"], ["ahead"], ["opening"]]
+    assert windows_by_tick[1] == ["ahead"] and windows_by_tick[2] == []
+    counts = {r["trace_id"]: r["counts"] for r in reqtrace.completed()}[
+        second._req.trace_id]
+    assert (counts["prefill_ticks"], counts["prefill_windows"]) == (4, 4)
+
+
 def test_profiler_capture_shows_the_same_spans(model_params, tracer,
                                                tmp_path):
     """Under a jax.profiler capture the program's spans are
@@ -405,3 +454,60 @@ def test_train_step_spans_feed_goodput_and_the_save_histogram(tmp_path):
     hist = tele.registry.get("dttpu_checkpoint_save_seconds")
     assert hist.count == 1
     assert hist.sum == pytest.approx(seconds([save]), abs=1e-9)
+
+
+def test_state_snapshot_spans_and_counters_of_a_recurrent_state_model(tracer):
+    """A model with recurrent state beside K/V: every snapshot and restore
+    is a span inside the tick with slot, depth and bytes; the admit span
+    says whether the turn resumed; the decode spans carry what a byte count
+    of the step is made from; the counters agree with the spans and render
+    in the registry.  With no tracer the same calls are null spans."""
+    import jax.numpy as jnp
+    from distributed_tensorflow_tpu.models.hybrid import hybrid_tiny
+    from distributed_tensorflow_tpu.obs import metrics as metrics_lib
+
+    model = hybrid_tiny(conv_state_dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0))
+    registry = metrics_lib.Registry()
+    engine = serve.Engine(model, params, num_slots=2, max_len=128,
+                          registry=registry)
+    first = _prompt(45, seed=3, vocab=128)
+    handle = engine.submit(first, 6)
+    while not handle.done:
+        engine.step()
+    second = np.concatenate([first, np.asarray(handle.tokens, np.int32),
+                             _prompt(9, seed=4, vocab=128)])
+    handle = engine.submit(second, 4)
+    while not handle.done:
+        engine.step()
+
+    spans = tracer.spans()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    snaps, restores = by_name["serve.state_snapshot"], \
+        by_name["serve.state_restore"]
+    state_bytes = serve.pages.state_bytes_per_slot(model)
+    assert [s.args["at"] for s in snaps] == ["prompt_end", "turn_end"] * 2
+    assert [s.args["depth"] for s in snaps] == [45, 50, 60, 63]
+    assert len(restores) == 1 and restores[0].args["depth"] == 50
+    for s in snaps + restores:
+        assert s.args["bytes"] == state_bytes and s.args["slot"] in (0, 1)
+        assert spans[s.parent].name in ("serve.register", "serve.deliver",
+                                        "serve.admit")
+    assert [s.args["resumed"] for s in by_name["serve.admit"]] == \
+        [False, True]
+    for dispatch, fetch in zip(by_name["serve.decode_dispatch"],
+                               by_name["serve.decode_fetch"]):
+        assert dispatch.args["cached_tokens"] >= 45
+        assert 0 < fetch.args["live_steps"] <= dispatch.args["steps"]
+    stats = engine.stats()
+    assert (stats.state_snapshots_total, stats.state_restores_total,
+            stats.state_snapshots_evicted_total) == (4, 1, 0)
+    # a radix node keeps one snapshot, the newest: depths 50, 60 and 63
+    # end in the same page, so two are held now (45 and 63)
+    assert stats.state_snapshot_bytes == 2 * state_bytes
+    assert registry.get("dttpu_serve_state_snapshots_total").value == 4
+    assert registry.get("dttpu_serve_state_restores_total").value == 1
+    assert registry.get("dttpu_serve_state_snapshot_bytes").value == \
+        2 * state_bytes

@@ -222,3 +222,65 @@ def test_fsdp_train_step_is_zero3_on_v5e(topo):
     assert re.search(rf"\[{local},{HEADS},{FSDP_SEQ},{FSDP_SEQ}\]", text)
     assert not re.search(
         rf"\[{FSDP_BATCH},{HEADS},{FSDP_SEQ},{FSDP_SEQ}\]", text)
+
+
+def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
+    """The three hot programs and the two state-snapshot copies of the
+    decoder of state-space and attention layers, at the benchmark's
+    published widths and deployment (32 slots x 4096 tokens, 40 snapshot
+    rows), compiled for the described chip from shapes alone: every donated
+    buffer is aliased (the 2.4 GB of recurrent state and the page pool are
+    updated in place) and the temporaries stay small.  A matrix whose width
+    is no multiple of a lane tile (the fused in-projection's 8512) cost the
+    decode program a 1.25 GB re-laid-out copy of the weights on every
+    dispatch (rehearsal, PR 30): this is the test that sees it."""
+    import json
+    from distributed_tensorflow_tpu.models.hybrid import (HybridConfig,
+                                                          HybridDecoder)
+    from distributed_tensorflow_tpu.serve import pages as pages_lib
+    from distributed_tensorflow_tpu.serve.scheduler import SlotScheduler
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        c = json.load(f)
+    model = HybridDecoder(HybridConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layer_types=tuple(c["layer_types"]),
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"],
+        intermediate_size=c["shared_intermediate_size"],
+        ssm_heads=c["mamba_n_heads"], ssm_head_dim=c["mamba_d_head"],
+        ssm_state=c["mamba_d_state"], conv_width=c["mamba_d_conv"],
+        max_position=c["serve"]["max_len"], param_dtype=jnp.bfloat16))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # shapes, not 6.6 GB of zeros: the scheduler only ever asks its cache
+    # and snapshot arrays for shape and dtype here
+    real = pages_lib.init_paged_cache, pages_lib.init_state_snapshots
+    try:
+        pages_lib.init_paged_cache = lambda *a: jax.eval_shape(
+            lambda: real[0](*a))
+        pages_lib.init_state_snapshots = lambda *a: jax.eval_shape(
+            lambda: real[1](*a))
+        sched = SlotScheduler(model, params,
+                              num_slots=c["serve"]["num_slots"],
+                              max_len=c["serve"]["max_len"])
+    finally:
+        pages_lib.init_paged_cache, pages_lib.init_state_snapshots = real
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    state_gb = 0.0
+    for target in sched.graph_targets():
+        compiled = target.fn.lower(*place(target.args)).compile()
+        memory = compiled.memory_analysis()
+        assert KERNEL_MARK not in compiled.as_text(), target.name
+        assert memory.temp_size_in_bytes < 0.5e9, (target.name, memory)
+        # what is donated comes back in place: the slot cache (3.5 GB) for
+        # the three and the restore, cache + snapshots (6.6 GB) for the
+        # snapshot copy
+        assert memory.alias_size_in_bytes > 3.5e9, (target.name, memory)
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 15.75e9), (target.name, memory)
+        state_gb = max(state_gb, memory.alias_size_in_bytes / 1e9)
+    assert 6.5 < state_gb < 6.7
