@@ -13,6 +13,10 @@ the library's own entry points at full GPT-2-small width — 12 layers, hidden
   serve       serve.Engine(num_slots=16, max_len=1024): mixed-length requests,
               a shared prefix, the fused paged-attention kernel vs the gather
               read path against a float64 host truth
+  serve_pool  the same engine at GPT-2-XL widths (25 heads x 64: a width that
+              is no multiple of a lane tile), 8 slots x 1024: the compiled
+              text of its three hot programs moves no copy or slice the size
+              of the page pool or of one layer of it
 
 ``python3 chip_smoke.py --chips=4`` runs only the path that exists only
 across chips: the same train step on a data x fsdp mesh of four, compared
@@ -37,6 +41,7 @@ import gc
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import time
@@ -63,6 +68,10 @@ FULL = dict(
     train_long=dict(batch=6, seq=2048, steps=3),
     serve=dict(num_slots=16, max_len=1024, new_tokens=32, warm_len=40,
                prefix=128, prompt_lens=(8, 23, 64, 148, 300, 33, 200, 161)),
+    serve_pool=dict(model=dict(vocab_size=50257, hidden_size=1600,
+                               num_layers=48, num_heads=25,
+                               intermediate_size=6400),
+                    num_slots=8, max_len=1024),
     dp4=dict(batch=24, seq=2048, steps=3),
 )
 # Off-TPU only: small enough that XLA:CPU and the Pallas interpreter walk
@@ -74,6 +83,9 @@ TINY = dict(
     train_long=dict(batch=2, seq=64, steps=3),
     serve=dict(num_slots=4, max_len=64, new_tokens=8, warm_len=40,
                prefix=16, prompt_lens=(4, 7, 12, 22, 40, 9, 30, 27)),
+    serve_pool=dict(model=dict(vocab_size=512, hidden_size=256, num_layers=2,
+                               num_heads=4, intermediate_size=128),
+                    num_slots=4, max_len=64),
     dp4=dict(batch=8, seq=64, steps=3),
 )
 
@@ -443,7 +455,7 @@ def _paged_read_errors(model, num_slots, max_len, page_size, window, seed):
     num_pages = num_slots * pps + 1
     layer = c.num_layers - 1
     kk, kv, kq, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
-    shape = (c.num_layers, num_pages, page_size, c.kv_heads, c.head_dim)
+    shape = (c.num_layers, num_pages, page_size, c.kv_heads * c.head_dim)
     pool = {"k": jax.random.normal(kk, shape, c.dtype),
             "v": jax.random.normal(kv, shape, c.dtype)}
     rng = np.random.default_rng(seed)
@@ -586,6 +598,64 @@ def phase_serve(ctx):
     }
 
 
+def pool_moves(text: str, pool_shape) -> list:
+    """The instructions of a compiled program that MOVE the page pool or one
+    whole layer of it: every ``copy`` / ``dynamic-slice`` /
+    ``dynamic-update-slice`` (fused ones too) whose result has the pool's
+    ``[L, num_pages, page_size, width]`` or a layer's ``[num_pages,
+    page_size, width]`` in it.  A hot program writes a few rows and reads a
+    few pages; the scatter that writes them is in place and is not a move."""
+    layer = ",".join(str(int(d)) for d in pool_shape[1:])
+    moved = re.compile(rf"\[(\d+,)?{layer}\]")
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = (\S+) ([\w-]+)\(", line)
+        if m and m.group(3) in ("copy", "copy-start", "dynamic-slice",
+                                "dynamic-update-slice") \
+                and moved.search(m.group(2)):
+            found.append(f"{m.group(1)} = {m.group(2)} {m.group(3)}")
+    return found
+
+
+def phase_serve_pool(ctx):
+    import jax
+    import jax.numpy as jnp
+    from distributed_tensorflow_tpu import serve
+    from distributed_tensorflow_tpu.models.gpt import GPT, GPTConfig
+
+    size = ctx.sizes["serve_pool"]
+    model = GPT(GPTConfig(**size["model"], max_position=size["max_len"],
+                          dtype=jnp.bfloat16, dropout_rate=0.0))
+    params = jax.jit(lambda key: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(key)))(
+            jax.random.PRNGKey(ctx.seed))
+    engine = serve.Engine(model, params, num_slots=size["num_slots"],
+                          max_len=size["max_len"])
+    sched = engine.scheduler
+    stats = engine.stats()
+    targets = sched.graph_targets()
+    pool_shape = targets[0].args[1]["kv"]["k"].shape   # args: params, cache
+    texts = {t.name: t.fn.lower(*t.args).compile().as_text()
+             for t in targets}
+    moves = {name: pool_moves(text, pool_shape)
+             for name, text in texts.items()}
+    return {
+        "num_slots": size["num_slots"], "max_len": size["max_len"],
+        "page_size": sched.page_size, "pool_shape": list(pool_shape),
+        "kv_pool_bytes": stats.kv_pool_bytes,
+        "kv_pool_tiled_bytes": stats.kv_pool_tiled_bytes,
+        "use_paged_kernel": sched.use_paged_kernel,
+        "pool_moves": moves,
+        "checks": {
+            "paged_kernel_in_programs": all(KERNEL_MARK in text
+                                            for text in texts.values()),
+            "pool_tiles_within_5_percent":
+                stats.kv_pool_tiled_bytes <= 1.05 * stats.kv_pool_bytes,
+            "no_pool_sized_moves": not any(moves.values()),
+        },
+    }
+
+
 # ----------------------------------------------------------------------- main
 
 PHASES = {
@@ -593,9 +663,10 @@ PHASES = {
     "train": phase_train,
     "train_long": phase_train_long,
     "serve": phase_serve,
+    "serve_pool": phase_serve_pool,
     "dp4": phase_dp4,
 }
-ONE_CHIP = ("device", "train", "train_long", "serve")
+ONE_CHIP = ("device", "train", "train_long", "serve", "serve_pool")
 FOUR_CHIPS = ("dp4",)
 
 

@@ -886,11 +886,17 @@ class GPT:
     def paged_cache_spec(self) -> Dict[str, Any]:
         """What a slot's paged cache is made of (``serve/pages.py`` builds
         the pool from it): K/V for every layer, int8 scale planes
-        included, and no recurrent state."""
+        included, and no recurrent state.  A token's heads are one FLAT
+        row of ``kv_heads * head_dim`` (a scale plane: ``kv_heads``), as
+        ``HybridDecoder.paged_cache_spec`` has them, so the pool is
+        ``[L, num_pages, page_size, kv_heads * head_dim]``: a page is
+        ``page_size`` sublanes by whole lane tiles and tiles on the TPU
+        with almost no padding (``[.., 25, 64]`` minor dimensions pad
+        2.56 x)."""
         c = self.config
-        token = (c.kv_heads, c.head_dim)
+        token = (c.kv_heads * c.head_dim,)
         if c.kv_cache_dtype == "int8":
-            scale = ((c.kv_heads, 1), jnp.dtype(jnp.float32))
+            scale = ((c.kv_heads,), jnp.dtype(jnp.float32))
             kv = {"k": (token, jnp.dtype(jnp.int8)),
                   "v": (token, jnp.dtype(jnp.int8)),
                   "k_scale": scale, "v_scale": scale}
@@ -924,21 +930,25 @@ class GPT:
         """Layer ``i``'s (k, v) read from a PAGE POOL through per-row
         page tables, in the compute dtype.
 
-        ``kv``: pool subtree with ``[L, num_pages, page_size, kv_heads,
-        ...]`` leaves (serve/pages.py); ``page_tab`` [b, pages_per_row]
+        ``kv``: pool subtree with ``[L, num_pages, page_size, kv_heads *
+        head_dim]`` leaves (scale planes ``[..., kv_heads]``;
+        serve/pages.py); ``page_tab`` [b, pages_per_row]
         int32: row r's logical page j lives at pool page
-        ``page_tab[r, j]``.  The traced gather materializes the same
-        ``[b, view_len, kv_heads, head_dim]`` operand the contiguous
-        slot cache hands attention (``view_len = pages_per_row *
-        page_size``), so downstream attention math — int8 dequant at
-        the operand included — is IDENTICAL to the stripe layout's; the
-        indirection swaps per-slot worst-case stripes for pay-as-you-go
-        pages without touching the compiled attention."""
+        ``page_tab[r, j]``.  One gather on the pool picks the rows'
+        pages of layer ``i``, and a reshape of the gathered rows gives
+        the same ``[b, view_len, kv_heads, head_dim]`` operand the
+        contiguous slot cache hands attention (``view_len =
+        pages_per_row * page_size``), so downstream attention math —
+        int8 dequant at the operand included — is IDENTICAL to the
+        stripe layout's; the indirection swaps per-slot worst-case
+        stripes for pay-as-you-go pages without touching the compiled
+        attention."""
+        kv_heads = self.config.kv_heads
+
         def view(name):
-            layer = lax.dynamic_index_in_dim(kv[name], i, keepdims=False)
-            g = jnp.take(layer, page_tab, axis=0)   # [b, mp, pg, kvh, x]
+            g = kv[name][i, page_tab]               # [b, mp, pg, width]
             return g.reshape(g.shape[0], g.shape[1] * g.shape[2],
-                             *g.shape[3:])
+                             kv_heads, -1)
         k_all, v_all = view("k"), view("v")
         if "k_scale" not in kv:
             return k_all, v_all
@@ -1205,13 +1215,14 @@ class GPT:
         at different sequence lengths share one compiled step.
 
         ``paged``: (page_ids [N], offs [N]) with N = b*s — the cache is
-        a PAGE POOL ([L, num_pages, page_size, kv_heads, ...] leaves,
-        serve/pages.py) and token t of the flattened (b, s) window
-        writes at pool cell ``(page_ids[t], offs[t])`` instead of a
-        column of a per-row stripe.  The traced indices come from a
-        per-slot page table, so every (slot, page) assignment runs the
-        SAME executable; ``write_pos`` is ignored for the write (reads
-        still gather through the table in ``attention``).
+        a PAGE POOL ([L, num_pages, page_size, kv_heads * head_dim]
+        leaves, serve/pages.py) and token t of the flattened (b, s)
+        window writes its flat row at pool cell ``(i, page_ids[t],
+        offs[t])`` instead of a column of a per-row stripe.  The traced
+        indices come from a per-slot page table, so every (slot, page)
+        assignment runs the SAME executable; ``write_pos`` is ignored
+        for the write (reads still go through the table in
+        ``attention``).
         """
         h = self._norm(p["ln_1"], x)
         a = p["attention"]
@@ -1265,21 +1276,18 @@ class GPT:
                     val.astype(kv[name].dtype))
 
         def page_write(name, val):
-            """Pool-cell scatter: the flattened (b, s) tokens land at
-            ``(page_ids[t], offs[t])`` of layer ``i``'s pool plane —
-            scattered on the LAYER slice, then slice-written back, so
-            XLA never lowers a scatter over the whole [L, ...] pool
-            (same layer-slice trick as the contiguous ``row_write``).
+            """Pool-cell scatter, in place on the carried pool: the
+            flattened (b, s) tokens' flat rows land at ``(i,
+            page_ids[t], offs[t])`` — N rows written, and no layer of
+            the pool sliced out or written back (on the chip that moved
+            a 67 MB padded layer twice per leaf to place 8 rows).
             Live slots always map disjoint write cells (a slot's write
             page is private — serve/pages.py); retired rows map the
             reserved trash page 0, whose cells no validity mask ever
             admits, so their frozen writes are dead weight, not state."""
-            flat = val.reshape((-1,) + val.shape[2:])
-            layer = lax.dynamic_index_in_dim(kv[name], i, keepdims=False)
-            layer = layer.at[paged].set(flat.astype(layer.dtype))
-            kv[name] = lax.dynamic_update_slice(
-                kv[name], layer[None],
-                (i,) + (jnp.int32(0),) * layer.ndim)
+            flat = val.reshape(val.shape[0] * val.shape[1], -1)
+            kv[name] = kv[name].at[(i,) + paged].set(
+                flat.astype(kv[name].dtype))
 
         def write(name, val):
             if "k_scale" in kv:
@@ -1519,10 +1527,12 @@ class GPT:
                 params, kv, token_ids, page_row, pos, head=head,
                 adapters=adapters, adapter_rows=adapter_rows)
 
+        kv_heads = self.config.kv_heads
+
         def gather(name):
-            g = jnp.take(kv[name], page_row, axis=1)  # [L, mp, pg, ...]
+            g = jnp.take(kv[name], page_row, axis=1)  # [L, mp, pg, width]
             return g.reshape(g.shape[0], 1, g.shape[1] * g.shape[2],
-                             *g.shape[3:])
+                             kv_heads, -1)
         view = {name: gather(name) for name in kv}
         logits, view = self.decode_window(
             params, dict(view, pos=pos), token_ids, head=head,
@@ -1533,8 +1543,9 @@ class GPT:
         offs = cols % page_size
         new_kv = {}
         for name in kv:
-            vals = jnp.take(view[name][:, 0], cols, axis=1)  # [L, s, ...]
-            new_kv[name] = kv[name].at[:, pids, offs].set(vals)
+            vals = jnp.take(view[name][:, 0], cols, axis=1)  # [L,s,kvh,x]
+            new_kv[name] = kv[name].at[:, pids, offs].set(
+                vals.reshape(vals.shape[:2] + (-1,)))
         return logits, new_kv
 
     def _decode_window_paged_kernel(self, params, kv, token_ids,

@@ -181,11 +181,11 @@ class HybridDecoder:
     """Functional decoder: ``init`` -> params, ``apply`` -> hidden states
     ``[b, s, d]`` (after the final norm), ``logits`` -> LM logits."""
 
-    # The Mosaic paged-attention kernel walks every page of a slot's table,
-    # one page a grid step, and reads [page, kv_heads, head_dim] blocks: it
-    # cannot read this model's flat rows, and at 4096-token tables it would
-    # spend its time on grid steps over unmapped pages.  The scheduler
-    # takes the gather read path for a model that says so.
+    # The Mosaic paged-attention kernel reads the flat rows this pool has
+    # (one layout for every family), but it walks every page of a slot's
+    # table, one page a grid step: at 4096-token tables it would spend its
+    # time on grid steps over unmapped pages.  The scheduler takes the
+    # gather read path for a model that says so.
     paged_kernel_ok = False
 
     def __init__(self, config: HybridConfig, mesh=None):

@@ -1,8 +1,12 @@
 """Fused Pallas paged attention: walk the page table inside the kernel.
 
 The paged serve tier (serve/pages.py) stores K/V as fixed-size pages in
-one pool per leaf — ``[L, num_pages, page_size, kv_heads, head_dim]`` —
-with a per-slot page-table row mapping logical columns to pool pages.
+one pool per leaf — ``[L, num_pages, page_size, kv_heads * head_dim]``,
+a token's heads one flat row, so a page is ``page_size`` sublanes by
+whole lane tiles and the pool tiles almost unpadded whatever the head
+count (``[.., 25, 64]`` minor dimensions padded 2.56 x and cost every hot
+program a relayout of the whole pool) — with a per-slot page-table row
+mapping logical columns to pool pages.
 The XLA read path (``models/gpt.py _paged_layer_kv``) gathers each row's
 pages into a contiguous operand before attention runs; the measured
 ``vs_lockstep_paged`` ≈ 0.75 smoke cost is exactly that gather (the
@@ -29,13 +33,23 @@ Two variants share ONE kernel body (``_make_paged_kernel``):
   mask is computed in-kernel, never materialized at ``view_len``).
 
 Both mirror ``_paged_layer_kv`` + ``ops.attention.dot_product_attention``
-semantics: f32 logits, additive finite ``NEG_INF`` masks (matching
-``ops.attention.NEG_INF``), GQA by head-group reshape (the kv heads are
-never broadcast in memory), int8 KV dequantized at the operand from the
-pool's scale planes.  Masked columns underflow to exactly 0.0 in the
-exp, so the online softmax agrees with the reference full softmax to
-float round-off and greedy token streams are bit-identical
-(tests/test_pages.py pins kernel == gather == contiguous == generate).
+semantics: f32 logits, softmax weights cast to the compute dtype for the
+second matmul, additive finite ``NEG_INF`` masks (matching
+``ops.attention.NEG_INF``), GQA by query rows that share their kv head's
+lanes (the kv heads are never broadcast in memory), int8 KV dequantized
+at the operand from the pool's ``[..., kv_heads]`` scale planes.  The
+kernel reads a page as it lies — ``[page_size, kv_heads * head_dim]`` —
+and never splits heads out of the lane dimension: the wrapper hands it
+BLOCK-DIAGONAL queries — over the whole row for a decode step's few
+query rows, per 128-lane block of it for a window's many
+(``_lane_block``) — so logits and context are plain matmuls against
+aligned lane slices of a step that is bound by the bytes it reads and by
+its fixed costs, not by FLOPs.  ``kv_heads`` is what the leaf's width
+and the query's head size say it is.  Masked columns underflow to
+exactly 0.0 in the exp, so the online softmax agrees with the reference
+full softmax to float round-off and greedy token streams are
+bit-identical (tests/test_pages.py pins kernel == gather == contiguous
+== generate).
 
 Off-TPU the kernel runs in Pallas interpret mode (ops/pallas/common.py),
 so the tier-1 suite executes THIS kernel code on CPU; Mosaic compilation
@@ -68,8 +82,9 @@ __all__ = ["MIN_PAGE_SIZE", "page_size_kernel_ok", "paged_decode_attention",
 # which is what makes kernel-vs-gather agreement testable.
 NEG_INF = -1e9
 
-# Mosaic sublane tile: a k/v page block's second-minor dims tile in
-# units of 8, so the kernel requires page_size % 8 == 0 (and >= 8).
+# Mosaic sublane tile: a page is the sublane dimension of its k/v block
+# ([page_size, kv_heads * head_dim]), which tiles in units of 8, so the
+# kernel requires page_size % 8 == 0 (and >= 8).
 # serve/scheduler.py validates this at construction; serve/pages.py
 # ``auto_page_size(multiple_of=...)`` prefers compatible sizes.
 MIN_PAGE_SIZE = 8
@@ -81,18 +96,57 @@ def page_size_kernel_ok(page_size: int) -> bool:
     return page_size >= MIN_PAGE_SIZE and page_size % MIN_PAGE_SIZE == 0
 
 
-def _make_paged_kernel(*, scale, group, page_size, window_causal,
+# Query rows (kv_heads * group * window rows) up to which the kernel
+# contracts a pool row whole.  On the v5e at GPT-2-XL's 1600-lane row, 64
+# page steps a call (my chip run, PR 31): 25 rows (the decode step, 8
+# slots) 0.307 ms whole against 0.446 by 128-lane blocks, 50 rows 0.056
+# against 0.073; 100 rows 0.075 against 0.063, 200 rows 0.110 against
+# 0.071, 800 rows (a 32-token window) 0.338 against 0.170.
+WHOLE_ROW_MAX_QUERY_ROWS = 64
+
+
+def _lane_block(width: int, head_dim: int, query_rows: int) -> int:
+    """Lanes of a pool row the kernel contracts at once, always whole
+    heads.  Few query rows (a decode step): the row's full width — one
+    matmul a page, ``kv_heads`` times the useful FLOPs of a step whose
+    cost is its per-block bookkeeping.  Many (a prefill window): a whole
+    number of 128-lane tiles wherever the head size allows (two 64-lane
+    heads, one 128-lane head), so every K/V slice starts on a tile
+    boundary and the excess FLOPs stay at ``128 / head_dim`` times."""
+    if query_rows <= WHOLE_ROW_MAX_QUERY_ROWS:
+        return width
+    if head_dim % 128 == 0:
+        return head_dim
+    if 128 % head_dim == 0:
+        return min(128, width)
+    return width
+
+
+def _make_paged_kernel(*, scale, head_dim, blocks, window_causal,
                        quantized):
     """One body for both variants.  Ref order (after the 3 scalar-
     prefetch refs) matches the in_specs built in ``_paged_attention``:
-    q, k, v, [k_scale, v_scale,] valid, out, then acc/m/l scratch."""
+    q, k, v, [k_scale, v_scale,] valid, [row_pos,] out, then acc/m/l
+    scratch.
+
+    ``blocks``: static ``(first lane, lanes, first kv head, kv heads)``
+    per lane block of a pool row.  Block ``j``'s queries arrive
+    BLOCK-DIAGONAL — ``q_ref[0, j]`` is ``[rows, lanes]`` with a row's
+    head vector in the lanes of its K/V head and zeros elsewhere — so
+    its logits are one ``[rows, lanes] x [lanes, page_size]`` matmul
+    against the page's lanes as they lie in the pool, and its context
+    one ``[rows, page_size] x [page_size, lanes]``: heads are never
+    split out of the lane dimension.  A row's context is the lanes of
+    its own head; the wrapper picks them."""
 
     def kernel(layer_ref, tab_ref, pos_ref, q_ref, k_ref, v_ref, *rest):
         del layer_ref, tab_ref  # consumed by the BlockSpec index maps
-        if quantized:
-            ks_ref, vs_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        else:
-            valid_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        rest = list(rest)
+        ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quantized \
+            else (None, None)
+        valid_ref = rest.pop(0)
+        rows_ref = rest.pop(0) if window_causal else None
+        o_ref, acc_ref, m_ref, l_ref = rest
         # program_id must be read at kernel top level (the HLO
         # interpreter cannot lower it inside pl.when).
         pi = pl.program_id(1)
@@ -104,59 +158,70 @@ def _make_paged_kernel(*, scale, group, page_size, window_causal,
             m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        sq, h, hd = q_ref.shape[1:]
-        kvh = k_ref.shape[3]
-        # GQA: q head ih reads kv head ih // group — a reshape, never a
-        # materialized broadcast of the kv heads.
-        q = q_ref[0].astype(jnp.float32).reshape(sq, kvh, group, hd)
-        k = k_ref[0, 0].astype(jnp.float32)   # [page_size, kvh, hd]
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # dequant-at-the-operand from the pool's scale planes,
-            # mirroring quant.dequantize_tensor in _paged_layer_kv.
-            k = k * ks_ref[0, 0]              # [page_size, kvh, 1] f32
-            v = v * vs_ref[0, 0]
-
-        # [kvh, sq, group, page_size] — batch over kv heads.
-        logits = jax.lax.dot_general(
-            q, k, (((3,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pvalid = valid_ref[0, 0, 0]           # [page_size] f32 plane
-        logits = logits + jnp.where(pvalid > 0.5, 0.0, NEG_INF)
+        rows = q_ref.shape[2]
+        page_size = k_ref.shape[2]
+        dtype = q_ref.dtype
+        pvalid = valid_ref[0, 0]              # [1, page_size] f32 plane
+        mask = jnp.where(pvalid > 0.5, 0.0, NEG_INF)
         if window_causal:
-            # logical column of lane t in this page vs window row j:
-            # attend iff col <= pos + j (prefix + causal-in-window),
-            # matching decode_window's positional mask.
+            # logical column of lane t in this page vs the window row a
+            # query row belongs to: attend iff col <= pos + j (prefix +
+            # causal-in-window), matching decode_window's positional
+            # mask.
             col = pi * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (1, sq, 1, page_size), 3)
-            row = jax.lax.broadcasted_iota(
-                jnp.int32, (1, sq, 1, page_size), 1)
-            logits = logits + jnp.where(col <= pos_ref[0] + row,
-                                        0.0, NEG_INF)
+                jnp.int32, (rows, page_size), 1)
+            mask = mask + jnp.where(col <= pos_ref[0] + rows_ref[...],
+                                    0.0, NEG_INF)
 
-        # Online softmax (flash scaffold): masks are FINITE, so only the
-        # -inf init needs the isfinite guard.
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(logits, axis=-1, keepdims=True))
-        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(logits - shift)
-        alpha = jnp.where(jnp.isfinite(m_prev),
-                          jnp.exp(m_prev - shift), 0.0)
-        l_new = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((3,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
-        l_ref[...] = l_new
+        def dequant(x, s_ref, lanes, h0, nh):
+            """int8 page lanes -> compute dtype through the per-(token,
+            head) scale plane, as quant.dequantize_tensor does in
+            _paged_layer_kv: each head's scale column spread over its
+            head_dim lanes."""
+            head = jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, lanes), 1) // head_dim
+            spread = jnp.zeros((page_size, lanes), jnp.float32)
+            for hh in range(nh):
+                spread = jnp.where(head == hh,
+                                   s_ref[0, 0, :, h0 + hh:h0 + hh + 1],
+                                   spread)
+            return (x.astype(jnp.float32) * spread).astype(dtype)
+
+        for j, (lo, lanes, h0, nh) in enumerate(blocks):
+            q = q_ref[0, j, :, :lanes]                # [rows, lanes]
+            k = k_ref[0, 0, :, lo:lo + lanes]         # [page_size, lanes]
+            v = v_ref[0, 0, :, lo:lo + lanes]
+            if quantized:
+                k = dequant(k, ks_ref, lanes, h0, nh)
+                v = dequant(v, vs_ref, lanes, h0, nh)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + mask
+
+            # Online softmax (flash scaffold): masks are FINITE, so only
+            # the -inf init needs the isfinite guard.
+            m_prev = m_ref[j]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(logits - shift)
+            alpha = jnp.where(jnp.isfinite(m_prev),
+                              jnp.exp(m_prev - shift), 0.0)
+            l_ref[j] = alpha * l_ref[j] + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+            # weights in the compute dtype for the MXU, as
+            # ops.attention.dot_product_attention casts them
+            pv = jax.lax.dot_general(
+                p.astype(dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[j, :, :lanes] = acc_ref[j, :, :lanes] * alpha + pv
+            m_ref[j] = m_new
 
         @pl.when(pi == npages - 1)
         def _finalize():
             l = l_ref[...]
-            out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-            out = out.transpose(1, 0, 2, 3).reshape(sq, kvh * group, hd)
-            o_ref[0] = out.astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                        ).astype(o_ref.dtype)
 
     return kernel
 
@@ -165,14 +230,16 @@ def _paged_attention(q, kv, layer, page_tab, valid_plane, pos, *,
                      window_causal, scale=None, interpret=None):
     """Shared pallas_call builder.
 
-    q [B, sq, h, hd]; kv pool dict (k/v [L, num_pages, page_size, kvh,
-    hd], optional k_scale/v_scale [..., 1]); layer traced int32 scalar;
-    page_tab [B, P] int32; valid_plane [B, P, 1, page_size] f32; pos
-    traced window origin (ignored unless window_causal).
+    q [B, sq, h, hd]; kv pool dict (k/v [L, num_pages, page_size, kvh *
+    hd], optional k_scale/v_scale [..., kvh]); layer traced int32
+    scalar; page_tab [B, P] int32; valid_plane [B, P, 1, page_size] f32;
+    pos traced window origin (ignored unless window_causal).  ``kvh`` is
+    what the leaf's width and q's head size say it is.
     Returns [B, sq, h, hd] in q.dtype.
     """
     B, sq, h, hd = q.shape
-    _, _, page_size, kvh, _ = kv["k"].shape
+    _, _, page_size, width = kv["k"].shape
+    kvh = width // hd
     P = page_tab.shape[1]
     group = h // kvh
     if scale is None:
@@ -180,6 +247,22 @@ def _paged_attention(q, kv, layer, page_tab, valid_plane, pos, *,
     if interpret is None:
         interpret = use_interpret()
     quantized = "k_scale" in kv
+
+    # lane blocks of a pool row, and the block-diagonal queries: block j
+    # holds ``hpb`` kv heads (the last maybe fewer: zero rows and lanes),
+    # row ((hh * group + g) * sq + s) the query of head (h0 + hh) * group
+    # + g at window row s, placed in lanes [hh * hd, (hh + 1) * hd)
+    lb = _lane_block(width, hd, kvh * group * sq)
+    hpb = lb // hd
+    nb = -(-kvh // hpb)
+    blocks = tuple((j * lb, min(lb, width - j * lb), j * hpb,
+                    min(hpb, kvh - j * hpb)) for j in range(nb))
+    rows = hpb * group * sq
+    qh = q.reshape(B, sq, kvh, group, hd)
+    qh = jnp.pad(qh, ((0, 0), (0, 0), (0, nb * hpb - kvh), (0, 0), (0, 0)))
+    qh = qh.reshape(B, sq, nb, hpb, group, hd).transpose(0, 2, 3, 4, 1, 5)
+    own = jnp.eye(hpb, dtype=q.dtype)[:, None, None, :, None]
+    q_diag = (qh[:, :, :, :, :, None, :] * own).reshape(B, nb, rows, lb)
 
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     pos_arr = jnp.asarray(0 if pos is None else pos, jnp.int32).reshape(1)
@@ -191,48 +274,58 @@ def _paged_attention(q, kv, layer, page_tab, valid_plane, pos, *,
         return (b, 0, 0, 0)
 
     def kv_map(b, p, lr, tb, ps):
-        return (lr[0], tb[b, p], 0, 0, 0)
+        return (lr[0], tb[b, p], 0, 0)
 
     def valid_map(b, p, lr, tb, ps):
         return (b, p, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, sq, h, hd), q_map),
-        pl.BlockSpec((1, 1, page_size, kvh, hd), kv_map),
-        pl.BlockSpec((1, 1, page_size, kvh, hd), kv_map),
+        pl.BlockSpec((1, nb, rows, lb), q_map),
+        pl.BlockSpec((1, 1, page_size, width), kv_map),
+        pl.BlockSpec((1, 1, page_size, width), kv_map),
     ]
-    inputs = [q, kv["k"], kv["v"]]
+    inputs = [q_diag, kv["k"], kv["v"]]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, page_size, kvh, 1), kv_map)] * 2
+        in_specs += [pl.BlockSpec((1, 1, page_size, kvh), kv_map)] * 2
         inputs += [kv["k_scale"], kv["v_scale"]]
     in_specs.append(pl.BlockSpec((1, 1, 1, page_size), valid_map))
     inputs.append(valid_plane)
+    if window_causal:
+        # the window row of every query row (row order above)
+        in_specs.append(pl.BlockSpec((rows, 1),
+                                     lambda b, p, lr, tb, ps: (0, 0)))
+        inputs.append(jnp.tile(jnp.arange(sq, dtype=jnp.int32),
+                               hpb * group)[:, None])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, sq, h, hd), q_map),
+        out_specs=pl.BlockSpec((1, nb, rows, lb), q_map),
         scratch_shapes=[
-            pltpu.VMEM((kvh, sq, group, hd), jnp.float32),
-            pltpu.VMEM((kvh, sq, group, 1), jnp.float32),
-            pltpu.VMEM((kvh, sq, group, 1), jnp.float32),
+            pltpu.VMEM((nb, rows, lb), jnp.float32),
+            pltpu.VMEM((nb, rows, 1), jnp.float32),
+            pltpu.VMEM((nb, rows, 1), jnp.float32),
         ],
     )
-    kernel = _make_paged_kernel(scale=scale, group=group,
-                                page_size=page_size,
+    kernel = _make_paged_kernel(scale=scale, head_dim=hd, blocks=blocks,
                                 window_causal=window_causal,
                                 quantized=quantized)
     call = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, sq, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nb, rows, lb), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         # the kernel's name in HLO and in a device trace
         name=("dttpu_paged_window" if window_causal
               else "dttpu_paged_decode"),
     )
-    return call(layer_arr, tab, pos_arr, *inputs)
+    out = call(layer_arr, tab, pos_arr, *inputs)
+    # a row's context is the lanes of its own head
+    out = out.reshape(B, nb, hpb, group, sq, hpb, hd)
+    out = jnp.stack([out[:, :, hh, :, :, hh] for hh in range(hpb)], axis=2)
+    out = out.reshape(B, nb * hpb, group, sq, hd)[:, :kvh]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, sq, h, hd)
 
 
 def paged_decode_attention(q, kv, layer, page_tab, valid, *, scale=None,
@@ -274,7 +367,7 @@ def paged_window_attention(q, kv, layer, page_row, pos, *, scale=None,
 
 
 # --- dtlint graph tier registration (docs/ANALYSIS.md) ----------------
-# Budget: the tiny-entry pool (2 layers x 9 pages x 8 x 2 x 16 f32 x 2
+# Budget: the tiny-entry pool (2 layers x 9 pages x 8 x (2 x 16) f32 x 2
 # leaves ~= 36 KiB) + operands, with NO headroom for a gathered
 # [S, view_len, kvh, hd] copy at real scale — DT404 is the static proof
 # that the gather never came back.
@@ -289,8 +382,8 @@ def _graph_entries():
     S, P, PG, KVH, GROUP, HD, L, NP = 2, 4, 8, 2, 2, 16, 2, 9
     h = KVH * GROUP
     sds = jax.ShapeDtypeStruct
-    kv = {"k": sds((L, NP, PG, KVH, HD), jnp.float32),
-          "v": sds((L, NP, PG, KVH, HD), jnp.float32)}
+    kv = {"k": sds((L, NP, PG, KVH * HD), jnp.float32),
+          "v": sds((L, NP, PG, KVH * HD), jnp.float32)}
     return [
         _graph_lib.Target(
             "decode",

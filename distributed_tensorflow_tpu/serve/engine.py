@@ -135,6 +135,13 @@ class ServeMetrics:
         self.state_snapshot_bytes = reg.gauge(
             "dttpu_serve_state_snapshot_bytes",
             "Bytes of recurrent state held in snapshots.")
+        self.kv_pool_bytes = reg.gauge(
+            "dttpu_serve_kv_pool_bytes",
+            "Logical bytes of the page pool's K/V leaves.")
+        self.kv_pool_tiled_bytes = reg.gauge(
+            "dttpu_serve_kv_pool_tiled_bytes",
+            "Bytes the page pool's K/V leaves take once tiled on the "
+            "TPU.")
         # what the pump dispatched (scheduler.py counts each where it
         # happens): over ticks, windows and decode steps a tick
         self.ticks = reg.counter(
@@ -239,6 +246,8 @@ class ServeMetrics:
         self.active_slots.set(stats.active)
         self.pages_free.set(stats.pages_free)
         self.state_snapshot_bytes.set(stats.state_snapshot_bytes)
+        self.kv_pool_bytes.set(stats.kv_pool_bytes)
+        self.kv_pool_tiled_bytes.set(stats.kv_pool_tiled_bytes)
         self.pages_per_request.set(stats.pages_per_request)
         for entry in self._by_delta:
             counter, field, last = entry

@@ -7,8 +7,12 @@ two requests sharing a system prompt each hold their own copy of its
 K/V.  This module replaces the stripe with fixed-size PAGES:
 
 * **Device**: one pool of ``num_pages`` pages per K/V leaf —
-  ``[L, num_pages, page_size, kv_heads, head_dim]`` (int8 scale planes
-  ride along as ``[..., 1]`` — the PR 4 splice-exact int8 layout).
+  ``[L, num_pages, page_size, kv_heads * head_dim]``: a token's heads
+  are one FLAT row, for every model family, so a page is ``page_size``
+  sublanes by whole lane tiles and the pool tiles on the TPU almost
+  unpadded (``kv_pool_bytes`` says by how much; ``[.., 25, 64]`` minor
+  dimensions padded 2.56 x).  int8 scale planes ride along as
+  ``[..., kv_heads]`` (the PR 4 splice-exact per-(token, head) scales).
   Page 0 is the reserved TRASH page: retired rows' frozen writes and
   prefill pad columns land there, and no validity mask ever admits its
   cells.
@@ -89,8 +93,8 @@ import numpy as np
 
 __all__ = ["FINGERPRINT_K", "PageLease", "PagePool", "PagePoolExhausted",
            "StateSnapshot", "auto_page_size", "decode_paged_step",
-           "init_paged_cache", "init_state_snapshots", "paged_kv_valid",
-           "prompt_chain_keys", "state_bytes_per_slot"]
+           "init_paged_cache", "init_state_snapshots", "kv_pool_bytes",
+           "paged_kv_valid", "prompt_chain_keys", "state_bytes_per_slot"]
 
 # default bound on the hot-chain fingerprint (entries, not pages): big
 # enough for a handful of system prompts at every chunk depth, small
@@ -161,7 +165,8 @@ def init_paged_cache(model, num_slots: int, num_pages: int,
     ``kv_layers``, ``kv`` {leaf: (per-token shape, dtype)}, ``state``
     {leaf: (layers, per-slot shape, dtype)}, empty for a model that
     caches keys and values only): a page-pool K/V subtree (``[kv_layers,
-    num_pages, page_size, ...]`` leaves, int8 scale planes included)
+    num_pages, page_size, kv_heads * head_dim]`` leaves, a token's heads
+    one flat row; int8 scale planes ``[..., kv_heads]`` included)
     plus the same per-slot column state the contiguous cache carries
     (serve/slots.py) — ``start_col``/``write_col``/``positions`` stay
     LOGICAL columns; only the storage under them is paged — and, for a
@@ -179,6 +184,25 @@ def init_paged_cache(model, num_slots: int, num_pages: int,
     if spec["state"]:
         cache["state"] = init_state_snapshots(model, num_slots)
     return cache
+
+
+def kv_pool_bytes(kv) -> Tuple[int, int]:
+    """``(logical, tiled)`` bytes of a pool's K/V leaves (arrays or
+    shapes).  Tiled is what the TPU holds: the two minor dimensions
+    rounded up to the dtype's tile, 128 lanes by 8 sublanes of 32 bits
+    (so 16 rows of bf16, 32 of int8) — a ``[page_size, kv_heads *
+    head_dim]`` page of GPT-2-XL pads 4 % where ``[.., 25, 64]`` padded
+    156 %."""
+    logical = tiled = 0
+    for leaf in kv.values():
+        *lead, rows, lanes = leaf.shape
+        itemsize = np.dtype(leaf.dtype).itemsize
+        sublanes = 8 * max(1, 4 // itemsize)
+        lead = int(np.prod(lead, dtype=np.int64)) * itemsize
+        logical += lead * rows * lanes
+        tiled += lead * (-(-rows // sublanes) * sublanes
+                         * -(-lanes // 128) * 128)
+    return logical, tiled
 
 
 def init_state_snapshots(model, rows: int):

@@ -282,6 +282,10 @@ class EngineStats:
     state_restores_total: int = 0            # admissions resumed from one
     state_snapshots_evicted_total: int = 0   # lost to row or page pressure
     state_snapshot_bytes: int = 0            # held now (gauge)
+    # the K/V leaves of the page pool: their logical size and what the
+    # TPU's tiling makes of it (pages.kv_pool_bytes; fixed at build)
+    kv_pool_bytes: int = 0
+    kv_pool_tiled_bytes: int = 0
     prefill_windows_skipped_total: int = 0   # window dispatches avoided
     # prefix-affinity placement inputs (fleet/router.py): the pool's
     # bounded hot-chain digest (chain hash -> cached tokens, already a
@@ -565,6 +569,8 @@ class SlotScheduler:
                        if self.paged
                        else slots_lib.init_slot_cache(model, num_slots,
                                                       max_len))
+        self._kv_pool_bytes = (pages_lib.kv_pool_bytes(self._cache["kv"])
+                               if self.paged else (0, 0))
         # state snapshots: the slot state's layout, one row a snapshot
         # (empty dict for a K/V-only model)
         self._snaps = (pages_lib.init_state_snapshots(
@@ -1019,6 +1025,8 @@ class SlotScheduler:
                 state_snapshots_evicted_total=p[
                     "state_snapshots_evicted_total"],
                 state_snapshot_bytes=p["state_snapshot_bytes"],
+                kv_pool_bytes=self._kv_pool_bytes[0],
+                kv_pool_tiled_bytes=self._kv_pool_bytes[1],
                 prefill_windows_skipped_total=skipped,
                 page_size=p["page_size"],
                 prefix_fingerprint=p["prefix_fingerprint"])

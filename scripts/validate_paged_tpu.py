@@ -51,25 +51,30 @@ def main():
     rng = np.random.default_rng(20260805)
 
     def make_pool(L, NP, PG, kvh, hd, quantized):
-        shape = (L, NP, PG, kvh, hd)
+        """The pool layout of serve/pages.py: a token's heads one flat
+        row, scale planes one scale a head."""
+        shape = (L, NP, PG, kvh * hd)
         if quantized:
+            planes = (L, NP, PG, kvh)
             return {
                 "k": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
                 "v": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
                 "k_scale": jnp.asarray(
-                    rng.uniform(0.01, 0.05, shape[:-1] + (1,)), jnp.float32),
+                    rng.uniform(0.01, 0.05, planes), jnp.float32),
                 "v_scale": jnp.asarray(
-                    rng.uniform(0.01, 0.05, shape[:-1] + (1,)), jnp.float32),
+                    rng.uniform(0.01, 0.05, planes), jnp.float32),
             }
         return {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
                 "v": jnp.asarray(rng.standard_normal(shape), jnp.float32)}
 
     def gather(pool, layer, tab, PG):
-        """The XLA gather read path at script scale."""
+        """The XLA gather read path at script scale: the rows' pages,
+        their flat rows split into heads."""
         view = tab.shape[-1] * PG
+        kvh = pool["k"].shape[-1] // hd
         def g(leaf):
             out = leaf[layer][tab.reshape(-1)]
-            return out.reshape(tab.shape[0], view, *leaf.shape[3:])
+            return out.reshape(tab.shape[0], view, kvh, -1)
         k, v = g(pool["k"]), g(pool["v"])
         if "k_scale" in pool:
             k = k.astype(jnp.float32) * g(pool["k_scale"])
@@ -95,6 +100,8 @@ def main():
     failures = 0
     cases = [
         ("decode_f32", dict(kvh=8, h=8, quantized=False)),
+        # 5 x 64 = 320 lanes: a row that is no multiple of a lane tile
+        ("decode_odd_width", dict(kvh=5, h=5, quantized=False)),
         ("decode_gqa", dict(kvh=2, h=8, quantized=False)),
         ("decode_int8", dict(kvh=2, h=8, quantized=True)),
     ]

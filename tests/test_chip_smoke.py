@@ -31,6 +31,9 @@ CHIP_ONLY_CHECKS = {
     "train": set(),
     "train_long": {"flash_kernel_in_compiled_step"},
     "serve": {"paged_kernel_dispatched", "paged_kernel_in_programs"},
+    # XLA:CPU copies the pool around the interpreted kernel; the v5e's
+    # compiler is the one asked
+    "serve_pool": {"paged_kernel_in_programs", "no_pool_sized_moves"},
     "dp4": {"flash_kernel_in_compiled_step"},
 }
 

@@ -81,13 +81,35 @@ def test_init_paged_cache_shapes_and_int8_planes():
     c = model.config
     cache = pages_lib.init_paged_cache(model, num_slots=3, num_pages=9,
                                        page_size=8)
-    assert cache["kv"]["k"].shape == (c.num_layers, 9, 8, c.kv_heads,
-                                      c.head_dim)
+    # a token's heads are ONE flat row, a scale plane one scale a head
+    assert cache["kv"]["k"].shape == (c.num_layers, 9, 8,
+                                      c.kv_heads * c.head_dim)
     assert cache["kv"]["k"].dtype == jnp.int8
     assert cache["kv"]["k_scale"].shape == (c.num_layers, 9, 8,
-                                            c.kv_heads, 1)
+                                            c.kv_heads)
     assert cache["kv"]["k_scale"].dtype == jnp.float32
     assert cache["write_col"].shape == (3,)
+
+
+@pytest.mark.parametrize("layers,heads", [(48, 25), (24, 16)],
+                         ids=["gpt2-xl", "gpt2-medium"])
+def test_pool_tiles_almost_unpadded_at_published_widths(layers, heads):
+    """The layout's point: under the v5e's (16, 128) bf16 tile the pool
+    takes at most 1.05 x its logical bytes — 8 slots x 1024 tokens of
+    GPT-2-XL are 2.52 GB and tile to 2.62, where ``[.., 25, 64]`` minor
+    dimensions tiled to 6.46."""
+    model = gpt_tiny(num_layers=layers, num_heads=heads,
+                     hidden_size=64 * heads, dtype=jnp.bfloat16)
+    cache = jax.eval_shape(
+        lambda: pages_lib.init_paged_cache(model, 8, 513, 16))
+    logical, tiled = pages_lib.kv_pool_bytes(cache["kv"])
+    assert logical == 2 * layers * 513 * 16 * heads * 64 * 2
+    assert logical <= tiled <= 1.05 * logical
+    # ... and the function sees padding where there is some
+    old = {"k": jax.ShapeDtypeStruct((layers, 513, 16, heads, 64),
+                                     jnp.bfloat16)}
+    logical, tiled = pages_lib.kv_pool_bytes(old)
+    assert tiled == logical * (-(-heads // 16) * 16 * 128) // (heads * 64)
 
 
 def test_pool_validation():
@@ -465,6 +487,16 @@ def test_paged_metrics_land_in_registry():
     assert doc["dttpu_serve_pages_free"]["type"] == "gauge"
     assert doc["dttpu_serve_pages_per_request"]["type"] == "gauge"
     assert doc["dttpu_serve_prefix_hits_total"]["type"] == "counter"
+    # the pool's size as built: logical and tiled (f32 pool here: 8-row
+    # pages by 128 lanes tile exactly)
+    kv = eng.scheduler._cache["kv"]
+    assert st.kv_pool_bytes == sum(v.nbytes for v in kv.values()) > 0
+    assert (st.kv_pool_bytes, st.kv_pool_tiled_bytes) \
+        == pages_lib.kv_pool_bytes(kv)
+    assert reg.get("dttpu_serve_kv_pool_bytes").value == st.kv_pool_bytes
+    assert reg.get("dttpu_serve_kv_pool_tiled_bytes").value \
+        == st.kv_pool_tiled_bytes
+    assert doc["dttpu_serve_kv_pool_tiled_bytes"]["type"] == "gauge"
 
 
 # ---------------------------------------------------------------------------
@@ -489,7 +521,16 @@ def test_auto_page_size_multiple_of():
     {"position_embedding": "rope", "num_heads": 4, "hidden_size": 128,
      "num_kv_heads": 2},
     {"kv_cache_dtype": "int8"},
-], ids=["base", "rope_gqa", "int8"])
+    # K/V rows that are no multiple of 128 lanes, which the flat pool
+    # layout exists for: 5 heads x 64 = 320; the same with grouped
+    # queries and with int8 planes; GPT-2-XL's own 25 x 64 = 1600
+    {"num_heads": 5, "hidden_size": 320},
+    {"position_embedding": "rope", "num_heads": 10, "hidden_size": 640,
+     "num_kv_heads": 5},
+    {"num_heads": 5, "hidden_size": 320, "kv_cache_dtype": "int8"},
+    {"num_heads": 25, "hidden_size": 1600, "intermediate_size": 256},
+], ids=["base", "rope_gqa", "int8", "w320", "w320_rope_gqa", "w320_int8",
+        "w1600_xl_heads"])
 def test_kernel_engine_matches_gather_contiguous_and_generate(kw):
     """The kernel exactness contract, per config family: the fused
     page-walk read path produces token streams bit-identical to the
@@ -554,6 +595,56 @@ def test_prefix_hit_and_cow_exact_through_kernel():
                             registry=metrics_lib.Registry()),
                reqs[1]) == got_b
     assert got_cow == _generate_tokens(model, params, sys_prompt, 7, 64)
+
+
+def _pool_equations(jaxpr, pool_shape, found):
+    """Every equation, however deeply nested, one of whose operands has
+    the pool's shape -> ``found`` {primitive name: count}; a
+    ``pallas_call`` is ONE equation (its body reads blocks, not the
+    pool)."""
+    for eqn in jaxpr.eqns:
+        if any(getattr(v.aval, "shape", None) == pool_shape
+               for v in eqn.invars):
+            name = eqn.primitive.name
+            found[name] = found.get(name, 0) + 1
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pool_equations(sub, pool_shape, found)
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_window"])
+def test_kernel_programs_touch_the_pool_by_scatter_and_kernel_only(
+        program):
+    """With the kernel on, the pool is written by ONE scatter a leaf on
+    the scan-carried array and read by the kernel alone: no
+    ``dynamic_slice`` / ``dynamic_update_slice`` takes a pool leaf (a
+    layer sliced out and written back moved 2 x 67 MB a layer on the
+    chip to place 8 rows), and no gather materializes a view."""
+    model, params = _model_params(num_heads=5, hidden_size=320)
+    pg, pps, slots = 8, 4, 2
+    cache = pages_lib.init_paged_cache(model, slots, slots * pps + 1, pg)
+    kv = cache["kv"]
+    if program == "decode_step":
+        jaxpr = jax.make_jaxpr(
+            lambda kv: model.decode_step_slots_paged(
+                params, kv, jnp.zeros((slots,), jnp.int32),
+                jnp.ones((slots, pps), jnp.int32),
+                jnp.zeros((slots,), jnp.int32),
+                jnp.zeros((slots, pps * pg), bool),
+                jnp.zeros((slots,), jnp.int32), use_kernel=True))(kv)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda kv, pos: model.decode_window_paged(
+                params, kv, jnp.zeros((1, 4), jnp.int32),
+                jnp.ones((pps,), jnp.int32), pos, head="none",
+                use_kernel=True))(kv, jnp.int32(3))
+    found = _pool_equations(jaxpr.jaxpr, kv["k"].shape, {})
+    assert "dynamic_slice" not in found, found
+    assert "dynamic_update_slice" not in found, found
+    assert "gather" not in found, found
+    assert found["scatter"] == 2 and found["pallas_call"] == 1, found
 
 
 @pytest.mark.retrace_guard(budget=1, enforce_donation=True)

@@ -527,29 +527,34 @@ class TestPagedAttention:
     tests/test_pages.py)."""
     L, NP, PG, HD = 2, 14, 8, 16
 
-    def _pool(self, key, kvh, quantized=False):
+    def _pool(self, key, kvh, quantized=False, hd=None):
+        """serve/pages.py's layout: a token's heads one flat row
+        ``[L, NP, PG, kvh * hd]``, scale planes ``[L, NP, PG, kvh]``."""
         kk, kv_, ks, vs = jax.random.split(key, 4)
-        shape = (self.L, self.NP, self.PG, kvh, self.HD)
+        shape = (self.L, self.NP, self.PG, kvh * (hd or self.HD))
         if quantized:
+            planes = shape[:-1] + (kvh,)
             pool = {
                 "k": jax.random.randint(kk, shape, -127, 128, jnp.int8),
                 "v": jax.random.randint(kv_, shape, -127, 128, jnp.int8),
                 "k_scale": jax.random.uniform(
-                    ks, shape[:-1] + (1,), jnp.float32, 0.01, 0.05),
+                    ks, planes, jnp.float32, 0.01, 0.05),
                 "v_scale": jax.random.uniform(
-                    vs, shape[:-1] + (1,), jnp.float32, 0.01, 0.05),
+                    vs, planes, jnp.float32, 0.01, 0.05),
             }
         else:
             pool = {"k": jax.random.normal(kk, shape),
                     "v": jax.random.normal(kv_, shape)}
         return pool
 
-    def _dense_kv(self, pool, layer, tab):
-        """The gather read path at test scale: pages -> contiguous."""
+    def _dense_kv(self, pool, layer, tab, hd=None):
+        """The gather read path at test scale: pages -> contiguous,
+        flat rows -> heads."""
         view = tab.shape[-1] * self.PG
+        kvh = pool["k"].shape[-1] // (hd or self.HD)
         def gather(leaf):
             g = leaf[layer][tab.reshape(-1)]
-            return g.reshape(tab.shape[0], view, *leaf.shape[3:])
+            return g.reshape(tab.shape[0], view, kvh, -1)
         k, v = gather(pool["k"]), gather(pool["v"])
         if "k_scale" in pool:
             k = k.astype(jnp.float32) * gather(pool["k_scale"])
@@ -579,6 +584,43 @@ class TestPagedAttention:
                 mask=padding_mask(valid))
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("kvh,h,hd,quantized", [
+        (5, 5, 64, False),      # 320 lanes: two and a half lane tiles
+        (25, 25, 64, False),    # GPT-2-XL's own row, 1600 lanes
+        (5, 10, 64, False),     # grouped queries over an odd width
+        (5, 10, 64, True),      # int8 planes, one scale a head
+        (3, 3, 128, False),     # a head a lane tile
+        (3, 6, 48, False),      # a head size that divides no lane tile
+    ], ids=["w320", "w1600", "w320_gqa", "w320_int8", "hd128", "hd48"])
+    def test_odd_widths_match_gather(self, kvh, h, hd, quantized):
+        """Rows that are no multiple of 128 lanes — the layout exists
+        for them — through both variants: the decode step over several
+        slots (few query rows: the row contracted whole) and a causal
+        window over one row (past 64 query rows: by 128-lane blocks)."""
+        S, P, s, pos = 2, 3, 8, 5
+        pool = self._pool(jax.random.PRNGKey(21), kvh, quantized, hd=hd)
+        rng = np.random.default_rng(23)
+        tab = jnp.asarray(rng.choice(self.NP, size=(S, P), replace=False),
+                          jnp.int32)
+        view = P * self.PG
+        valid = jnp.asarray(rng.random((S, view)) < 0.6).at[:, 0].set(True)
+        q = jax.random.normal(jax.random.PRNGKey(22), (S, 1, h, hd))
+        k, v = self._dense_kv(pool, 1, tab, hd=hd)
+        np.testing.assert_allclose(
+            np.asarray(paged_decode_attention(q, pool, 1, tab, valid)),
+            np.asarray(dot_product_attention(
+                q, k.astype(q.dtype), v.astype(q.dtype),
+                mask=padding_mask(valid))), atol=3e-6, rtol=3e-6)
+        qw = jax.random.normal(jax.random.PRNGKey(24), (1, s, h, hd))
+        cols = jnp.arange(view)[None, None, None, :]
+        rows = jnp.arange(s)[None, None, :, None]
+        np.testing.assert_allclose(
+            np.asarray(paged_window_attention(qw, pool, 1, tab[0], pos)),
+            np.asarray(dot_product_attention(
+                qw, k[:1].astype(q.dtype), v[:1].astype(q.dtype),
+                mask=jnp.where(cols <= pos + rows, 0.0, -1e9))),
+            atol=3e-6, rtol=3e-6)
 
     @pytest.mark.parametrize("pos", [0, 5, 17])
     def test_window_matches_reference(self, pos):
@@ -629,8 +671,8 @@ class TestPagedAttention:
         for leaf in ("k", "v"):
             scrambled[leaf] = pool[leaf].at[:, trash].set(
                 jax.random.normal(jax.random.PRNGKey(99),
-                                  (self.L, trash.size, self.PG, kvh,
-                                   self.HD)))
+                                  (self.L, trash.size, self.PG,
+                                   kvh * self.HD)))
         got = np.asarray(paged_decode_attention(q, scrambled, 0, tab,
                                                 valid))
         assert np.array_equal(base, got)

@@ -63,40 +63,46 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
-def _pool(page_size, kv_heads, quantized, sharding):
-    shape = (LAYERS, SLOTS * (MAX_LEN // page_size) + 1, page_size,
-             kv_heads, HEAD_DIM)
+def _pool(page_size, kv_heads, quantized, sharding, slots=SLOTS):
+    """serve/pages.py's layout: a token's heads one flat row."""
+    shape = (LAYERS, slots * (MAX_LEN // page_size) + 1, page_size,
+             kv_heads * HEAD_DIM)
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
     if quantized:
         return {"k": sds(shape, jnp.int8), "v": sds(shape, jnp.int8),
-                "k_scale": sds(shape[:-1] + (1,), jnp.float32),
-                "v_scale": sds(shape[:-1] + (1,), jnp.float32)}
+                "k_scale": sds(shape[:-1] + (kv_heads,), jnp.float32),
+                "v_scale": sds(shape[:-1] + (kv_heads,), jnp.float32)}
     return {"k": sds(shape, jnp.bfloat16), "v": sds(shape, jnp.bfloat16)}
 
 
-def _paged_decode(page_size=16, kv_heads=HEADS, quantized=False):
+def _paged_decode(page_size=16, heads=HEADS, kv_heads=None, quantized=False,
+                  slots=SLOTS):
     def build(topo):
         one = SingleDeviceSharding(topo.devices[0])
         sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
         fn = lambda q, pool, layer, tab, valid: \
             paged_mod.paged_decode_attention(q, pool, layer, tab, valid,
                                              interpret=False)
-        return fn, (sds((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16),
-                    _pool(page_size, kv_heads, quantized, one),
+        return fn, (sds((slots, 1, heads, HEAD_DIM), jnp.bfloat16),
+                    _pool(page_size, kv_heads or heads, quantized, one,
+                          slots),
                     sds((), jnp.int32),
-                    sds((SLOTS, MAX_LEN // page_size), jnp.int32),
-                    sds((SLOTS, MAX_LEN), jnp.bool_))
+                    sds((slots, MAX_LEN // page_size), jnp.int32),
+                    sds((slots, MAX_LEN), jnp.bool_))
     return build
 
 
-def _paged_window(topo):
-    one = SingleDeviceSharding(topo.devices[0])
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
-    fn = lambda q, pool, layer, row, pos: paged_mod.paged_window_attention(
-        q, pool, layer, row, pos, interpret=False)
-    return fn, (sds((1, WINDOW, HEADS, HEAD_DIM), jnp.bfloat16),
-                _pool(16, HEADS, False, one), sds((), jnp.int32),
-                sds((MAX_LEN // 16,), jnp.int32), sds((), jnp.int32))
+def _paged_window(heads=HEADS, quantized=False):
+    def build(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
+        fn = lambda q, pool, layer, row, pos: \
+            paged_mod.paged_window_attention(q, pool, layer, row, pos,
+                                             interpret=False)
+        return fn, (sds((1, WINDOW, heads, HEAD_DIM), jnp.bfloat16),
+                    _pool(16, heads, quantized, one), sds((), jnp.int32),
+                    sds((MAX_LEN // 16,), jnp.int32), sds((), jnp.int32))
+    return build
 
 
 def _flash(grad):
@@ -139,7 +145,13 @@ CASES = {
     "paged_decode_bf16_page128": _paged_decode(page_size=128),
     "paged_decode_int8_planes": _paged_decode(quantized=True),
     "paged_decode_gqa4": _paged_decode(kv_heads=4),
-    "paged_window_32": _paged_window,
+    # GPT-2-XL's row: 25 heads x 64 = 1600 lanes, twelve and a half tiles
+    "paged_decode_xl_heads25": _paged_decode(heads=25, slots=8),
+    "paged_decode_xl_heads25_int8": _paged_decode(heads=25, slots=8,
+                                                  quantized=True),
+    "paged_window_32": _paged_window(),
+    "paged_window_32_xl_heads25": _paged_window(heads=25),
+    "paged_window_32_int8_planes": _paged_window(quantized=True),
     "flash_forward": _flash(grad=False),
     "flash_backward": _flash(grad=True),
     "flash_mesh_data4": _flash_on_mesh({"data": 4}),
@@ -224,6 +236,65 @@ def test_fsdp_train_step_is_zero3_on_v5e(topo):
         rf"\[{FSDP_BATCH},{HEADS},{FSDP_SEQ},{FSDP_SEQ}\]", text)
 
 
+def _scheduler_from_shapes(model, params, **kw):
+    """A ``SlotScheduler`` whose cache and snapshot arrays are shapes, not
+    gigabytes of zeros: it only ever asks them for shape and dtype here."""
+    from distributed_tensorflow_tpu.serve import pages as pages_lib
+    from distributed_tensorflow_tpu.serve.scheduler import SlotScheduler
+    real = pages_lib.init_paged_cache, pages_lib.init_state_snapshots
+    try:
+        pages_lib.init_paged_cache = lambda *a: jax.eval_shape(
+            lambda: real[0](*a))
+        pages_lib.init_state_snapshots = lambda *a: jax.eval_shape(
+            lambda: real[1](*a))
+        return SlotScheduler(model, params, **kw)
+    finally:
+        pages_lib.init_paged_cache, pages_lib.init_state_snapshots = real
+
+
+def test_gpt2_xl_serving_programs_move_no_pool_sized_copy_on_v5e(topo):
+    """The three hot programs of the GPT-2-XL serving cell (8 slots x 1024,
+    25 heads x 64, the Mosaic kernel on) compiled for the described chip
+    from shapes alone.  With ``[.., 25, 64]`` minor dimensions every one of
+    them re-laid out the whole 2.5 GB pool on entry and exit and sliced a
+    layer out and back per write (48 % of the cell's device time, ledger
+    PR 30); with flat rows the compiled text holds no copy or slice the size
+    of the pool or of a layer of it, the pool is updated in place, and the
+    temporaries are far under a second pool."""
+    from chip_smoke import pool_moves
+    from distributed_tensorflow_tpu.models.gpt import GPT, GPTConfig
+
+    model = GPT(GPTConfig(vocab_size=50257, hidden_size=1600, num_layers=48,
+                          num_heads=25, intermediate_size=6400,
+                          max_position=MAX_LEN, dtype=jnp.bfloat16,
+                          dropout_rate=0.0))
+    params = jax.eval_shape(lambda key: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(key)),
+        jax.random.PRNGKey(0))
+    sched = _scheduler_from_shapes(model, params, num_slots=8,
+                                   max_len=MAX_LEN, use_paged_kernel=True)
+    pool = sched._cache["kv"]["k"]
+    assert pool.shape == (48, 8 * (MAX_LEN // 16) + 1, 16, 1600)
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    real = paged_mod.use_interpret
+    paged_mod.use_interpret = lambda: False    # the backend here is the CPU
+    try:
+        compiled = {t.name: t.fn.lower(*place(t.args)).compile()
+                    for t in sched.graph_targets()}
+    finally:
+        paged_mod.use_interpret = real
+    pool_gb = 2 * pool.size * 2 / 1e9
+    for name, program in compiled.items():
+        text, memory = program.as_text(), program.memory_analysis()
+        assert KERNEL_MARK in text, name
+        assert pool_moves(text, pool.shape) == [], name
+        assert memory.alias_size_in_bytes > pool_gb * 1e9, (name, memory)
+        assert memory.temp_size_in_bytes < 0.5 * pool_gb * 1e9, (name,
+                                                                 memory)
+
+
 def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
     """The three hot programs and the two state-snapshot copies of the
     decoder of state-space and attention layers, at the benchmark's
@@ -237,8 +308,6 @@ def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
     import json
     from distributed_tensorflow_tpu.models.hybrid import (HybridConfig,
                                                           HybridDecoder)
-    from distributed_tensorflow_tpu.serve import pages as pages_lib
-    from distributed_tensorflow_tpu.serve.scheduler import SlotScheduler
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
@@ -254,19 +323,9 @@ def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
         ssm_state=c["mamba_d_state"], conv_width=c["mamba_d_conv"],
         max_position=c["serve"]["max_len"], param_dtype=jnp.bfloat16))
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    # shapes, not 6.6 GB of zeros: the scheduler only ever asks its cache
-    # and snapshot arrays for shape and dtype here
-    real = pages_lib.init_paged_cache, pages_lib.init_state_snapshots
-    try:
-        pages_lib.init_paged_cache = lambda *a: jax.eval_shape(
-            lambda: real[0](*a))
-        pages_lib.init_state_snapshots = lambda *a: jax.eval_shape(
-            lambda: real[1](*a))
-        sched = SlotScheduler(model, params,
-                              num_slots=c["serve"]["num_slots"],
-                              max_len=c["serve"]["max_len"])
-    finally:
-        pages_lib.init_paged_cache, pages_lib.init_state_snapshots = real
+    sched = _scheduler_from_shapes(model, params,      # 6.6 GB of shapes
+                                   num_slots=c["serve"]["num_slots"],
+                                   max_len=c["serve"]["max_len"])
     one = SingleDeviceSharding(topo.devices[0])
     place = lambda tree: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
