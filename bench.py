@@ -1356,12 +1356,9 @@ def bench_gpt_serve():
     mesh), like the other decode rows; wall clocks close with host
     value fetches on both sides.
 
-    BOTH storage layouts replay the mixed trace: ``vs_lockstep`` stays
-    the CONTIGUOUS stripe engine's ratio (the PR 4 comparator, so the
-    metric is comparable across rounds) and ``vs_lockstep_paged`` is
-    the default paged engine's — on this CPU smoke the XLA-emulated
-    page gather costs fusion in the tiny-model tick, which is exactly
-    what the side-by-side number makes visible (docs/SERVING.md).
+    ``vs_lockstep`` is the engine's ratio on the mixed trace
+    (``vs_lockstep_paged`` is the same number under its older name, kept
+    so that no reader of the row loses a key).
 
     Two more measured phases (serve/pages.py):
 
@@ -1372,11 +1369,12 @@ def bench_gpt_serve():
       engine, same paging, reuse ablated): ``vs_no_reuse`` is the
       cache's own win, ``prefix_hit_rate``/``prefill_windows_skipped``
       the mechanism, and the TTFT p50 delta the latency effect.
-    * ``slots_at_fixed_mem``: with the page pool capped at the
-      contiguous layout's HBM budget (``slots`` full stripes), a burst
+    * ``slots_at_fixed_mem``: with the page pool capped at the HBM of
+      ``slots`` full ``[max_len]`` stripes, a burst
       of short requests shows how many slots the paged engine actually
-      runs CONCURRENTLY — strictly more than the stripe layout's
-      ``slots``, because pages are allocated per actual footprint.
+      runs CONCURRENTLY — strictly more than the ``slots`` that a
+      stripe per slot would hold, because pages are allocated per
+      actual footprint.
     """
     import jax
     import numpy as np
@@ -1473,7 +1471,7 @@ def bench_gpt_serve():
     # best of 2 windows on BOTH sides (the WINDOWS rationale: a
     # background spike landing in one side's single window flips the
     # ratio); TTFTs are reported from the best engine window
-    eng = make_engine()                          # paged (the default)
+    eng = make_engine()
     wall_engine, handles = min(
         (replay_engine(eng, prompts, budgets, arrivals, tenants)
          for _ in range(2)), key=lambda r: r[0])
@@ -1481,12 +1479,6 @@ def bench_gpt_serve():
     engine_tps = total_tokens / wall_engine
     ttft_p50, ttft_p95 = ttft_pcts(handles)
     page_size = eng.scheduler.page_size
-
-    eng_c = make_engine(paged=False)             # the PR 4 comparator
-    wall_contig, handles_c = min(
-        (replay_engine(eng_c, prompts, budgets, arrivals, tenants)
-         for _ in range(2)), key=lambda r: r[0])
-    contig_tps = sum(len(h.tokens) for h in handles_c) / wall_contig
 
     # Kernel read path: the SAME paged layout read through the fused
     # Pallas page-walk kernel instead of the XLA gather.  Off-TPU the
@@ -1531,14 +1523,12 @@ def bench_gpt_serve():
         wall_lock = w if wall_lock is None else min(wall_lock, w)
     lock_tps = float(budgets.sum()) / wall_lock
 
-    ratio_contig = contig_tps / lock_tps
     ratio_paged = engine_tps / lock_tps
     ratio_kernel = kernel_tps / lock_tps
     kernel_vs_gather = kernel_tps / engine_tps
-    log(f"gpt_serve: paged {engine_tps:,.0f} tok/s, contiguous "
-        f"{contig_tps:,.0f}, kernel {kernel_tps:,.0f}, lockstep "
-        f"{lock_tps:,.0f} "
-        f"(contiguous {ratio_contig:.2f}x / paged {ratio_paged:.2f}x / "
+    log(f"gpt_serve: paged {engine_tps:,.0f} tok/s, "
+        f"kernel {kernel_tps:,.0f}, lockstep {lock_tps:,.0f} "
+        f"(paged {ratio_paged:.2f}x / "
         f"kernel {ratio_kernel:.2f}x, kernel vs gather "
         f"{kernel_vs_gather:.2f}x), "
         f"ttft p50 {ttft_p50*1e3:.1f} ms / p95 {ttft_p95*1e3:.1f} ms "
@@ -1636,8 +1626,8 @@ def bench_gpt_serve():
         f"ttft p50 {shared_prefix['ttft_p50_ms']:.1f} ms vs "
         f"{shared_prefix['no_reuse_ttft_p50_ms']:.1f} ms uncached")
 
-    # ---- slots_at_fixed_mem: concurrency at the contiguous budget ----
-    # Page pool capped at the stripe layout's HBM (slots full stripes);
+    # ---- slots_at_fixed_mem: concurrency at a stripe-per-slot budget ----
+    # Page pool capped at the HBM of ``slots`` full [max_len] stripes;
     # 2x the slots; a same-tick burst of short requests.  Peak
     # concurrent ACTIVE slots is the measured claim: pages allocated
     # per actual footprint, not per worst-case stripe.
@@ -1655,7 +1645,7 @@ def bench_gpt_serve():
         peak_active = max(peak_active, eng_m.stats().active)
     assert all(h.done for h in b_handles)
     log(f"gpt_serve slots_at_fixed_mem: {peak_active} concurrent slots "
-        f"on a {slots}-stripe budget (contiguous layout: {slots})")
+        f"on a {slots}-stripe budget (a stripe per slot: {slots})")
 
     # ---- tracing overhead: the span-emission budget, measured ----
     # The mixed trace replayed with request tracing ON (ids minted at
@@ -1775,11 +1765,10 @@ def bench_gpt_serve():
     return dict(metric="gpt_serve_tokens_per_sec_per_chip",
                 value=round(engine_tps, 1), unit="tokens/sec/chip",
                 tracing=tracing,
-                vs_baseline=round(ratio_contig, 3),  # lock-step, same run
+                vs_baseline=round(ratio_paged, 3),  # lock-step, same run
                 tokens_per_sec=round(engine_tps, 1),
-                contiguous_tokens_per_sec=round(contig_tps, 1),
                 lockstep_tokens_per_sec=round(lock_tps, 1),
-                vs_lockstep=round(ratio_contig, 3),
+                vs_lockstep=round(ratio_paged, 3),
                 vs_lockstep_paged=round(ratio_paged, 3),
                 kernel_tokens_per_sec=round(kernel_tps, 1),
                 vs_lockstep_paged_kernel=round(ratio_kernel, 3),
@@ -2407,8 +2396,7 @@ def _fleet_affinity_real():
         reg = metrics_lib.Registry()
         engines = [serve.Engine(model, params, num_slots=slots,
                                 max_len=128, prefill_chunk=chunk,
-                                tick_steps=ticks, registry=reg,
-                                paged=True)
+                                tick_steps=ticks, registry=reg)
                    for _ in range(2)]
         router = fleet.Router(engines, registry=reg,
                               affinity_weight=weight)

@@ -787,6 +787,13 @@ class _FunctionWalk:
             if binding is not None:
                 self.resources[idx].guard = binding
             return idx
+        if proto.kind == "value" and binding is not None:
+            # one resource a site: a second path's state reaching the
+            # same acquire is the same resource (each state tracks its
+            # own status), or the name would follow only the last birth
+            idx = self.by_name.get(binding)
+            if idx is not None and self.resources[idx].key == key:
+                return idx
         idx = len(self.resources)
         res = _Resource(idx, proto, call, binding, key)
         self.resources.append(res)

@@ -214,7 +214,7 @@ class Autoscaler:
         removing the fleet's only copy of a hot prefix re-prefills it
         from scratch for every follower), then least inflight, ties by
         highest id — retire the newest capacity first.  Fleets without
-        fingerprints (contiguous engines, cold pools) score 0
+        fingerprints (prefix cache off, cold pools) score 0
         everywhere and keep the original least-loaded choice exactly.
         A drain that times out is rolled back with ``resume_replica``
         instead of failing requests."""

@@ -330,7 +330,7 @@ class PageWire:
         if not records:
             return 0
         if not hasattr(dest, "import_wire_pages"):
-            return 0                      # contiguous engine: degrade
+            return 0                      # no page pool: degrade
         t0 = time.perf_counter()
         # tokens covered comes from the snapshot manifest when present
         # (authoritative), else from chunk order x page size

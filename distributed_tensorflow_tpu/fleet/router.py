@@ -122,7 +122,7 @@ def expected_pages_reused(prompt, stats, manifest=None) -> int:
     cache — the affinity term of the placement score.  The deepest
     fingerprint match wins; the cached length caps what a shallower
     cached chain can give.  0 when the replica publishes no
-    fingerprint (contiguous engine, cold pool, prefix cache off) —
+    fingerprint (cold pool, prefix cache off, no pool at all) —
     which is what makes the blind fallback exact.
 
     ``manifest`` (a ``RequestSnapshot.shipped_pages`` tuple) overrides
@@ -446,7 +446,7 @@ class Router:
     def pages_free(self) -> int:
         """Fleet-wide free KV pages (sum over live paged replicas) —
         the admission-headroom signal a capacity autoscaler would act
-        on; 0 when every replica runs the contiguous layout."""
+        on; 0 when no replica reports a pool."""
         return sum(s.pages_free for s in self.stats().values())
 
     def load_adapter(self, adapter_id: str, adapter) -> None:

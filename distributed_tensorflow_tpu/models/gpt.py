@@ -936,11 +936,11 @@ class GPT:
         int32: row r's logical page j lives at pool page
         ``page_tab[r, j]``.  One gather on the pool picks the rows'
         pages of layer ``i``, and a reshape of the gathered rows gives
-        the same ``[b, view_len, kv_heads, head_dim]`` operand the
-        contiguous slot cache hands attention (``view_len =
+        the same ``[b, view_len, kv_heads, head_dim]`` operand
+        ``decode_step`` reads from an ``init_cache`` row (``view_len =
         pages_per_row * page_size``), so downstream attention math —
         int8 dequant at the operand included — is IDENTICAL to the
-        stripe layout's; the indirection swaps per-slot worst-case
+        ``generate()`` path's; the indirection swaps per-slot worst-case
         stripes for pay-as-you-go pages without touching the compiled
         attention."""
         kv_heads = self.config.kv_heads
@@ -1029,88 +1029,21 @@ class GPT:
         logits = self.logits(params, x)[:, 0, :]
         return logits, dict(new_kv, pos=pos + 1)
 
-    def decode_step_slots(self, params, kv, token_ids, write_col,
-                          kv_valid, positions, adapters=None,
-                          adapter_rows=None):
-        """One token per row against a SLOT cache (continuous batching).
-
-        The serving tier's hot step (serve/): ``kv`` is a position-free
-        cache subtree ({k, v[, k_scale, v_scale]} — the ``init_cache``
-        layout minus ``pos``) whose batch dimension is a bank of SLOTS,
-        each holding an independent request.  Per-row state replaces the
-        scalar ``pos``: row r's incoming token is written at column
-        ``write_col[r]`` (per-row scatter, see ``_cache_layer``),
-        attention sees the columns flagged in ``kv_valid[r]`` plus the
-        token's own column, and ``positions[r]`` supplies the row's
-        position index — the token count, which differs from
-        ``write_col`` when the slot was spliced from a LEFT-padded
-        ragged prefill.  Per row the math is exactly ``decode_step`` at
-        ``pos = write_col[r]``, and every op is row-independent, so
-        admitting or retiring one slot cannot change another slot's
-        logits (bit-identity pinned by tests/test_serve.py).
-
-        Returns (logits [b, vocab] f32, new kv).  State advancement —
-        marking the written column valid, bumping write_col/positions —
-        is the caller's job (serve.slots.decode_slots_step), because
-        only the scheduler knows which rows are live.
-
-        ``adapters`` / ``adapter_rows`` [b]: per-row LoRA deltas from a
-        stacked adapter table (see the LoRA section above) — row r runs
-        table row ``adapter_rows[r]``'s adapter; row 0 of the table is
-        the zero adapter, so mixing adapter and non-adapter requests in
-        one tick costs one gather, never a recompile.
-        """
-        c = self.config
-        emb = params["embeddings"]
-        x = jnp.take(emb["word"], token_ids, axis=0)[:, None, :]  # [b,1,d]
-        if c.position_embedding == "learned":
-            x = x + jnp.take(emb["position"], positions,
-                             axis=0)[:, None, :]
-        x = x.astype(c.dtype)
-
-        max_len = kv["k"].shape[2]
-        valid = kv_valid | (jnp.arange(max_len)[None, :]
-                            == write_col[:, None])
-        kv_mask = jnp.where(valid, 0.0, attn_lib.NEG_INF)[:, None, None, :]
-
-        rope_cs = None
-        if c.position_embedding == "rope":
-            rope_cs = attn_lib.rope_tables(positions[:, None], c.head_dim,
-                                           base=c.rope_base)
-
-        def attention(q, k_blk, v_blk, kv, i):
-            del k_blk, v_blk   # single token: read back through the cache
-            k_cache, v_cache = self._dequant_layer_kv(kv, i)
-            return attn_lib.dot_product_attention(q, k_cache, v_cache,
-                                                  mask=kv_mask)
-
-        def body(carry, inputs):
-            x, kv = carry
-            p, i = inputs
-            return self._cache_layer(p, x, kv, i,
-                                     write_pos=write_col, rope_cs=rope_cs,
-                                     attention=attention,
-                                     adapters=adapters,
-                                     adapter_rows=adapter_rows), None
-
-        (x, new_kv), _ = lax.scan(
-            body, (x, dict(kv)),
-            (params["decoder"], jnp.arange(c.num_layers)))
-        x = self._norm(params["ln_f"], x)
-        return self.logits(params, x)[:, 0, :], new_kv
-
     def decode_step_slots_paged(self, params, kv, token_ids, page_tab,
                                 write_col, kv_valid, positions,
                                 adapters=None, adapter_rows=None,
                                 use_kernel: bool = False):
-        """``decode_step_slots`` against a PAGED slot cache.
+        """One token per row against a PAGED slot cache (continuous
+        batching): the serving tier's hot step (serve/).
 
-        Same per-row semantics as ``decode_step_slots`` — row r's token
-        writes at its logical column ``write_col[r]``, attends
-        ``kv_valid[r]`` plus its own column, embeds at ``positions[r]``
-        — but the K/V live in a shared page pool (``kv``: ``[L,
-        num_pages, page_size, ...]`` leaves) indexed by the per-row
-        ``page_tab`` [b, pages_per_row]: reads gather each row's pages
+        The batch dimension is a bank of SLOTS, each an independent
+        request, and per-row state replaces ``decode_step``'s scalar
+        ``pos``: row r's token writes at its logical column
+        ``write_col[r]``, attends ``kv_valid[r]`` plus its own column,
+        and embeds at ``positions[r]`` (the row's token count).  The
+        K/V live in a shared page pool (``kv``: ``[L, num_pages,
+        page_size, ...]`` leaves) indexed by the per-row ``page_tab``
+        [b, pages_per_row]: reads gather each row's pages
         into the usual ``[b, view_len, ...]`` operand
         (``_paged_layer_kv``), the write scatters into pool cell
         ``(page_tab[r, write_col[r] // page_size], write_col[r] %
@@ -1121,9 +1054,19 @@ class GPT:
         are retired: their writes land where no validity mask looks.
 
         Returns (logits [b, vocab] f32, new kv pool).  Per row the math
-        is exactly ``decode_step_slots``'s on the gathered view — the
-        serve tier's paged==contiguous bit-identity tests hold it
-        there.
+        is exactly ``decode_step`` at ``pos = write_col[r]`` on the
+        gathered view, and every op is row-independent, so admitting or
+        retiring one slot cannot change another slot's logits
+        (tests/test_pages.py).  State advancement — bumping
+        write_col/positions — is the caller's job
+        (serve.pages.decode_paged_step), because only the scheduler
+        knows which rows are live.
+
+        ``adapters`` / ``adapter_rows`` [b]: per-row LoRA deltas from a
+        stacked adapter table (see the LoRA section above) — row r runs
+        table row ``adapter_rows[r]``'s adapter; row 0 of the table is
+        the zero adapter, so mixing adapter and non-adapter requests in
+        one tick costs one gather, never a recompile.
 
         ``use_kernel`` (STATIC, resolved by the caller through
         ``attn_lib.resolve_use_paged_kernel``): read the pool through
@@ -1208,11 +1151,9 @@ class GPT:
         (see the LoRA section) — q/k/v deltas add BEFORE RoPE so the
         result equals projecting with the merged kernel.
 
-        ``write_pos`` may be a scalar (one column for the whole batch —
-        the generate/beam path) or a [b] vector (per-row columns — the
-        slot-serving path, ``decode_step_slots``): vector positions
-        write by scatter, one (row, column-run) per batch row, so slots
-        at different sequence lengths share one compiled step.
+        The write has two cases.  ``write_pos``, a scalar: one column
+        for the whole batch (decode_step, decode_block, decode_window —
+        the generate/beam/speculative path).
 
         ``paged``: (page_ids [N], offs [N]) with N = b*s — the cache is
         a PAGE POOL ([L, num_pages, page_size, kv_heads * head_dim]
@@ -1245,35 +1186,6 @@ class GPT:
             q = attn_lib.apply_rope(q, *rope_cs)
             k = attn_lib.apply_rope(k, *rope_cs)
         zero = jnp.zeros((), jnp.int32)
-        per_row = paged is None and jnp.ndim(write_pos) == 1
-        if per_row:
-            b, s = x.shape[:2]
-            if s == 1:
-                # single-token serving step: a per-row masked overwrite
-                # of the layer slice beats XLA's general scatter
-                # (measured ~1.5x on CPU), and the slice is read back by
-                # attention anyway.  hit: [b, max_len, 1, 1]
-                max_len = kv["k"].shape[2]
-                hit = (jnp.arange(max_len)[None, :]
-                       == write_pos[:, None])[:, :, None, None]
-            else:
-                rows = jnp.arange(b)[:, None]                      # [b,1]
-                cols = write_pos[:, None] + jnp.arange(s)[None, :]  # [b,s]
-
-        def row_write(name, val):
-            """Per-row positions: masked layer overwrite for s=1, a
-            scatter for window writes.  Out-of-bounds columns (a slot
-            past max_len) hit nothing / are dropped — never clamped
-            onto live entries."""
-            if s == 1:
-                layer = lax.dynamic_index_in_dim(kv[name], i,
-                                                 keepdims=False)
-                layer = jnp.where(hit, val.astype(layer.dtype), layer)
-                kv[name] = lax.dynamic_update_slice(
-                    kv[name], layer[None], (i,) + (zero,) * layer.ndim)
-            else:
-                kv[name] = kv[name].at[i, rows, cols].set(
-                    val.astype(kv[name].dtype))
 
         def page_write(name, val):
             """Pool-cell scatter, in place on the carried pool: the
@@ -1299,9 +1211,6 @@ class GPT:
                 if paged is not None:
                     page_write(name, qt.q)
                     page_write(name + "_scale", qt.scale)
-                elif per_row:
-                    row_write(name, qt.q)
-                    row_write(name + "_scale", qt.scale)
                 else:
                     kv[name] = lax.dynamic_update_slice(
                         kv[name], qt.q[None],
@@ -1311,8 +1220,6 @@ class GPT:
                         (i, zero, write_pos, zero, zero))
             elif paged is not None:
                 page_write(name, val)
-            elif per_row:
-                row_write(name, val)
             else:
                 kv[name] = lax.dynamic_update_slice(
                     kv[name], val[None].astype(kv[name].dtype),
@@ -1494,14 +1401,14 @@ class GPT:
 
         Structure: gather the row's pages ONCE into a contiguous
         ``[L, 1, view_len, ...]`` stripe, run the UNMODIFIED
-        ``decode_window`` on it (so the window math is the contiguous
-        engine's to the bit — and the layer scan carries one stripe,
+        ``decode_window`` on it (so the window math is ``generate()``'s
+        to the bit — and the layer scan carries one stripe,
         never the whole pool), then scatter the ``s`` written columns
         back to their pool cells ``(page_row[c // page_size], c %
         page_size)``.  Pad columns of the last window map whatever
         ``page_row`` holds there (the reserved trash page 0 when
-        unallocated) — written but never valid, exactly the contiguous
-        path's dead-weight pads.
+        unallocated) — written but never valid: dead weight, not
+        state.
 
         ``head`` as in ``decode_window``.  Returns (logits, new kv
         pool) — the pool subtree carries no ``pos``; the caller owns

@@ -253,7 +253,7 @@ class FederatedMetrics:
         ``dttpu_serve_page_size`` matched on the same key.  Returns
         ``{source label tuple: RemoteAffinity}``; chains rendered 0
         (evicted on the engine) are dropped, and sources publishing no
-        page size (contiguous engines) score affinity 0 downstream.
+        page size (engines with no pool) score affinity 0 downstream.
 
         This is the cross-host half of prefix-affinity routing
         (fleet/router.py): the serve tier renders the pool fingerprint

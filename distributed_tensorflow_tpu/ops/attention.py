@@ -90,9 +90,8 @@ def resolve_use_flash(use_flash, seq_len: int) -> bool:
 # removes costs O(view_len) HBM traffic per layer per step, so the
 # kernel wins as soon as the gathered operand stops fitting the fusion
 # window — measured crossover printed by scripts/validate_paged_tpu.py;
-# override with DTTPU_PAGED_KERNEL_MIN_VIEW, re-calibrate on new
-# hardware.
-_PAGED_KERNEL_MIN_VIEW_DEFAULT = 512
+# re-calibrate on new hardware.
+_PAGED_KERNEL_MIN_VIEW = 512
 
 
 def paged_kernel_wins(view_len: int) -> bool:
@@ -100,12 +99,9 @@ def paged_kernel_wins(view_len: int) -> bool:
     real TPU backend and only at per-slot view lengths past the measured
     crossover (off-TPU the interpret-mode kernel is a correctness tool,
     never a win)."""
-    import os
-
     import jax as _jax
-    min_view = int(os.environ.get("DTTPU_PAGED_KERNEL_MIN_VIEW",
-                                  _PAGED_KERNEL_MIN_VIEW_DEFAULT))
-    return view_len >= min_view and _jax.default_backend() == "tpu"
+    return (view_len >= _PAGED_KERNEL_MIN_VIEW
+            and _jax.default_backend() == "tpu")
 
 
 def resolve_use_paged_kernel(use_paged_kernel, view_len: int) -> bool:

@@ -48,8 +48,8 @@ its fixed costs, not by FLOPs.  ``kv_heads`` is what the leaf's width
 and the query's head size say it is.  Masked columns underflow to
 exactly 0.0 in the exp, so the online softmax agrees with the reference
 full softmax to float round-off and greedy token streams are
-bit-identical (tests/test_pages.py pins kernel == gather == contiguous
-== generate).
+bit-identical (tests/test_pages.py pins kernel == gather ==
+generate).
 
 Off-TPU the kernel runs in Pallas interpret mode (ops/pallas/common.py),
 so the tier-1 suite executes THIS kernel code on CPU; Mosaic compilation

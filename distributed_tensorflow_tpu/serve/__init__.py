@@ -5,10 +5,13 @@ decode step stays hot while requests are admitted and retired with no
 retracing — the batch dimension of the KV cache becomes a bank of
 SLOTS, each an independent request at its own length.
 
-Four layers (docs/SERVING.md):
+Three layers (docs/SERVING.md):
 
-* ``serve.slots`` — the slot cache state: per-slot kv_valid/write_col/
-  positions, the ``insert_slot`` splice, the all-slots decode step.
+* ``serve.pages`` — the K/V storage and the slot cache state: a device
+  page pool with per-slot page tables and start_col/write_col/positions,
+  the all-slots decode step, host free-list/refcount bookkeeping, and a
+  radix prefix cache that lets requests sharing a prompt prefix map the
+  same read-only pages and skip those prefill windows.
 * ``serve.scheduler`` — the state machine: chunked prefill (one
   fixed-width window per tick), K-step decode dispatches, EOS/budget
   retirement, slot reuse.
@@ -17,19 +20,12 @@ Four layers (docs/SERVING.md):
   TTFT and per-request decode histograms, token counters) on the
   existing ``/metrics`` endpoint.
 
-* ``serve.pages`` — the paged K/V memory layer (the default storage):
-  a device page pool with per-slot page tables, host free-list/refcount
-  bookkeeping, and a radix prefix cache that lets requests sharing a
-  prompt prefix map the same read-only pages and skip those prefill
-  windows (``paged=False`` keeps the contiguous stripe layout).
-
 Measured by ``bench.py --config=gpt_serve`` against a lock-step-batching
 baseline in the same process; exactness (single request == greedy
-``GPT.generate``, admission never perturbs other slots, paged ==
-contiguous bit-for-bit) is pinned by tests/test_serve.py and
-tests/test_pages.py.
+``GPT.generate``, admission never perturbs other slots, kernel read ==
+gather read) is pinned by tests/test_serve.py and tests/test_pages.py.
 """
-from . import adapters, engine, pages, scheduler, slots
+from . import adapters, engine, pages, scheduler
 from .adapters import AdapterTable, AdapterTableFull
 from .engine import (DrainResult, Engine, QueueFullError, RequestHandle,
                      ServeMetrics)
@@ -38,14 +34,10 @@ from .pages import (PageLease, PagePool, PagePoolExhausted,
                     paged_kv_valid)
 from .scheduler import (EngineStats, Request, RequestSnapshot,
                         SlotScheduler)
-from .slots import (decode_slots_step, init_slot_cache, insert_slot,
-                    slot_kv_valid, strip_pos)
 
 __all__ = ["AdapterTable", "AdapterTableFull", "DrainResult", "Engine",
            "EngineStats", "PageLease", "PagePool", "PagePoolExhausted",
            "QueueFullError", "RequestHandle", "RequestSnapshot",
            "ServeMetrics", "Request", "SlotScheduler", "auto_page_size",
-           "decode_paged_step", "decode_slots_step", "init_paged_cache",
-           "init_slot_cache", "insert_slot", "paged_kv_valid",
-           "slot_kv_valid", "strip_pos",
-           "adapters", "engine", "pages", "scheduler", "slots"]
+           "decode_paged_step", "init_paged_cache", "paged_kv_valid",
+           "adapters", "engine", "pages", "scheduler"]

@@ -101,9 +101,9 @@ class ServeMetrics:
             "engine) at which an imported request resumed.",
             buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
                      256.0, 512.0))
-        # paged-KV series (serve/pages.py; flat zero on a contiguous
-        # engine) — rendered from the same Engine.stats() snapshot as
-        # the gauges above, so there is exactly ONE bookkeeping source
+        # page-pool series (serve/pages.py) — rendered from the same
+        # Engine.stats() snapshot as the gauges above, so there is
+        # exactly ONE bookkeeping source
         self.pages_free = reg.gauge(
             "dttpu_serve_pages_free",
             "KV-cache pool pages on the free list.")
@@ -166,8 +166,7 @@ class ServeMetrics:
         # rides along: remote scorers must chunk prompts identically.
         self.page_size_gauge = reg.gauge(
             "dttpu_serve_page_size",
-            "KV page-pool page size in tokens (0 on a contiguous "
-            "engine).")
+            "KV page-pool page size in tokens.")
         self._chain_gauges: dict = {}
         # counters render by delta against the stats() snapshot (the
         # exposition forbids decreasing counters; stats are monotonic)
@@ -376,20 +375,19 @@ class DrainResult:
 class Engine:
     """Continuous-batching serving engine over one jitted decode step.
 
-    K/V storage is PAGED by default (``paged=True``, serve/pages.py):
-    slots hold fixed-size pool pages through per-slot page tables
-    instead of full ``[max_len]`` stripes — memory scales with actual
-    request lengths, requests sharing a prompt prefix map the same
-    read-only radix-cached pages and skip those prefill windows
-    entirely, and allocation/sharing/eviction never recompile the hot
-    executables.  ``paged=False`` restores the contiguous stripe
-    layout; ``page_size``/``num_pages`` tune the pool (defaults: the
-    largest divisor of ``max_len`` <= 16, and the contiguous layout's
-    token capacity).  Output tokens are bit-identical either way
+    K/V storage is the page pool (serve/pages.py): slots hold
+    fixed-size pool pages through per-slot page tables instead of full
+    ``[max_len]`` stripes — memory scales with actual request lengths,
+    requests sharing a prompt prefix map the same read-only
+    radix-cached pages and skip those prefill windows entirely, and
+    allocation/sharing/eviction never recompile the hot executables.
+    ``page_size``/``num_pages`` tune the pool (defaults: the largest
+    divisor of ``max_len`` <= 16, and ``max_len`` tokens for every
+    slot).  Output tokens are bit-identical to ``GPT.generate``'s
     (tests/test_pages.py).
 
     Args mirror ``SlotScheduler`` (num_slots, max_len, prefill_chunk,
-    tick_steps, temperature/top_k/top_p, eos_id/pad_id, rng, paged/
+    tick_steps, temperature/top_k/top_p, eos_id/pad_id, rng,
     page_size/num_pages) plus:
 
       registry: obs metrics registry to record into (default: the
@@ -598,8 +596,8 @@ class Engine:
         ``export_request``: the export's lease handoff published the
         pages into the radix tree, where they stay readable (and
         evictable — whatever was evicted since simply doesn't ship).
-        Returns ``[]`` for a snapshot without a manifest, a contiguous
-        engine, or a pump busy past ``timeout_s`` — the migration then
+        Returns ``[]`` for a snapshot without a manifest, a pool with
+        its prefix cache off, or a pump busy past ``timeout_s`` — the migration then
         proceeds as plain re-prefill."""
         manifest = getattr(snap, "shipped_pages", None)
         if not manifest:

@@ -1,10 +1,11 @@
 """Paged KV cache + shared-prefix (radix) reuse: the memory layer of
 the serving tier.
 
-The contiguous slot cache (serve/slots.py) gives every slot a full
-``[max_len]`` K/V stripe, so HBM scales with the WORST-CASE length and
-two requests sharing a system prompt each hold their own copy of its
-K/V.  This module replaces the stripe with fixed-size PAGES:
+The batch dimension of the K/V cache is a bank of SLOTS, each one
+independent in-flight request at its own length.  A full ``[max_len]``
+K/V stripe per slot would scale HBM with the WORST-CASE length, and two
+requests sharing a system prompt would each hold their own copy of its
+K/V.  So the storage under the slots is fixed-size PAGES:
 
 * **Device**: one pool of ``num_pages`` pages per K/V leaf —
   ``[L, num_pages, page_size, kv_heads * head_dim]``: a token's heads
@@ -140,8 +141,9 @@ def auto_page_size(max_len: int, target: int = 16,
                    multiple_of: int = 1) -> int:
     """Largest divisor of ``max_len`` that is <= ``target``.  Pages
     must tile ``max_len`` exactly so the gathered page view has the
-    SAME shape as the contiguous stripe — that shape equality is what
-    makes paged attention bit-identical to the stripe layout.
+    SAME shape as a ``[max_len]`` cache row of ``GPT.init_cache`` — that
+    shape equality is what makes paged attention bit-identical to the
+    ``generate()`` path.
 
     ``multiple_of`` additionally constrains the result to multiples of
     that value — the fused paged-attention kernel's lane-tileability
@@ -167,9 +169,11 @@ def init_paged_cache(model, num_slots: int, num_pages: int,
     caches keys and values only): a page-pool K/V subtree (``[kv_layers,
     num_pages, page_size, kv_heads * head_dim]`` leaves, a token's heads
     one flat row; int8 scale planes ``[..., kv_heads]`` included)
-    plus the same per-slot column state the contiguous cache carries
-    (serve/slots.py) — ``start_col``/``write_col``/``positions`` stay
-    LOGICAL columns; only the storage under them is paged — and, for a
+    plus the per-slot column state [S] — a slot's tokens occupy the
+    LOGICAL column run ``[start_col, write_col)`` (validity is two ints;
+    the boolean view is derived per step, ``paged_kv_valid``, never
+    stored), ``positions`` is its next position index; all slots start
+    retired (an empty window at column 0) — and, for a
     model with recurrent state, one ``[layers, num_slots, ...]`` block
     per state leaf under ``"state"`` (absent otherwise, so a K/V-only
     model's programs are what they were)."""
@@ -224,9 +228,11 @@ def state_bytes_per_slot(model) -> int:
 
 
 def paged_kv_valid(cache, view_len: int):
-    """[S, view_len] bool view of each slot's valid LOGICAL columns —
-    the paged twin of ``slots.slot_kv_valid`` (the pool's own shape no
-    longer encodes the per-slot view length, so it is passed in)."""
+    """[S, view_len] bool view of each slot's valid LOGICAL columns
+    (the pool's shape does not encode the per-slot view length, so it
+    is passed in).  Masked columns get exp(NEG_INF) = 0 attention weight:
+    whatever a previous occupant left behind is multiplied by an exact
+    zero, so retirement never scrubs a page."""
     import jax.numpy as jnp
     cols = jnp.arange(view_len)[None, :]
     return ((cols >= cache["start_col"][:, None])
@@ -237,11 +243,14 @@ def decode_paged_step(model, params, cache, page_tab, tokens, live,
                       adapters=None, adapter_rows=None,
                       use_kernel: bool = False):
     """One decode step for every slot against the page pool -> (logits
-    [S, vocab], new cache).  The paged twin of
-    ``slots.decode_slots_step``: same frozen-dead-row semantics, same
-    per-row state advancement; ``page_tab`` [S, pages_per_slot] is the
-    traced page-table snapshot for this tick (retired rows map the
-    trash page, so their frozen writes can never touch a live page).
+    [S, vocab], new cache).  ``tokens`` [S]: each live slot's input
+    token (the one it emitted last).  Dead rows compute too (static
+    shapes — the price of never recompiling) but their state is FROZEN:
+    only ``live`` rows advance write_col/positions, and row independence
+    makes live rows' logits bit-identical whatever the dead rows hold.
+    ``page_tab`` [S, pages_per_slot] is the traced page-table snapshot
+    for this tick (retired rows map the trash page, so their frozen
+    writes can never touch a live page).
     ``use_kernel`` (STATIC, resolved once at scheduler construction):
     read through the fused Pallas page-walk kernel instead of the XLA
     gather (models/gpt.py ``decode_step_slots_paged``)."""

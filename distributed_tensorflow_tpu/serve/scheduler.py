@@ -3,23 +3,21 @@
 The control plane of the continuous-batching engine (docs/SERVING.md).
 All device work goes through THREE jitted functions built once at
 construction — a mid-prefill window, a fused last-prefill window
-(+ first-token sample + slot splice/arm), and the K-step decode tick —
+(+ first-token sample + slot arm), and the K-step decode tick —
 each with fully static shapes, so admitting and retiring requests never
 recompiles anything (pinned by tests/test_serve.py under the runtime
 sanitizer, and warn-checked by ``bench.py --config=gpt_serve``).
 
-Two storage layouts behind the SAME state machine (``paged=``, default
-True): the paged layout (serve/pages.py) maps slot columns to
-fixed-size pool pages through per-slot page tables — prefill writes
+One storage layout: the page pool (serve/pages.py) maps slot columns
+to fixed-size pool pages through per-slot page tables — prefill writes
 straight into the request's leased pages, shared prompt prefixes map
 the same read-only radix-cached pages and skip their prefill windows,
 and page allocation/eviction is host bookkeeping handed to the same
-three executables as traced arguments.  ``paged=False`` keeps the
-contiguous per-slot stripes (the exactness comparator).
+three executables as traced arguments.
 
 Request lifecycle::
 
-    QUEUED --admission--> PREFILLING --insert_slot--> ACTIVE --> FINISHED
+    QUEUED --admission--> PREFILLING --last window--> ACTIVE --> FINISHED
                 (free slot)   (chunked)    (first token)  (EOS/budget)
 
 Any in-flight state is also EXPORTABLE as a portable ``RequestSnapshot``
@@ -29,17 +27,18 @@ set to ``prompt + generated`` and its token list pre-seeded, so decode
 resumes where the source stopped through the SAME three executables.
 
 * **Chunked prefill**: the prompt is RIGHT-padded to a multiple of
-  ``prefill_chunk`` and streamed through ``GPT.decode_window`` one
-  fixed-width window per tick, into a pooled batch-1 prefill cache — so
-  a long prompt never stalls in-flight decodes for more than one window
-  per tick, and every prompt length reuses the same two executables.
+  ``prefill_chunk`` and streamed through ``GPT.decode_window_paged`` one
+  fixed-width window per tick, straight into the pages the request
+  leased at admission — so a long prompt never stalls in-flight decodes
+  for more than one window per tick, and every prompt length reuses the
+  same two executables.
   Free slots are filled eagerly: up to one prefill per free slot runs
   concurrently (each advancing one window per tick), so a burst of
   arrivals admits at slot rate, not one request per tick.  The pad
   columns are written but never flagged valid, so they are dead weight,
   not state.  The last window gathers logits at the prompt's real final
-  position, samples the first token, and splices the cache into its
-  slot in the SAME dispatch (time-to-first-token stops when that token
+  position, samples the first token, and arms the slot's column state
+  in the SAME dispatch (time-to-first-token stops when that token
   reaches the host).
 * **Decode tick**: ``tick_steps`` decode steps scanned inside ONE
   dispatch (the same dispatch-amortization lever as
@@ -49,14 +48,14 @@ resumes where the source stopped through the SAME three executables.
   stream to the host once per tick, so retirement/admission decisions
   lag at most one tick.
 * **Retirement**: EOS (when configured) or the request's token budget.
-  A retired slot is immediately admissible; ``insert_slot``'s validity
-  window guarantees the newcomer never attends the departed request's
-  K/V.
+  A retired slot is immediately admissible; the slot's validity window
+  (``pages.paged_kv_valid``) and its own page-table row guarantee the
+  newcomer never attends the departed request's K/V.
 
 Exactness contract: with one request in flight the emitted tokens equal
 ``GPT.generate``'s greedy output token-for-token, and admission
 mid-decode leaves other slots' logits bit-identical — see
-``GPT.decode_step_slots`` and tests/test_serve.py.
+``GPT.decode_step_slots_paged`` and tests/test_pages.py.
 
 Thread-safety contract (dtlint DT3xx + tests/test_thread_safety.py):
 ``submit``/``cancel``/``stats`` may run on any thread concurrently with
@@ -68,12 +67,12 @@ the pump.  Two locks, strictly ordered pump -> state:
   race-free and concurrent ``step()`` callers simply queue behind the
   running tick;
 * ``_lock`` guards host bookkeeping (queue, slots table, prefill list,
-  cache pool, tenant counters) in short critical sections that never
+  page tables, tenant counters) in short critical sections that never
   span a device dispatch or a user callback.
 
 Cross-thread ``cancel`` never touches device arrays: it marks the row
 in ``_stale_rows`` (the pump freezes it at the next tick) and moves an
-in-flight prefill to the orphan list (the pump pools its cache).  Token
+in-flight prefill to the orphan list (the pump releases its lease).  Token
 delivery and terminal transitions are queued in tick order and flushed
 at the END of the tick — holding the pump mutex but NOT the state lock,
 so a slow ``on_token`` callback never blocks a concurrent ``submit``.
@@ -96,7 +95,6 @@ from ..obs import trace as trace_lib
 from ..resilience import faults as faults_lib
 from ..ops import decoding as dec
 from . import pages as pages_lib
-from . import slots as slots_lib
 from .adapters import AdapterTableFull
 
 __all__ = ["EngineStats", "QueueFullError", "Request", "RequestSnapshot",
@@ -145,8 +143,8 @@ class Request:
     # terminal transitions are claim-once (cancel vs pump races resolve
     # in _retire_accounting under the scheduler lock)
     _retired: bool = dataclasses.field(default=False, repr=False)
-    # paged engines: the request's page holdings (serve/pages.py),
-    # granted at prefill begin, released once at retirement
+    # the request's page holdings (serve/pages.py), granted at prefill
+    # begin, released once at retirement
     _lease: Optional[object] = dataclasses.field(default=None,
                                                  repr=False)
     # migration (import_snapshot): ``context`` is what prefill actually
@@ -265,8 +263,8 @@ class EngineStats:
     num_slots: int
     inflight_per_tenant: Dict[str, int]      # queued+prefilling+active
     tokens_inflight_per_tenant: Dict[str, int]   # sum of max_new_tokens
-    # paged engines only (serve/pages.py; all-zero on a contiguous
-    # engine): page-pool occupancy and radix prefix-cache counters —
+    # page-pool occupancy and radix prefix-cache counters (serve/pages.py;
+    # all-zero from an engine that reports no pool, e.g. the simulator's) —
     # the single source the dttpu_serve_pages_*/dttpu_serve_prefix_*
     # series render from
     pages_total: int = 0                     # pool capacity (sans trash)
@@ -290,8 +288,9 @@ class EngineStats:
     # prefix-affinity placement inputs (fleet/router.py): the pool's
     # bounded hot-chain digest (chain hash -> cached tokens, already a
     # copy — see PagePool.fingerprint) and the page size the router
-    # needs to chunk candidate prompts identically.  Empty/0 on a
-    # contiguous engine, which degrades the router to least-loaded
+    # needs to chunk candidate prompts identically.  Empty/0 from an
+    # engine that reports no pool, which degrades the router to
+    # least-loaded
     page_size: int = 0
     prefix_fingerprint: Dict[bytes, int] = dataclasses.field(
         default_factory=dict)
@@ -371,6 +370,19 @@ def _written_context(req: "Request") -> np.ndarray:
             if len(fresh) > 1 else ctx)
 
 
+@dataclasses.dataclass(slots=True, eq=False)
+class _Prefill:
+    """One in-flight prefill.  Compared by identity: ``st in
+    self._prefills`` and ``.remove(st)`` mean THIS prefill."""
+    req: Request
+    windows: np.ndarray                      # [n, 1, W] int32
+    next: int                                # index of the next window
+    lease: pages_lib.PageLease
+    plan: List[Tuple[int, int, int]]         # (pos, real, snapshot depth)
+    slot: Optional[int]                      # a recurrent-state model's row
+    ran_ahead: bool = False                  # this tick's window is out
+
+
 class SlotScheduler:
     """Drive a slot cache for a GPT-family ``model``/``params`` pair.
 
@@ -387,7 +399,7 @@ class SlotScheduler:
                  eos_id: Optional[int] = None, pad_id: Optional[int] = None,
                  rng=None, metrics=None, queue=None, adapters=None,
                  max_queue_depth: Optional[int] = None, tenancy=None,
-                 paged: bool = True, page_size: Optional[int] = None,
+                 page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  prefix_cache: bool = True,
                  use_paged_kernel="auto"):
@@ -437,97 +449,86 @@ class SlotScheduler:
         self.metrics = metrics if metrics is not None else _NullMetrics()
         self.adapters = adapters
         self.max_queue_depth = max_queue_depth
-        # paged K/V (serve/pages.py, the default): slot columns map to
-        # fixed-size pool pages through per-slot page tables, prefill
-        # writes straight into the request's pages (no pooled [1,
-        # max_len] spares at all), and shared prompt prefixes map the
-        # same read-only pages.  paged=False keeps the contiguous
-        # stripe layout — the exactness comparator and the fallback.
-        self.paged = bool(paged)
-        self.pages: Optional[pages_lib.PagePool] = None
-        self._page_tab = None
         self._windows_skipped = 0
-        self.use_paged_kernel = False
         # a model whose slot cache holds recurrent state beside K/V
         # (``paged_cache_spec()["state"]``: models/hybrid.py).  Its slots
         # are reserved when a prefill STARTS (the windows build the state
-        # in the slot's row), its prefix hits resume from state snapshots
-        # (serve/pages.py), and it serves paged only.
+        # in the slot's row) and its prefix hits resume from state
+        # snapshots (serve/pages.py).
         self._stateful = bool(model.paged_cache_spec()["state"])
-        if self._stateful and not self.paged:
-            raise ValueError("a model with recurrent state serves from the "
-                             "paged cache only (paged=True)")
         if not getattr(model, "paged_kernel_ok", True):
             if use_paged_kernel is True:
                 raise ValueError(
                     f"{type(model).__name__} cannot read its pages through "
                     "the paged-attention kernel (paged_kernel_ok is False)")
             use_paged_kernel = False
-        if self.paged:
-            from ..ops import attention as attn_lib
-            from ..ops.pallas import paged_attention as paged_kernel_lib
-            if page_size:
-                page_size = int(page_size)
-            else:
-                # prefer a kernel-tileable size whenever the kernel may
-                # dispatch; plain largest-divisor pick otherwise
-                page_size = pages_lib.auto_page_size(
-                    max_len,
-                    multiple_of=(1 if use_paged_kernel is False
-                                 else paged_kernel_lib.MIN_PAGE_SIZE))
-            if page_size < 1 or max_len % page_size:
-                raise ValueError(
-                    f"page_size must divide max_len {max_len} (the "
-                    f"gathered page view must tile the stripe shape "
-                    f"exactly); got {page_size}")
-            # fused-kernel gate: resolved ONCE here (the executables
-            # below close over the static answer — no retrace surface).
-            # An explicit use_paged_kernel=True with a non-tileable
-            # page_size is a configuration error, surfaced NOW as a
-            # ValueError instead of a Mosaic failure inside the kernel;
-            # "auto" falls back to the gather read path with a logged
-            # reason.
-            kernel_ok = paged_kernel_lib.page_size_kernel_ok(page_size)
-            if use_paged_kernel is True and not kernel_ok:
-                raise ValueError(
-                    f"use_paged_kernel=True requires a lane-tileable "
-                    f"page_size (a multiple of "
-                    f"{paged_kernel_lib.MIN_PAGE_SIZE}, Mosaic's "
-                    f"sublane tile); got page_size={page_size}. Pick a "
-                    f"compatible page_size or leave use_paged_kernel="
-                    f"'auto' to fall back to the gather read path.")
-            resolved = attn_lib.resolve_use_paged_kernel(
-                use_paged_kernel, max_len)
-            if resolved and not kernel_ok:
-                import warnings
-                warnings.warn(
-                    f"paged-attention kernel disabled: page_size "
-                    f"{page_size} is not a multiple of "
-                    f"{paged_kernel_lib.MIN_PAGE_SIZE} (Mosaic lane "
-                    f"tiling) — falling back to the XLA gather read "
-                    f"path", RuntimeWarning, stacklevel=2)
-                resolved = False
-            self.use_paged_kernel = resolved
-            pps = max_len // page_size
-            if num_pages is None:
-                # default: the contiguous layout's token capacity
-                # (num_slots stripes) plus the reserved trash page —
-                # same HBM, now shareable and pay-as-you-go (floor:
-                # one full slot plus a spare, the pool's own minimum)
-                num_pages = max(num_slots * pps + 1, pps + 2)
-            self.page_size = page_size
-            self.num_pages = int(num_pages)
-            # snapshot budget: a row for every slot's turn end and eight
-            # for shared prefixes (a system prompt's, a chain met without
-            # one), each the size of one slot's state: the deployment's
-            # configuration counts them with its bytes.  None where the
-            # model caches keys and values only
-            snapshot_rows = num_slots + 8 if self._stateful else None
-            self.pages = pages_lib.PagePool(
-                self.num_pages, page_size, pps, prefix_cache=prefix_cache,
-                state_rows=snapshot_rows,
-                state_row_bytes=pages_lib.state_bytes_per_slot(model))
-            self._page_tab = np.zeros((num_slots, pps), np.int32)
+        # the K/V storage (serve/pages.py): slot columns map to
+        # fixed-size pool pages through per-slot page tables, prefill
+        # writes straight into the request's pages, and shared prompt
+        # prefixes map the same read-only pages
+        from ..ops import attention as attn_lib
+        from ..ops.pallas import paged_attention as paged_kernel_lib
+        if page_size:
+            page_size = int(page_size)
+        else:
+            # prefer a kernel-tileable size whenever the kernel may
+            # dispatch; plain largest-divisor pick otherwise
+            page_size = pages_lib.auto_page_size(
+                max_len,
+                multiple_of=(1 if use_paged_kernel is False
+                             else paged_kernel_lib.MIN_PAGE_SIZE))
+        if page_size < 1 or max_len % page_size:
+            raise ValueError(
+                f"page_size must divide max_len {max_len} (the "
+                f"gathered page view must tile the stripe shape "
+                f"exactly); got {page_size}")
+        # fused-kernel gate: resolved ONCE here (the executables
+        # below close over the static answer — no retrace surface).
+        # An explicit use_paged_kernel=True with a non-tileable
+        # page_size is a configuration error, surfaced NOW as a
+        # ValueError instead of a Mosaic failure inside the kernel;
+        # "auto" falls back to the gather read path with a logged
+        # reason.
+        kernel_ok = paged_kernel_lib.page_size_kernel_ok(page_size)
+        if use_paged_kernel is True and not kernel_ok:
+            raise ValueError(
+                f"use_paged_kernel=True requires a lane-tileable "
+                f"page_size (a multiple of "
+                f"{paged_kernel_lib.MIN_PAGE_SIZE}, Mosaic's "
+                f"sublane tile); got page_size={page_size}. Pick a "
+                f"compatible page_size or leave use_paged_kernel="
+                f"'auto' to fall back to the gather read path.")
+        resolved = attn_lib.resolve_use_paged_kernel(
+            use_paged_kernel, max_len)
+        if resolved and not kernel_ok:
+            import warnings
+            warnings.warn(
+                f"paged-attention kernel disabled: page_size "
+                f"{page_size} is not a multiple of "
+                f"{paged_kernel_lib.MIN_PAGE_SIZE} (Mosaic lane "
+                f"tiling) — falling back to the XLA gather read "
+                f"path", RuntimeWarning, stacklevel=2)
+            resolved = False
+        self.use_paged_kernel = resolved
+        pps = max_len // page_size
+        if num_pages is None:
+            # default: max_len tokens for every slot plus the reserved
+            # trash page — shareable and pay-as-you-go (floor: one full
+            # slot plus a spare, the pool's own minimum)
+            num_pages = max(num_slots * pps + 1, pps + 2)
+        self.page_size = page_size
+        self.num_pages = int(num_pages)
+        # snapshot budget: a row for every slot's turn end and eight
+        # for shared prefixes (a system prompt's, a chain met without
+        # one), each the size of one slot's state: the deployment's
+        # configuration counts them with its bytes.  None where the
+        # model caches keys and values only
+        snapshot_rows = num_slots + 8 if self._stateful else None
+        self.pages = pages_lib.PagePool(
+            self.num_pages, page_size, pps, prefix_cache=prefix_cache,
+            state_rows=snapshot_rows,
+            state_row_bytes=pages_lib.state_bytes_per_slot(model))
+        self._page_tab = np.zeros((num_slots, pps), np.int32)
         # duck-typed admission policy (fleet.tenancy.TenantPolicy):
         # checked under the state lock so quota decisions are atomic
         # against concurrent submitters
@@ -540,37 +541,26 @@ class SlotScheduler:
         self._lock = threading.Lock()
         self._pump_lock = threading.Lock()
         # cross-thread cancel leaves device work to the pump: rows to
-        # freeze at the next tick, cancelled prefills whose caches the
-        # pump pools back
+        # freeze at the next tick, cancelled prefills whose leases the
+        # pump releases
         self._stale_rows: set = set()
-        self._orphans: List[list] = []
+        self._orphans: List[_Prefill] = []
         # admission queue: a deque by default; any object with append/
         # popleft/remove/__len__/__iter__ (e.g. fleet.tenancy's deficit-
         # weighted fair queue) plugs in — the scheduler only asks "next
         # admissible request", the policy decides whose turn it is
         self._queue = queue if queue is not None else collections.deque()
         self._slots: List[Optional[Request]] = [None] * num_slots
-        # in-flight prefills: [req, windows [n, 1, W], next index, cache
-        # or lease, window plan [(pos, real, snapshot depth)], the slot of a
-        # recurrent-state model (else None), this tick's window ran ahead]
-        self._prefills: List[list] = []
-        # spare batch-1 prefill caches, reused across requests (stale
-        # columns are masked by the slot validity window, never read)
-        self._pf_pool: List[dict] = []
+        self._prefills: List[_Prefill] = []
         # per-tenant in-flight accounting (the ONE bookkeeping source:
         # quotas, fair-share, gauges, and Engine.stats() all read it)
         self._tenant_inflight: Dict[str, int] = {}
         self._tenant_tokens: Dict[str, int] = {}
 
         # -- device state -------------------------------------------------
-        self._cache = (pages_lib.init_paged_cache(
-                           model, num_slots, self.num_pages,
-                           self.page_size)
-                       if self.paged
-                       else slots_lib.init_slot_cache(model, num_slots,
-                                                      max_len))
-        self._kv_pool_bytes = (pages_lib.kv_pool_bytes(self._cache["kv"])
-                               if self.paged else (0, 0))
+        self._cache = pages_lib.init_paged_cache(
+            model, num_slots, self.num_pages, self.page_size)
+        self._kv_pool_bytes = pages_lib.kv_pool_bytes(self._cache["kv"])
         # state snapshots: the slot state's layout, one row a snapshot
         # (empty dict for a K/V-only model)
         self._snaps = (pages_lib.init_state_snapshots(
@@ -591,10 +581,9 @@ class SlotScheduler:
         pad = self.pad_id if self.pad_id is not None else 0
 
         def sample_step(carry_step, step_fn):
-            """Shared tick-step body: one decode dispatch via
-            ``step_fn``, in-graph sampling, EOS/budget freeze — ONE
-            implementation for the contiguous and paged ticks so their
-            retirement semantics can never diverge."""
+            """The tick's step body: one decode dispatch via
+            ``step_fn``, in-graph sampling, EOS/budget freeze — the
+            retirement semantics of generate()."""
             cache, tokens, finished, remaining, key = carry_step
             live = ~finished
             logits, cache = step_fn(cache, tokens, live)
@@ -613,7 +602,7 @@ class SlotScheduler:
 
         def first_token(logits, last_idx, key, tokens, finished,
                         remaining, slot_idx, budget):
-            """Shared last-window tail: sample the first token from the
+            """The last window's tail: sample the first token from the
             prompt's final-position logits and arm the slot's
             tokens/finished/remaining rows."""
             row = jax.lax.dynamic_index_in_dim(logits[0], last_idx,
@@ -667,7 +656,7 @@ class SlotScheduler:
                              slot_idx, length, budget, ad, ad_row,
                              valid=None):
             """Last prefill window + first-token sample + slot arm in
-            ONE dispatch.  No splice: the prompt's K/V already live in
+            ONE dispatch.  The prompt's K/V already live in
             the request's pages — admission just points the slot's
             column state at them (the page-table row is host state,
             handed to the next tick)."""
@@ -725,43 +714,6 @@ class SlotScheduler:
                 length=tick_steps)
             return carry, em, mask
 
-        def win_mid(params, cache, window, ad, ad_row):
-            return model.decode_window(params, cache, window,
-                                       head="none", adapters=ad,
-                                       adapter_rows=ad_row)[1]
-
-        def last_admit(params, pf_cache, window, last_idx, key,
-                       cache, tokens, finished, remaining,
-                       slot_idx, length, budget, ad, ad_row):
-            """Last prefill window + first-token sample + slot splice in
-            ONE dispatch.  ``pf_cache`` is NOT donated: the pool entry
-            stays host-valid for the next request (its columns become
-            stale, which the slot validity window masks)."""
-            logits, pf_cache = model.decode_window(params, pf_cache,
-                                                   window, head="all",
-                                                   adapters=ad,
-                                                   adapter_rows=ad_row)
-            tok, key, tokens, finished, remaining = first_token(
-                logits, last_idx, key, tokens, finished, remaining,
-                slot_idx, budget)
-            cache = slots_lib.insert_slot(
-                cache, slot_idx, slots_lib.strip_pos(pf_cache), length)
-            return tok, cache, tokens, finished, remaining, key
-
-        def tick(params, cache, tokens, finished, remaining, key,
-                 ad, ad_rows):
-            def one(carry, _):
-                return sample_step(
-                    carry,
-                    lambda cache, toks, live: slots_lib.decode_slots_step(
-                        model, params, cache, toks, live,
-                        adapters=ad, adapter_rows=ad_rows))
-
-            carry, (em, mask) = jax.lax.scan(
-                one, (cache, tokens, finished, remaining, key), None,
-                length=tick_steps)
-            return carry, em, mask
-
         def wire_gather(kv, idx):
             # page-wire device read (fleet/pagewire.py): gather the
             # pages at ``idx`` (padded to pages_per_slot — ONE shape,
@@ -779,26 +731,17 @@ class SlotScheduler:
             return {k: v.at[:, page].set(payload[k])
                     for k, v in kv.items()}
 
-        if self.paged:
-            self._win_mid = jax.jit(paged_win_mid, donate_argnums=(1,))
-            self._last_admit = jax.jit(paged_last_admit,
-                                       donate_argnums=(1, 6, 7, 8, 9))
-            self._tick = jax.jit(paged_tick,
-                                 donate_argnums=(1, 3, 4, 5, 6))
-            self._wire_gather = jax.jit(wire_gather)
-            self._wire_splice = jax.jit(wire_splice,
-                                        donate_argnums=(0,))
-            # recurrent-state models: two more pinned programs, both row
-            # copies dispatched in the tick's stream (census: 3 + 2)
-            self._state_snapshot = jax.jit(state_snapshot,
-                                           donate_argnums=(0, 1))
-            self._state_restore = jax.jit(state_restore,
-                                          donate_argnums=(0,))
-        else:
-            self._win_mid = jax.jit(win_mid, donate_argnums=(1,))
-            self._last_admit = jax.jit(last_admit,
-                                       donate_argnums=(4, 5, 6, 7, 8))
-            self._tick = jax.jit(tick, donate_argnums=(1, 2, 3, 4, 5))
+        self._win_mid = jax.jit(paged_win_mid, donate_argnums=(1,))
+        self._last_admit = jax.jit(paged_last_admit,
+                                   donate_argnums=(1, 6, 7, 8, 9))
+        self._tick = jax.jit(paged_tick, donate_argnums=(1, 3, 4, 5, 6))
+        self._wire_gather = jax.jit(wire_gather)
+        self._wire_splice = jax.jit(wire_splice, donate_argnums=(0,))
+        # recurrent-state models: two more pinned programs, both row
+        # copies dispatched in the tick's stream (census: 3 + 2)
+        self._state_snapshot = jax.jit(state_snapshot,
+                                       donate_argnums=(0, 1))
+        self._state_restore = jax.jit(state_restore, donate_argnums=(0,))
 
     # ------------------------------------------------ graph-tier targets
 
@@ -829,55 +772,39 @@ class SlotScheduler:
         row1 = (jax.ShapeDtypeStruct((1,), np.int32)
                 if ad_rows is not None else None)
         rows = sds(ad_rows) if ad_rows is not None else None
-        if self.paged:
-            pps = self.max_len // self.page_size
-            prow = jax.ShapeDtypeStruct((pps,), np.int32)
-            tab = jax.ShapeDtypeStruct((self.num_slots, pps), np.int32)
-            st = (i32,) if self._stateful else ()
-            targets = [
-                graph_lib.Target(
-                    "prefill_window", self._win_mid,
-                    (params, cache, win, prow, i32, ad, row1) + st + st,
-                    hbm_budget=hbm_budget),
-                graph_lib.Target(
-                    "admit", self._last_admit,
-                    (params, cache, win, prow, i32, i32, key, toks,
-                     fin, rem, i32, i32, i32, ad, row1) + st,
-                    hbm_budget=hbm_budget),
-                graph_lib.Target(
-                    "decode_tick", self._tick,
-                    (params, cache, tab, toks, fin, rem, key, ad, rows),
-                    hbm_budget=hbm_budget),
-            ]
-            if self._stateful:
-                # the snapshot copies are programs of their own (a turn's
-                # end is known only after the tick's fetch, so the copy
-                # cannot ride inside the tick); listed here so that they
-                # are warmed, analysed and censused with the three
-                copy = (cache, snaps,
-                        jax.ShapeDtypeStruct((4,), np.int32))
-                targets += [
-                    graph_lib.Target("state_snapshot",
-                                     self._state_snapshot, copy,
-                                     hbm_budget=hbm_budget),
-                    graph_lib.Target("state_restore", self._state_restore,
-                                     copy, hbm_budget=hbm_budget)]
-            return targets
-        pf = sds(jax.eval_shape(
-            lambda: self.model.init_cache(1, self.max_len)))
-        return [
+        pps = self.max_len // self.page_size
+        prow = jax.ShapeDtypeStruct((pps,), np.int32)
+        tab = jax.ShapeDtypeStruct((self.num_slots, pps), np.int32)
+        st = (i32,) if self._stateful else ()
+        targets = [
             graph_lib.Target(
                 "prefill_window", self._win_mid,
-                (params, pf, win, ad, row1), hbm_budget=hbm_budget),
+                (params, cache, win, prow, i32, ad, row1) + st + st,
+                hbm_budget=hbm_budget),
             graph_lib.Target(
                 "admit", self._last_admit,
-                (params, pf, win, i32, key, cache, toks, fin, rem,
-                 i32, i32, i32, ad, row1), hbm_budget=hbm_budget),
+                (params, cache, win, prow, i32, i32, key, toks,
+                 fin, rem, i32, i32, i32, ad, row1) + st,
+                hbm_budget=hbm_budget),
             graph_lib.Target(
                 "decode_tick", self._tick,
-                (params, cache, toks, fin, rem, key, ad, rows),
+                (params, cache, tab, toks, fin, rem, key, ad, rows),
                 hbm_budget=hbm_budget),
         ]
+        if self._stateful:
+            # the snapshot copies are programs of their own (a turn's
+            # end is known only after the tick's fetch, so the copy
+            # cannot ride inside the tick); listed here so that they
+            # are warmed, analysed and censused with the three
+            copy = (cache, snaps,
+                    jax.ShapeDtypeStruct((4,), np.int32))
+            targets += [
+                graph_lib.Target("state_snapshot",
+                                 self._state_snapshot, copy,
+                                 hbm_budget=hbm_budget),
+                graph_lib.Target("state_restore", self._state_restore,
+                                 copy, hbm_budget=hbm_budget)]
+        return targets
 
     # ------------------------------------------------------------- intake
 
@@ -1009,27 +936,26 @@ class SlotScheduler:
                 decode_steps_total=self._decode_steps,
                 admit_backpressure_total=self._admit_backpressure)
             skipped = self._windows_skipped
-        if self.pages is not None:
-            p = self.pages.stats()
-            base.update(
-                pages_total=p["pages_total"],
-                pages_free=p["pages_free"],
-                pages_per_request=p["pages_per_request"],
-                prefix_lookups_total=p["prefix_lookups_total"],
-                prefix_hits_total=p["prefix_hits_total"],
-                prefix_tokens_reused_total=p["prefix_tokens_reused_total"],
-                prefix_evictions_total=p["prefix_evictions_total"],
-                cow_splits_total=p["cow_splits_total"],
-                state_snapshots_total=p["state_snapshots_total"],
-                state_restores_total=p["state_restores_total"],
-                state_snapshots_evicted_total=p[
-                    "state_snapshots_evicted_total"],
-                state_snapshot_bytes=p["state_snapshot_bytes"],
-                kv_pool_bytes=self._kv_pool_bytes[0],
-                kv_pool_tiled_bytes=self._kv_pool_bytes[1],
-                prefill_windows_skipped_total=skipped,
-                page_size=p["page_size"],
-                prefix_fingerprint=p["prefix_fingerprint"])
+        p = self.pages.stats()
+        base.update(
+            pages_total=p["pages_total"],
+            pages_free=p["pages_free"],
+            pages_per_request=p["pages_per_request"],
+            prefix_lookups_total=p["prefix_lookups_total"],
+            prefix_hits_total=p["prefix_hits_total"],
+            prefix_tokens_reused_total=p["prefix_tokens_reused_total"],
+            prefix_evictions_total=p["prefix_evictions_total"],
+            cow_splits_total=p["cow_splits_total"],
+            state_snapshots_total=p["state_snapshots_total"],
+            state_restores_total=p["state_restores_total"],
+            state_snapshots_evicted_total=p[
+                "state_snapshots_evicted_total"],
+            state_snapshot_bytes=p["state_snapshot_bytes"],
+            kv_pool_bytes=self._kv_pool_bytes[0],
+            kv_pool_tiled_bytes=self._kv_pool_bytes[1],
+            prefill_windows_skipped_total=skipped,
+            page_size=p["page_size"],
+            prefix_fingerprint=p["prefix_fingerprint"])
         return EngineStats(**base)
 
     def tenant_inflight(self, tenant: str) -> int:
@@ -1120,26 +1046,26 @@ class SlotScheduler:
             if req.phases is not None:
                 req.phases["prefill_compute"] += dt
 
-        def window_of(st: list) -> int:
+        def window_of(st: _Prefill) -> int:
             with trace_lib.timed("serve.prefill",
-                                 trace_id=st[0].trace_id) as window:
+                                 trace_id=st.req.trace_id) as window:
                 n = self._advance_prefill(st, firsts)
-            charge(st[0], window.duration_s)
+            charge(st.req, window.duration_s)
             return n
 
         # The tick keeps the device's queue from running empty: admitting
         # windows go last of the windows, their tokens are read only after
         # everything of the tick is dispatched, and the mid windows the NEXT
         # tick would open with are dispatched behind the decode program
-        # (``st[6]``), so deliveries, admissions and the caller's own work
+        # (``ran_ahead``), so deliveries, admissions and the caller's own work
         # between ticks run beside a busy device.  A request still gets one
         # window a tick, in the same place of the device's stream.
         firsts: List[tuple] = []     # admitting windows, tokens unread
-        pending.sort(key=lambda st: st[2] == len(st[1]) - 1)
+        pending.sort(key=lambda st: st.next == len(st.windows) - 1)
         for st in pending:
             did = True
-            if st[6]:
-                st[6] = False        # ran ahead, behind the last decode
+            if st.ran_ahead:
+                st.ran_ahead = False     # behind the last decode
                 continue
             windows += window_of(st)
         with self._lock:
@@ -1150,15 +1076,15 @@ class SlotScheduler:
             decoded = self._decode_dispatch(active)
             with self._lock:
                 ahead = [st for st in self._prefills
-                         if st[2] < len(st[1]) - 1]
+                         if st.next < len(st.windows) - 1]
             for st in ahead:
                 windows += window_of(st)
-                st[6] = True
+                st.ran_ahead = True
         for st, tok, slot in firsts:
             with trace_lib.timed("serve.prefill",
-                                 trace_id=st[0].trace_id) as read:
+                                 trace_id=st.req.trace_id) as read:
                 self._first_token(st, tok, slot, outbox)
-            charge(st[0], read.duration_s)
+            charge(st.req, read.duration_s)
         if decoded is not None:
             decoded = self._decode_fetch(*decoded)
         with trace_lib.span("serve.deliver") as deliver:
@@ -1184,11 +1110,10 @@ class SlotScheduler:
                              trace_id=req.trace_id) as admit:
             try:
                 st = self._begin_prefill(req)
-                admit.set(outcome="ok", skipped_tokens=int(
-                    st[3].skip if self.paged else 0))
+                admit.set(outcome="ok", skipped_tokens=int(st.lease.skip))
                 if self._stateful:
                     # it holds a ``serve.state_restore`` span iff True
-                    admit.set(resumed=st[3].restore is not None)
+                    admit.set(resumed=st.lease.restore is not None)
             except (AdapterTableFull, pages_lib.PagePoolExhausted):
                 st = None
                 admit.set(outcome="backpressure")
@@ -1213,44 +1138,28 @@ class SlotScheduler:
         return True
 
     def _harvest_orphans(self) -> None:
-        """Recycle the prefill storage of requests cancelled
-        cross-thread (only the pump owns recycling — a cancel
-        mid-window must not hand a buffer back while a dispatch is
-        still writing it).  Contiguous mode pools the [1, max_len]
-        cache; paged mode releases the lease (idempotent — the
-        cancelling thread's abort usually got there first)."""
+        """Release the leases of prefills cancelled cross-thread (only
+        the pump owns recycling — a cancel mid-window must not hand
+        pages back while a dispatch is still writing them).  Idempotent:
+        the cancelling thread's abort usually got there first."""
         with self._lock:
             orphans, self._orphans = self._orphans, []
-            if not self.paged:
-                for st in orphans:
-                    self._pool_prefill_cache(st[3])
-        if self.paged:
-            for st in orphans:
-                self.pages.release(st[3])
-
-    def _pool_prefill_cache(self, cache) -> None:
-        """Return a batch-1 prefill cache to the spare pool (caller
-        holds the state lock) — BOUNDED at ``num_slots`` entries:
-        concurrent prefills can never exceed the free-slot count, so
-        anything past that is a cancel/expiry storm's dead weight, not
-        a future saving."""
-        if len(self._pf_pool) < self.num_slots:
-            self._pf_pool.append(slots_lib.strip_pos(cache))
+        for st in orphans:
+            self.pages.release(st.lease)
 
     def _freeze_stale_rows(self) -> None:
         """Freeze device rows cancelled cross-thread since the last
-        tick.  Runs BEFORE admissions so a newcomer spliced into the
+        tick.  Runs BEFORE admissions so a newcomer armed in the
         freed slot this tick is never frozen by the departed request's
         leftover mark (reservation also discards its slot from the
-        set — the splice overwrites the whole row anyway).  Paged mode
-        also remaps the row's page table to the trash page, so its
-        frozen writes can never land in a reallocated page."""
+        set — admission rewrites the whole row anyway).  The row's page
+        table is remapped to the trash page, so its frozen writes can
+        never land in a reallocated page."""
         with self._lock:
             stale = sorted(self._stale_rows)
             self._stale_rows.clear()
-            if self._page_tab is not None:
-                for r in stale:
-                    self._page_tab[r] = 0
+            for r in stale:
+                self._page_tab[r] = 0
         if stale:
             self._finished = self._finished.at[np.asarray(stale)].set(
                 True)
@@ -1281,7 +1190,7 @@ class SlotScheduler:
 
     # ---------------------------------------------------------- prefill
 
-    def _begin_prefill(self, req: Request) -> list:
+    def _begin_prefill(self, req: Request) -> _Prefill:
         w = self.prefill_chunk
         # prefill runs over the request's CONTEXT — prompt + any tokens
         # already generated on a source engine (import_snapshot); a
@@ -1294,68 +1203,55 @@ class SlotScheduler:
             # with nothing to unwind
             req.adapter_row = self.adapters.acquire(req.adapter_id)
         try:
-            if self.paged:
-                slot = self._reserve_state_row() if self._stateful else None
-                # page lease: map any cached prefix chain read-only and
-                # allocate private pages for the rest of the request's
-                # whole footprint (context + remaining decode budget —
-                # upfront, so a mid-decode tick can never starve)
-                lease = self.pages.begin(
-                    ctx, plen + req.remaining_budget - 1)
-                req._lease = lease
-                if lease.restore is not None:
-                    # the hit's state, and its partial page, into the
-                    # slot's row and the request's own page: a device copy
-                    # ahead of the first window in the same stream
-                    row, src, dst = lease.restore
-                    with trace_lib.span(
-                            "serve.state_restore", trace_id=req.trace_id,
-                            slot=int(slot), depth=int(lease.skip),
-                            bytes=self.pages.state_row_bytes):
-                        self._cache = self._state_restore(
-                            self._cache, self._snaps,
-                            np.asarray([slot, row, src, dst], np.int32))
-                # the windows: W tokens each from ``skip`` on, cut where
-                # the pool asked for a snapshot (``snap_at``: the prompt
-                # met a chain there), each stretch's last one padded
-                cuts = [c for c in (lease.snap_at,)
-                        if lease.skip < c < plen] + [plen]
-                plan, rows, start = [], [], lease.skip
-                for cut in cuts:
-                    for pos in range(start, cut, w):
-                        real = min(w, cut - pos)
-                        row_ = np.zeros((w,), np.int32)
-                        row_[:real] = ctx[pos:pos + real]
-                        rows.append(row_)
-                        plan.append((pos, real,
-                                     cut if pos + real == cut < plen
-                                     else 0))
-                    start = cut
-                n_win = len(plan)
-                with self._lock:
-                    # window dispatches avoided by the prefix hit — the
-                    # measured TTFT/FLOPs saving, reported via stats()
-                    self._windows_skipped += max(0, -(-plen // w) - n_win)
-                return [req, np.stack(rows).reshape(n_win, 1, w), 0, lease,
-                        plan, slot, False]
-            n_win = -(-plen // w)
-            padded = np.zeros((n_win * w,), np.int32)
-            padded[:plen] = ctx
-            windows = padded.reshape(n_win, 1, w)
+            slot = self._reserve_state_row() if self._stateful else None
+            # page lease: map any cached prefix chain read-only and
+            # allocate private pages for the rest of the request's
+            # whole footprint (context + remaining decode budget —
+            # upfront, so a mid-decode tick can never starve)
+            lease = self.pages.begin(
+                ctx, plen + req.remaining_budget - 1)
+            req._lease = lease
+            if lease.restore is not None:
+                # the hit's state, and its partial page, into the
+                # slot's row and the request's own page: a device copy
+                # ahead of the first window in the same stream
+                row, src, dst = lease.restore
+                with trace_lib.span(
+                        "serve.state_restore", trace_id=req.trace_id,
+                        slot=int(slot), depth=int(lease.skip),
+                        bytes=self.pages.state_row_bytes):
+                    self._cache = self._state_restore(
+                        self._cache, self._snaps,
+                        np.asarray([slot, row, src, dst], np.int32))
+            # the windows: W tokens each from ``skip`` on, cut where
+            # the pool asked for a snapshot (``snap_at``: the prompt
+            # met a chain there), each stretch's last one padded
+            cuts = [c for c in (lease.snap_at,)
+                    if lease.skip < c < plen] + [plen]
+            plan, rows, start = [], [], lease.skip
+            for cut in cuts:
+                for pos in range(start, cut, w):
+                    real = min(w, cut - pos)
+                    row_ = np.zeros((w,), np.int32)
+                    row_[:real] = ctx[pos:pos + real]
+                    rows.append(row_)
+                    plan.append((pos, real,
+                                 cut if pos + real == cut < plen
+                                 else 0))
+                start = cut
+            n_win = len(plan)
             with self._lock:
-                kv = self._pf_pool.pop() if self._pf_pool else None
-            if kv is None:
-                kv = slots_lib.strip_pos(self.model.init_cache(
-                    1, self.max_len))
-            return [req, windows, 0, dict(kv, pos=np.int32(0)), None, None,
-                    False]
+                # window dispatches avoided by the prefix hit — the
+                # measured TTFT/FLOPs saving, reported via stats()
+                self._windows_skipped += max(0, -(-plen // w) - n_win)
+            return _Prefill(req, np.stack(rows).reshape(n_win, 1, w), 0,
+                            lease, plan, slot)
         except BaseException:
             # admission failed after the pin: pool exhaustion is the
             # common case, but begin() also raises ValueError for a
-            # footprint over pages_per_slot and init_cache can fail
-            # under fault injection — every path must unwind the lease
-            # and the pin so a requeued (or propagating) request holds
-            # nothing
+            # footprint over pages_per_slot — every path must unwind the
+            # lease and the pin so a requeued (or propagating) request
+            # holds nothing
             if req._lease is not None:
                 self.pages.release(req._lease)
                 req._lease = None
@@ -1370,7 +1266,7 @@ class SlotScheduler:
         row cancelled cross-thread decodes on until the next tick's
         housekeeping freezes it).  None to be had is backpressure."""
         with self._lock:
-            taken = {st[5] for st in self._prefills} | self._stale_rows
+            taken = {st.slot for st in self._prefills} | self._stale_rows
             for r, holder in enumerate(self._slots):
                 if holder is None and r not in taken:
                     return r
@@ -1403,71 +1299,59 @@ class SlotScheduler:
                                                     np.int32)
         return self.adapters.arrays, self._adapter_rows
 
-    def _advance_prefill(self, st: list, firsts: List[tuple]) -> int:
+    def _advance_prefill(self, st: _Prefill, firsts: List[tuple]) -> int:
         """One window for one in-flight prefill; admits the request into
         its slot on the last window, whose token stays on the device:
         ``firsts`` gains ``(st, token, slot)`` for ``_first_token`` to read
         once the tick's other work is dispatched.  Pump-only.  Returns
         the windows dispatched (0 for a request cancelled cross-thread).
 
-        Paged mode prefills straight into the request's leased pages
+        The windows go straight into the request's leased pages
         (``decode_window_paged`` at ``pos = skip + i*W`` — a prefix hit
         starts past the shared pages, whose windows are simply never
         dispatched), so admission is column-state arming plus a host
-        page-table write, not a cache splice; the request's full prompt
-        pages are published to the radix cache right after."""
-        req, windows, i, payload, plan, reserved, _ = st
+        page-table write; the request's full prompt pages are published
+        to the radix cache right after."""
+        req, lease, i = st.req, st.lease, st.next
         with self._lock:
             if st not in self._prefills:
                 return 0     # cancelled cross-thread: harvest recycles it
         ad, ad_row = self._adapter_args(req)
-        last = i == len(windows) - 1
-        if self.paged:
-            pos, real, snap_depth = plan[i]
-            # a recurrent-state model's windows also name the slot whose
-            # state they advance and how many of their tokens are real
-            state_args = ((np.int32(reserved), np.int32(real))
-                          if self._stateful else ())
+        last = i == len(st.windows) - 1
+        pos, real, snap_depth = st.plan[i]
+        # a recurrent-state model's windows also name the slot whose
+        # state they advance and how many of their tokens are real
+        state_args = ((np.int32(st.slot), np.int32(real))
+                      if self._stateful else ())
+        ctx = req.context if req.context is not None else req.prompt
         if not last:
             with trace_lib.span("serve.prefill_dispatch",
                                 trace_id=req.trace_id, window=int(i),
                                 last=False):
-                if self.paged:
-                    self._cache = self._win_mid(
-                        self.params, self._cache, windows[i], payload.row,
-                        np.int32(pos), ad, ad_row, *state_args)
-                else:
-                    new_cache = self._win_mid(self.params, payload,
-                                              windows[i], ad, ad_row)
+                self._cache = self._win_mid(
+                    self.params, self._cache, st.windows[i], lease.row,
+                    np.int32(pos), ad, ad_row, *state_args)
             req._windows += 1
             with self._lock:
-                if not self.paged:
-                    st[3] = new_cache
-                st[2] = i + 1
+                st.next = i + 1
                 self._prefill_windows += 1
             if req.trace_id:
                 reqtrace.mark(req.trace_id, "prefill_window",
                               window=int(i))
-            if self.paged and snap_depth:
-                ctx = req.context if req.context is not None else req.prompt
-                self._snapshot_state(req, payload, ctx[:snap_depth],
-                                     reserved, "chain_met")
+            if snap_depth:
+                self._snapshot_state(req, lease, ctx[:snap_depth],
+                                     st.slot, "chain_met")
             return 1
-        ctx = req.context if req.context is not None else req.prompt
-        plen = ctx.size
-        last_idx = np.int32(real - 1 if self.paged else
-                            plen - 1 - (len(windows) - 1)
-                            * self.prefill_chunk)
         with self._lock:
             if st not in self._prefills or req.done.is_set():
                 return 0
             self._prefills.remove(st)
-            slot = (reserved if reserved is not None
+            slot = (st.slot if st.slot is not None
                     else self._slots.index(None))
-            # reserve before the splice so the free-slot count stays
-            # consistent for concurrent admissions and stats(); the
-            # splice overwrites the row, so a leftover freeze mark from
-            # the slot's previous (cancelled) occupant must not fire
+            # reserve before the dispatch so the free-slot count stays
+            # consistent for concurrent admissions and stats(); admission
+            # rewrites the row, so a leftover freeze mark from the slot's
+            # previous (cancelled) occupant must not fire
             self._slots[slot] = req
             self._stale_rows.discard(slot)
         if self._adapter_rows is not None:
@@ -1475,38 +1359,28 @@ class SlotScheduler:
         with trace_lib.span("serve.prefill_dispatch",
                             trace_id=req.trace_id, window=int(i),
                             last=True):
-            if self.paged:
-                tok, self._cache, self._tokens, self._finished, \
-                    self._remaining, self._key = self._last_admit(
-                        self.params, self._cache, windows[-1],
-                        payload.row, np.int32(pos),
-                        last_idx, self._key, self._tokens,
-                        self._finished, self._remaining, np.int32(slot),
-                        np.int32(plen), np.int32(req.remaining_budget),
-                        ad, ad_row, *state_args[1:])
-            else:
-                tok, self._cache, self._tokens, self._finished, \
-                    self._remaining, self._key = self._last_admit(
-                        self.params, payload, windows[-1], last_idx,
-                        self._key, self._cache, self._tokens,
-                        self._finished, self._remaining, np.int32(slot),
-                        np.int32(plen), np.int32(req.remaining_budget),
-                        ad, ad_row)
+            tok, self._cache, self._tokens, self._finished, \
+                self._remaining, self._key = self._last_admit(
+                    self.params, self._cache, st.windows[-1], lease.row,
+                    np.int32(pos), np.int32(real - 1), self._key,
+                    self._tokens, self._finished, self._remaining,
+                    np.int32(slot), np.int32(ctx.size),
+                    np.int32(req.remaining_budget), ad, ad_row,
+                    *state_args[1:])
         if self._stateful:
             # publish the context's pages and snapshot the state after its
             # last token now, behind the admitting window in the device's
             # stream
             with trace_lib.span("serve.register"):
-                self.pages.register(payload, ctx)
-                self._snapshot_state(req, payload, ctx, slot, "prompt_end")
-        if self.paged:
-            with self._lock:
-                # before the tick's decode dispatch copies the table
-                self._page_tab[slot] = payload.row
+                self.pages.register(lease, ctx)
+                self._snapshot_state(req, lease, ctx, slot, "prompt_end")
+        with self._lock:
+            # before the tick's decode dispatch copies the table
+            self._page_tab[slot] = lease.row
         firsts.append((st, tok, slot))
         return 1
 
-    def _first_token(self, st: list, tok, slot: int,
+    def _first_token(self, st: _Prefill, tok, slot: int,
                      outbox: List[tuple]) -> None:
         """Read an admitting window's token off the device and finish the
         admission on the host; delivery is queued on ``outbox`` (flushed at
@@ -1514,7 +1388,7 @@ class SlotScheduler:
         windows are queued behind the window by now, so the read waits
         beside a busy device: it is no barrier, and its span is not named
         as the fetches that are (``serve.decode_fetch``)."""
-        req, windows, payload = st[0], st[1], st[3]
+        req = st.req
         ctx = req.context if req.context is not None else req.prompt
         with trace_lib.span("serve.first_token_read",
                             trace_id=req.trace_id):
@@ -1522,31 +1396,26 @@ class SlotScheduler:
         req.first_token_time = time.perf_counter()
         req._windows += 1
         with trace_lib.span("serve.register"):
-            if self.paged and not self._stateful:
+            if not self._stateful:
                 # the context's full pages are final now — publish them
                 # so the NEXT request with this prefix skips their
                 # windows
-                self.pages.register(payload, ctx)
+                self.pages.register(st.lease, ctx)
             with self._lock:
                 self._prefill_windows += 1
-                if not self.paged:
-                    # the pool entry was not donated — reusable for the
-                    # next request
-                    self._pool_prefill_cache(payload)
                 cancelled = req.done.is_set()
                 if cancelled and self._slots[slot] is req:
                     self._slots[slot] = None
-                    if self._page_tab is not None:
-                        self._page_tab[slot] = 0
+                    self._page_tab[slot] = 0
         if cancelled:
-            # cancel() raced the splice: retire the freshly spliced row
+            # cancel() raced the admission: retire the freshly armed row
             # (frozen rows never perturb the others) and deliver nothing
             self._finished = self._finished.at[slot].set(True)
             return
         self.metrics.admitted(req)
         if req.trace_id:
             reqtrace.mark(req.trace_id, "prefill_window",
-                          window=len(windows) - 1)
+                          window=len(st.windows) - 1)
             reqtrace.mark(req.trace_id, "admitted", slot=int(slot))
             reqtrace.mark(req.trace_id, "first_token",
                           ttft_s=req.first_token_time - req.submit_time)
@@ -1557,8 +1426,8 @@ class SlotScheduler:
         if req.remaining_budget <= 1 or (self.eos_id is not None
                                          and first == self.eos_id):
             self._drop_slot(slot, req)
-            # spliced but already finished in-graph: the slot stays free
-            # host-side and the splice is dead weight
+            # armed but already finished in-graph: the slot stays free
+            # host-side
             outbox.append(("deliver", req, [first], None))
             outbox.append(("finish", req))
         else:
@@ -1567,14 +1436,13 @@ class SlotScheduler:
     # ----------------------------------------------------------- decode
 
     def _drop_slot(self, r: int, req: Request) -> None:
-        """Free slot ``r`` if ``req`` still holds it; paged mode also
-        remaps the row's page table to the trash page so the frozen
-        row's future writes can never touch a reallocated page."""
+        """Free slot ``r`` if ``req`` still holds it, and remap the
+        row's page table to the trash page so the frozen row's future
+        writes can never touch a reallocated page."""
         with self._lock:
             if self._slots[r] is req:
                 self._slots[r] = None
-                if self._page_tab is not None:
-                    self._page_tab[r] = 0
+                self._page_tab[r] = 0
 
     def _decode_dispatch(self, active: int) -> tuple:
         """One K-step decode dispatch over the slots; what ``_decode_fetch``
@@ -1585,8 +1453,7 @@ class SlotScheduler:
             # page-table snapshot for this dispatch: host mutations
             # (admissions, retirements) between ticks never tear a
             # dispatch mid-read
-            tab = (self._page_tab.copy() if self._page_tab is not None
-                   else None)
+            tab = self._page_tab.copy()
         ad, ad_rows = self._adapter_args()
         with trace_lib.timed("serve.decode_dispatch",
                              steps=self.tick_steps,
@@ -1596,18 +1463,10 @@ class SlotScheduler:
                 # dispatch starts: what a byte count of the step is made of
                 dispatch.set(cached_tokens=sum(
                     _consumed(r) for r in slots if r is not None))
-            if self.paged:
-                (self._cache, self._tokens, self._finished,
-                 self._remaining, self._key), em, mask = self._tick(
-                    self.params, self._cache, tab, self._tokens,
-                    self._finished, self._remaining, self._key, ad,
-                    ad_rows)
-            else:
-                (self._cache, self._tokens, self._finished,
-                 self._remaining, self._key), em, mask = self._tick(
-                    self.params, self._cache, self._tokens,
-                    self._finished, self._remaining, self._key, ad,
-                    ad_rows)
+            (self._cache, self._tokens, self._finished,
+             self._remaining, self._key), em, mask = self._tick(
+                self.params, self._cache, tab, self._tokens,
+                self._finished, self._remaining, self._key, ad, ad_rows)
         return slots, em, mask, self._finished, dispatch.duration_s
 
     def _decode_fetch(self, slots, em, mask, finished,
@@ -1705,8 +1564,9 @@ class SlotScheduler:
 
     def _expire_deadlines(self) -> None:
         """Retire every request past its deadline, wherever it is —
-        queued (never admitted), mid-prefill (cache back to the pool),
-        or active (row frozen).  Runs once per tick, on the pump."""
+        queued (never admitted), mid-prefill (the lease comes back via
+        the abort's retirement accounting), or active (row frozen).  Runs
+        once per tick, on the pump."""
         now = time.perf_counter()
 
         def expired(req):
@@ -1721,20 +1581,15 @@ class SlotScheduler:
                 aborts.append(req)
             still = []
             for st in self._prefills:
-                if expired(st[0]):
-                    if not self.paged:
-                        # paged: the lease comes back via the abort's
-                        # retirement accounting, not a cache pool
-                        self._pool_prefill_cache(st[3])
-                    aborts.append(st[0])
+                if expired(st.req):
+                    aborts.append(st.req)
                 else:
                     still.append(st)
             self._prefills = still
             for r, req in enumerate(self._slots):
                 if expired(req):
                     self._slots[r] = None
-                    if self._page_tab is not None:
-                        self._page_tab[r] = 0
+                    self._page_tab[r] = 0
                     rows.append(r)
                     aborts.append(req)
         if rows:
@@ -1761,15 +1616,15 @@ class SlotScheduler:
         Thread-safe against a concurrently running tick: device work is
         left to the pump — an active row lands in ``_stale_rows`` (the
         pump freezes it next tick), a mid-window prefill moves to the
-        orphan list (the pump pools its cache when no dispatch can
-        still be writing it)."""
+        orphan list (the pump releases its lease when no dispatch can
+        still be writing its pages)."""
         if req.done.is_set():
             return False
         with self._lock:
             if req in self._queue:
                 self._queue.remove(req)
             for st in list(self._prefills):
-                if st[0] is req:
+                if st.req is req:
                     self._prefills.remove(st)
                     self._orphans.append(st)
             for r, other in enumerate(self._slots):
@@ -1794,8 +1649,8 @@ class SlotScheduler:
                 if req.rid == rid:
                     return req
             for st in self._prefills:
-                if st[0].rid == rid:
-                    return st[0]
+                if st.req.rid == rid:
+                    return st.req
             for req in self._slots:
                 if req is not None and req.rid == rid:
                     return req
@@ -1807,7 +1662,7 @@ class SlotScheduler:
         a wedged replica so it can forensic-dump each victim."""
         with self._lock:
             reqs = ([r for r in self._queue]
-                    + [st[0] for st in self._prefills]
+                    + [st.req for st in self._prefills]
                     + [r for r in self._slots if r is not None])
         return [r.trace_id for r in reqs if r.trace_id]
 
@@ -1821,7 +1676,7 @@ class SlotScheduler:
         lock; the finalize arithmetic runs outside it."""
         with self._lock:
             reqs = ([r for r in self._queue]
-                    + [st[0] for st in self._prefills]
+                    + [st.req for st in self._prefills]
                     + [r for r in self._slots if r is not None])
         now = time.perf_counter()
         out: Dict[str, dict] = {}
@@ -1882,7 +1737,7 @@ class SlotScheduler:
         try:
             with self._lock:
                 reqs = ([r for r in self._queue]
-                        + [st[0] for st in self._prefills]
+                        + [st.req for st in self._prefills]
                         + [r for r in self._slots if r is not None])
             snaps = []
             for req in sorted(reqs, key=lambda r: r.rid):
@@ -1904,7 +1759,7 @@ class SlotScheduler:
         ctx = req.context if req.context is not None else req.prompt
         with self._lock:
             prefill = next((st for st in self._prefills
-                            if st[0] is req), None)
+                            if st.req is req), None)
             row = next((r for r, other in enumerate(self._slots)
                         if other is req), None)
         active = row is not None
@@ -1944,8 +1799,7 @@ class SlotScheduler:
         # the completed windows of an in-flight prefill (the current
         # window may still be mid-dispatch under a forced export).
         lease = req._lease
-        if self.pages is not None and lease is not None \
-                and not lease.released:
+        if lease is not None and not lease.released:
             fresh = generated[req.resumed:]
             if active:
                 written = ctx.size + max(0, len(fresh) - 1)
@@ -1955,8 +1809,8 @@ class SlotScheduler:
             else:
                 # the windows a prefill has completed end where its next
                 # one starts (the current one may be mid-dispatch)
-                written = (prefill[4][prefill[2]][0] if prefill is not None
-                           else lease.skip)
+                written = (prefill.plan[prefill.next][0]
+                           if prefill is not None else lease.skip)
                 full = ctx
             published_ctx = full[:written]
             if self._stateful and active and clean:
@@ -2009,7 +1863,7 @@ class SlotScheduler:
 
         if self._stateful:
             raise ValueError(_WIRE_DECLINED)
-        if self.pages is None or not self.pages.prefix_cache:
+        if not self.pages.prefix_cache:
             return []
         if timeout_s is None:
             ok = self._pump_lock.acquire()
@@ -2054,8 +1908,7 @@ class SlotScheduler:
         all degrade to plain re-prefill)."""
         if self._stateful:
             raise ValueError(_WIRE_DECLINED)
-        if self.pages is None or not self.pages.prefix_cache \
-                or not records:
+        if not self.pages.prefix_cache or not records:
             return 0
         pg = self.page_size
         ctx = np.asarray(context, np.int32).reshape(-1)
@@ -2269,7 +2122,7 @@ class SlotScheduler:
             # own lock (lock order stays scheduler-independent)
             self.adapters.release(req.adapter_id)
             req.adapter_row = None
-        if req._lease is not None and self.pages is not None:
+        if req._lease is not None:
             # same discipline for the page lease: the pool has its own
             # lock, release is idempotent, and shared prefix pages stay
             # CACHED (refcount drops; eviction reclaims them only under

@@ -13,8 +13,8 @@ validate_flash_tpu.py:
    tolerance would measure rounding-order noise, not bugs.
 2. **Crossover**: decode-shaped timing (value-fetch closed, one scan
    dispatch) of the kernel vs gather+dense attention over a view_len
-   sweep — the numbers that seed ``DTTPU_PAGED_KERNEL_MIN_VIEW``
-   (ops/attention.py paged_kernel_wins) or demote the kernel.
+   sweep — the numbers behind ``_PAGED_KERNEL_MIN_VIEW``
+   (ops/attention.py paged_kernel_wins), or that demote the kernel.
 
 Prints one JSON line per measurement; paste results into docs/PERF.md.
 Exit codes: 0 ok, 1 parity failure, 2 not a TPU.
@@ -217,8 +217,8 @@ def main():
             "gather_reads_per_sec": round(S2 / t_gather, 1),
             "kernel_speedup": round(t_gather / t_kern, 3),
         }), flush=True)
-    print("crossover rule: set DTTPU_PAGED_KERNEL_MIN_VIEW to the first "
-          "view_len with kernel_speedup >= 1.1 (and record it in "
+    print("crossover rule: ops/attention.py _PAGED_KERNEL_MIN_VIEW is the "
+          "first view_len with kernel_speedup >= 1.1 (record it in "
           "docs/PERF.md); if no view_len wins, keep the 'auto' gate "
           "pointing at the gather path and demote in PERF.md",
           file=sys.stderr)

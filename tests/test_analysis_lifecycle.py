@@ -822,29 +822,6 @@ def test_begin_prefill_unwinds_pin_when_page_begin_fails_hard():
     assert eng.scheduler.pages._lease_count == 0    # nothing leaked
 
 
-def test_begin_prefill_unwinds_pin_when_cache_init_fails(monkeypatch):
-    """Contiguous-mode twin: a failure AFTER the pin in the kv-cache
-    init path (first admission, empty prefill pool) must unwind the
-    pin before propagating."""
-    from distributed_tensorflow_tpu.serve import scheduler as sched_mod
-    model, params = _model_params()
-    eng = serve.Engine(model, params, num_slots=2, max_len=32,
-                       prefill_chunk=4, tick_steps=2, paged=False,
-                       adapter_capacity=1, adapter_rank=4,
-                       registry=metrics_lib.Registry())
-    eng.load_adapter("tuned", _adapter(model, seed=3))
-
-    def boom(kv):
-        raise RuntimeError("synthetic cache-init failure")
-
-    eng.scheduler._pf_pool.clear()      # force the init_cache path
-    monkeypatch.setattr(sched_mod.slots_lib, "strip_pos", boom)
-    eng.submit(_prompt(5), 4, adapter_id="tuned")
-    with pytest.raises(RuntimeError, match="cache-init"):
-        eng.step()
-    assert eng.adapters._refs == {}
-
-
 # ---------------------------------------------------------------------------
 # chaos acceptance: fault storms under the ledger must balance exactly
 

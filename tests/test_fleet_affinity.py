@@ -238,17 +238,17 @@ def test_hot_prefix_convergence_sim_fleet():
     assert run(0.0) == [0] * 6          # blind: id tie every time
 
 
-def test_blind_fallback_contiguous_engines_keep_original_order():
-    """Contiguous engines publish NO fingerprint, so the affinity
-    router's placement order degrades exactly to the original
-    least-loaded (inflight, id) order — bit-identical to an
-    affinity_weight=0 fleet on the same trace."""
+def test_blind_fallback_engines_without_fingerprint_keep_original_order():
+    """Engines whose pool has its prefix cache off publish NO
+    fingerprint, so the affinity router's placement order degrades
+    exactly to the original least-loaded (inflight, id) order —
+    bit-identical to an affinity_weight=0 fleet on the same trace."""
     model, params = _model_params()
 
     def run(weight):
         reg = metrics_lib.Registry()
         router = fleet.Router(
-            [_engine(model, params, reg=reg, paged=False, page_size=None)
+            [_engine(model, params, reg=reg, prefix_cache=False)
              for _ in range(2)],
             registry=reg, affinity_weight=weight)
         for i in range(6):

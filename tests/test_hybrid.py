@@ -393,7 +393,7 @@ def _accounting(engine):
     pool = sched.pages
     with sched._lock:
         leases = [r._lease for r in sched._slots if r is not None] \
-            + [st[3] for st in sched._prefills]
+            + [st.lease for st in sched._prefills]
     with pool._lock:
         reachable, stack = set(), [pool._root]
         while stack:

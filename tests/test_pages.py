@@ -1,8 +1,11 @@
 """Paged KV cache + radix prefix reuse tests (serve/pages.py).
 
 The contracts pinned here (docs/SERVING.md):
-  * paged engine == contiguous engine == greedy ``GPT.generate``
-    token-for-token (chunked prefill, RoPE + GQA, int8 scale planes),
+  * engine == greedy ``GPT.generate`` token-for-token (chunked
+    prefill, RoPE + GQA, int8 scale planes), and below the engine the
+    jitted paged step == ``decode_step``'s logits,
+  * admitting a request mid-decode leaves other slots' logits
+    BIT-identical; a retired row's writes land on the trash page,
   * a prefix-cache HIT request's tokens are bit-identical to the same
     request on a COLD cache, and the skipped prefill windows are
     measured, not assumed,
@@ -31,6 +34,7 @@ import jax.numpy as jnp
 from distributed_tensorflow_tpu import serve
 from distributed_tensorflow_tpu.models.gpt import gpt_tiny
 from distributed_tensorflow_tpu.obs import metrics as metrics_lib
+from distributed_tensorflow_tpu.ops import attention as attn_lib
 from distributed_tensorflow_tpu.serve import pages as pages_lib
 
 
@@ -214,31 +218,181 @@ def test_pool_exhausted_rolls_back_pins():
 # engine exactness: paged == contiguous == generate
 
 
-@pytest.mark.parametrize("kw", [
+_FAMILIES = pytest.mark.parametrize("kw", [
     {},
     {"position_embedding": "rope", "num_heads": 4, "hidden_size": 128,
      "num_kv_heads": 2},
     {"kv_cache_dtype": "int8"},
 ], ids=["base", "rope_gqa", "int8"])
-def test_paged_engine_matches_contiguous_and_generate(kw):
+
+
+@_FAMILIES
+def test_paged_engine_matches_generate(kw):
     """The tentpole exactness contract, per config family: a mixed
-    workload through the paged engine equals the contiguous engine
-    request-for-request, and both equal solo generate."""
+    workload through the engine equals solo generate
+    request-for-request."""
     model, params = _model_params(**kw)
     prompts = [_prompt(7, seed=1), _prompt(5, seed=2), _prompt(9, seed=3),
                _prompt(3, seed=4)]
     budgets = [9, 6, 4, 8]
     wants = [_generate_tokens(model, params, p, n, 64)
              for p, n in zip(prompts, budgets)]
-    outs = {}
-    for paged in (True, False):
-        eng = serve.Engine(model, params, num_slots=2, max_len=64,
-                           prefill_chunk=4, tick_steps=3, paged=paged,
-                           registry=metrics_lib.Registry())
-        hs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
-        eng.drain()
-        outs[paged] = [h.tokens for h in hs]
-    assert outs[True] == outs[False] == wants
+    eng = serve.Engine(model, params, num_slots=2, max_len=64,
+                       prefill_chunk=4, tick_steps=3,
+                       registry=metrics_lib.Registry())
+    hs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+    eng.drain()
+    assert [h.tokens for h in hs] == wants
+
+
+# ---------------------------------------------------------------------------
+# below the engine: the jitted paged step against decode_step
+
+
+def _prefill_into_pages(model, params, cache, slot, page_row, prompt,
+                        window=8, use_kernel=False):
+    """What admission does, by hand: ``prompt`` through
+    ``decode_window_paged`` into the pages of ``page_row`` (the last
+    window right-padded), then the slot's column state armed."""
+    kv = cache["kv"]
+    for pos in range(0, prompt.size, window):
+        win = np.zeros((1, window), np.int32)
+        real = prompt[pos:pos + window]
+        win[0, :real.size] = real
+        _, kv = model.decode_window_paged(
+            params, kv, jnp.asarray(win), jnp.asarray(page_row),
+            jnp.int32(pos), head="none", use_kernel=use_kernel)
+    n = jnp.int32(prompt.size)
+    return dict(cache, kv=kv,
+                start_col=cache["start_col"].at[slot].set(0),
+                write_col=cache["write_col"].at[slot].set(n),
+                positions=cache["positions"].at[slot].set(n))
+
+
+def _paged_step(model, use_kernel=False):
+    return jax.jit(lambda params, cache, tab, toks, live:
+                   pages_lib.decode_paged_step(model, params, cache, tab,
+                                               toks, live,
+                                               use_kernel=use_kernel))
+
+
+@_FAMILIES
+def test_decode_paged_step_matches_decode_step_logits(kw):
+    """Numeric oracle below the engine: a slot whose prompt went through
+    ``decode_window_paged`` into pool pages produces ``decode_step``'s
+    logits over a ``decode_block`` prefill, step after step (per-row
+    column state and a page table vs one scalar ``pos``)."""
+    model, params = _model_params(**kw)
+    prompt, max_len, page_size = _prompt(6, seed=7), 32, 8
+    ref = model.init_cache(1, max_len)
+    _, ref = model.decode_block(params, ref, jnp.asarray(prompt[None]))
+    tab = np.zeros((3, max_len // page_size), np.int32)
+    tab[0] = [3, 1, 4, 2]               # any pages but the trash page
+    cache = pages_lib.init_paged_cache(model, 3, 9, page_size)
+    cache = _prefill_into_pages(model, params, cache, 0, tab[0], prompt)
+    if "k_scale" in ref:
+        # the int8 planes AND their f32 scales, token for token: the
+        # pool's flat rows are a contiguous cache row's heads side by
+        # side (that row filled by the same window — a whole-prompt
+        # ``decode_block`` rounds a few values the other way)
+        assert cache["kv"]["k"].dtype == jnp.int8
+        assert cache["kv"]["k_scale"].dtype == jnp.float32
+        win = np.zeros((1, 8), np.int32)
+        win[0, :prompt.size] = prompt
+        _, row = model.decode_window(params, model.init_cache(1, max_len),
+                                     jnp.asarray(win), head="none")
+        for name in ("k", "v", "k_scale", "v_scale"):
+            want = np.asarray(row[name][:, 0, :prompt.size])
+            got = np.asarray(cache["kv"][name][:, tab[0, 0], :prompt.size])
+            np.testing.assert_array_equal(
+                got, want.reshape(want.shape[:2] + (-1,)))
+        ref = dict(row, pos=jnp.int32(prompt.size))    # same rounding
+    step = _paged_step(model)
+    live = jnp.asarray([True, False, False])
+    tok = int(prompt[-1])               # any token, fed to both sides
+    for n in range(5):
+        ref_logits, ref = model.decode_step(params, ref,
+                                            jnp.asarray([tok], jnp.int32))
+        logits, cache = step(params, cache, tab,
+                             jnp.asarray([tok, 0, 0], jnp.int32), live)
+        np.testing.assert_allclose(np.asarray(logits[0]),
+                                   np.asarray(ref_logits[0]), atol=2e-4)
+        tok = int(jnp.argmax(ref_logits[0]))
+    assert int(cache["write_col"][0]) == prompt.size + 5  # live: advanced
+    assert int(cache["write_col"][1]) == 0                # dead: frozen
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["gather", "kernel"])
+def test_admission_mid_decode_keeps_other_slots_bit_identical(use_kernel):
+    """Arming slot 1 mid-decode (its prompt prefilled into its own pages,
+    its table row and column state set) must not change slot 0's logits
+    by even one bit: same executable, row-independent math — through the
+    gather read and through the page-walk kernel (interpret mode)."""
+    model, params = _model_params()
+    p0, p1 = _prompt(6, seed=1), _prompt(4, seed=2)
+    feed = np.asarray(_prompt(6, seed=9))       # fixed row-0 token feed
+    step = _paged_step(model, use_kernel)
+
+    def run(arm_at):
+        tab = np.zeros((2, 4), np.int32)
+        tab[0] = [1, 2, 3, 4]
+        cache = pages_lib.init_paged_cache(model, 2, 9, 8)
+        cache = _prefill_into_pages(model, params, cache, 0, tab[0], p0,
+                                    use_kernel=use_kernel)
+        live, out = jnp.asarray([True, False]), []
+        for t in range(6):
+            if t == arm_at:
+                tab[1] = [5, 6, 7, 8]
+                cache = _prefill_into_pages(model, params, cache, 1,
+                                            tab[1], p1,
+                                            use_kernel=use_kernel)
+                live = jnp.asarray([True, True])
+            logits, cache = step(params, cache, tab.copy(),
+                                 jnp.asarray([feed[t], 0], jnp.int32),
+                                 live)
+            out.append(np.asarray(logits[0]))
+        return out
+
+    for alone, beside in zip(run(arm_at=None), run(arm_at=3)):
+        np.testing.assert_array_equal(alone, beside)
+
+
+def test_retired_row_writes_land_on_the_trash_page():
+    """A retired slot still computes (static shapes), but the scheduler
+    hands the tick an all-zero table row for it, so its frozen write
+    lands on page 0: the pages it held — reallocatable now — and every
+    other slot's pages do not change by a bit."""
+    model, params = _model_params()
+    eng = serve.Engine(model, params, num_slots=2, max_len=32,
+                       prefill_chunk=4, tick_steps=2, page_size=8,
+                       registry=metrics_lib.Registry())
+    eng.submit(_prompt(5, seed=3), 3)
+    eng.drain()
+    assert not eng.scheduler._page_tab.any()    # retired rows map trash
+
+    tab = np.zeros((2, 4), np.int32)
+    tab[0] = [1, 2, 3, 4]
+    cache = pages_lib.init_paged_cache(model, 2, 9, 8)
+    cache = _prefill_into_pages(model, params, cache, 0, tab[0],
+                                _prompt(6, seed=1))
+    cache = _prefill_into_pages(model, params, cache, 1,
+                                np.asarray([5, 6, 7, 8], np.int32),
+                                _prompt(11, seed=2))
+    before = jax.tree.map(np.asarray, cache["kv"])
+    step = _paged_step(model)
+    live = jnp.asarray([True, False])           # slot 1 retired: row 0s
+    for _ in range(3):
+        _, cache = step(params, cache, tab,
+                        jnp.asarray([7, 9], jnp.int32), live)
+    assert int(cache["write_col"][1]) == 11     # frozen write head
+    for name, was in before.items():
+        now = np.asarray(cache["kv"][name])
+        changed = np.argwhere((now != was).any(axis=(0, 3)))
+        # slot 0 wrote its columns 6..8: cells 6, 7 of its first page
+        # and cell 0 of its second; the frozen row wrote trash cell
+        # 11 % 8 three times over — and no cell of the pages it held
+        assert changed.tolist() == [[0, 3], [1, 6], [1, 7], [2, 0]], name
 
 
 def test_prefix_hit_bit_identical_to_cold_cache_and_skips_windows():
@@ -531,12 +685,12 @@ def test_auto_page_size_multiple_of():
     {"num_heads": 25, "hidden_size": 1600, "intermediate_size": 256},
 ], ids=["base", "rope_gqa", "int8", "w320", "w320_rope_gqa", "w320_int8",
         "w1600_xl_heads"])
-def test_kernel_engine_matches_gather_contiguous_and_generate(kw):
+def test_kernel_engine_matches_gather_and_generate(kw):
     """The kernel exactness contract, per config family: the fused
     page-walk read path produces token streams bit-identical to the
-    XLA gather path, the contiguous stripe engine, and solo greedy
-    generate (the kernel runs in interpret mode on the CPU mesh, so
-    this executes the real kernel body)."""
+    XLA gather path and solo greedy generate (the kernel runs in
+    interpret mode on the CPU mesh, so this executes the real kernel
+    body)."""
     model, params = _model_params(**kw)
     prompts = [_prompt(7, seed=1), _prompt(5, seed=2), _prompt(9, seed=3),
                _prompt(3, seed=4)]
@@ -547,15 +701,14 @@ def test_kernel_engine_matches_gather_contiguous_and_generate(kw):
     for label, ekw in (("kernel", dict(use_paged_kernel=True,
                                        page_size=8)),
                        ("gather", dict(use_paged_kernel=False,
-                                       page_size=8)),
-                       ("contig", dict(paged=False))):
+                                       page_size=8))):
         eng = serve.Engine(model, params, num_slots=2, max_len=64,
                            prefill_chunk=4, tick_steps=3,
                            registry=metrics_lib.Registry(), **ekw)
         hs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
         eng.drain()
         outs[label] = [h.tokens for h in hs]
-    assert outs["kernel"] == outs["gather"] == outs["contig"] == wants
+    assert outs["kernel"] == outs["gather"] == wants
 
 
 def test_prefix_hit_and_cow_exact_through_kernel():
@@ -690,7 +843,7 @@ def test_use_paged_kernel_page_size_validation(monkeypatch):
     # make the auto gate say yes (TPU backend, threshold met) while the
     # layout stays incompatible: warn + fall back, never raise
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("DTTPU_PAGED_KERNEL_MIN_VIEW", "16")
+    monkeypatch.setattr(attn_lib, "_PAGED_KERNEL_MIN_VIEW", 16)
     with pytest.warns(RuntimeWarning, match="gather"):
         eng = serve.Engine(model, params, num_slots=2, max_len=30,
                            page_size=10, registry=metrics_lib.Registry())

@@ -717,12 +717,3 @@ class TestPagedKernelDispatch:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert attn_lib.resolve_use_paged_kernel("auto", 2048) is True
         assert attn_lib.resolve_use_paged_kernel("auto", 128) is False
-
-    def test_paged_kernel_min_view_env(self, monkeypatch):
-        from distributed_tensorflow_tpu.ops import attention as attn_lib
-        monkeypatch.setenv("DTTPU_PAGED_KERNEL_MIN_VIEW", "64")
-        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-        assert attn_lib.paged_kernel_wins(128) is False
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        assert attn_lib.paged_kernel_wins(128) is True
-        assert attn_lib.paged_kernel_wins(32) is False

@@ -4,11 +4,7 @@ safety, retrace-free scheduling, metrics.
 The contracts pinned here (docs/SERVING.md):
   * single request through the engine == greedy ``GPT.generate``
     token-for-token (chunked prefill included),
-  * admitting a request mid-decode leaves other slots' logits
-    BIT-identical (same executable, row-independent math),
-  * int8 ``kv_cache_dtype`` slot splices round-trip values AND scales,
-  * a reused slot never reads the previous occupant's K/V (left-padded
-    ragged splices included),
+  * a reused slot never reads the previous occupant's K/V,
   * admission/retirement never recompile anything (RetraceGuard).
 """
 import numpy as np
@@ -20,7 +16,6 @@ import jax.numpy as jnp
 from distributed_tensorflow_tpu import serve
 from distributed_tensorflow_tpu.models.gpt import gpt_tiny
 from distributed_tensorflow_tpu.obs import metrics as metrics_lib
-from distributed_tensorflow_tpu.ops import decoding as dec
 
 
 def _model_params(seed=0, **kw):
@@ -107,65 +102,8 @@ def test_rope_gqa_engine_matches_generate():
     assert h.tokens == want
 
 
-def test_decode_slots_step_matches_decode_step_logits():
-    """Numeric oracle below the engine: one slot holding a prefilled
-    request produces decode_step's logits (same cache contents, per-row
-    state vs scalar pos)."""
-    model, params = _model_params()
-    ids = np.asarray(_prompt(6, seed=7))[None, :]
-    ref_cache = model.init_cache(1, 16)
-    _, ref_cache = model.decode_block(params, ref_cache,
-                                      jnp.asarray(ids))
-    cache = serve.init_slot_cache(model, num_slots=3, max_len=16)
-    cache = serve.insert_slot(cache, 0, serve.strip_pos(ref_cache), 6)
-    tok = jnp.asarray([ids[0, -1], 0, 0], jnp.int32)
-    live = jnp.asarray([True, False, False])
-    # feed the same token through both paths (the value fed does not
-    # matter for the comparison as long as both sides see it)
-    ref_logits, _ = model.decode_step(params, ref_cache, tok[:1])
-    slot_logits, cache = serve.decode_slots_step(model, params, cache,
-                                                 tok, live)
-    np.testing.assert_allclose(np.asarray(slot_logits[0]),
-                               np.asarray(ref_logits[0]), atol=2e-4)
-    assert int(cache["write_col"][0]) == 7      # live row advanced
-    assert int(cache["write_col"][1]) == 0      # dead rows frozen
-
-
 # ---------------------------------------------------------------------------
 # isolation: admission / stale KV
-
-
-def test_mid_decode_insertion_keeps_other_slots_bit_identical():
-    """Splicing a request into slot 1 mid-decode must not change slot
-    0's logits by even one bit: same executable, row-independent math."""
-    model, params = _model_params()
-    p0, p1 = _prompt(6, seed=1), _prompt(4, seed=2)
-    pf0 = model.init_cache(1, 16)
-    _, pf0 = model.decode_block(params, pf0, jnp.asarray(p0[None]))
-    pf1 = model.init_cache(1, 16)
-    _, pf1 = model.decode_block(params, pf1, jnp.asarray(p1[None]))
-    feed = np.asarray(_prompt(6, seed=9))       # fixed row-0 token feed
-
-    def run(insert_at):
-        cache = serve.init_slot_cache(model, 2, 16)
-        cache = serve.insert_slot(cache, 0, serve.strip_pos(pf0), 6)
-        live = jnp.asarray([True, False])
-        out = []
-        for t in range(6):
-            if t == insert_at:
-                cache = serve.insert_slot(cache, 1,
-                                          serve.strip_pos(pf1), 4)
-                live = jnp.asarray([True, True])
-            tokens = jnp.asarray([feed[t], 0], jnp.int32)
-            logits, cache = serve.decode_slots_step(model, params,
-                                                    cache, tokens, live)
-            out.append(np.asarray(logits[0]))
-        return out
-
-    alone = run(insert_at=None)
-    with_insert = run(insert_at=3)
-    for a, b in zip(alone, with_insert):
-        np.testing.assert_array_equal(a, b)
 
 
 def test_retire_then_reuse_never_reads_stale_kv():
@@ -184,70 +122,6 @@ def test_retire_then_reuse_never_reads_stale_kv():
     assert h1.tokens == _generate_tokens(model, params, long_p, 20, 40)
     assert h2.tokens == _generate_tokens(model, params, short_p, 5, 40)
     assert h3.tokens == h1.tokens
-
-
-def test_left_padded_ragged_splice_matches_solo():
-    """insert_slot(pad_len=...) accepts a LEFT-padded ragged prefill row
-    (decode_block kv_valid/positions) and the slot then decodes exactly
-    the solo ragged generate — pads masked, positions shifted."""
-    model, params = _model_params()
-    plen, pad = 6, 2
-    real = _prompt(plen - pad, seed=13)
-    padded = np.zeros((plen,), np.int32)
-    padded[pad:] = real
-    valid = np.zeros((plen,), np.int32)
-    valid[pad:] = 1
-    max_len = 24
-    pad_len, kv_valid = dec.ragged_prompt_masks(
-        jnp.asarray(valid[None]), (1, plen), max_len)
-    pf = model.init_cache(1, max_len)
-    logits, pf = model.decode_block(
-        params, pf, jnp.asarray(padded[None]),
-        kv_valid=kv_valid[:, :plen],
-        positions=jnp.maximum(jnp.arange(plen)[None, :]
-                              - pad_len[:, None], 0))
-    want = _generate_tokens(model, params, real, 7, max_len)
-
-    cache = serve.init_slot_cache(model, 2, max_len)
-    cache = serve.insert_slot(cache, 0, serve.strip_pos(pf),
-                              plen - pad, pad_len=pad)
-    kvv = np.asarray(serve.slot_kv_valid(cache))
-    assert not kvv[0, :pad].any() and kvv[0, pad:plen].all() \
-        and not kvv[0, plen:].any()
-    tok = int(jnp.argmax(logits[0]))
-    got = [tok]
-    live = jnp.asarray([True, False])
-    for _ in range(6):
-        logits, cache = serve.decode_slots_step(
-            model, params, cache, jnp.asarray([tok, 0], jnp.int32), live)
-        tok = int(jnp.argmax(logits[0]))
-        got.append(tok)
-    assert got == want
-
-
-def test_int8_slot_splice_roundtrips_scales():
-    """kv_cache_dtype='int8': the slot splice carries int8 planes AND
-    f32 scales bit-for-bit, and the engine's greedy output equals the
-    int8 generate()'s."""
-    model, params = _model_params(kv_cache_dtype="int8")
-    prompt = _prompt(6, seed=1)
-    pf = model.init_cache(1, 16)
-    _, pf = model.decode_block(params, pf, jnp.asarray(prompt[None]))
-    cache = serve.init_slot_cache(model, 3, 16)
-    assert cache["kv"]["k"].dtype == jnp.int8
-    assert cache["kv"]["k_scale"].dtype == jnp.float32
-    cache = serve.insert_slot(cache, 1, serve.strip_pos(pf), 6)
-    for name in ("k", "v", "k_scale", "v_scale"):
-        np.testing.assert_array_equal(
-            np.asarray(cache["kv"][name][:, 1]),
-            np.asarray(pf[name][:, 0]))
-
-    want = _generate_tokens(model, params, prompt, 8, 32)
-    eng = serve.Engine(model, params, num_slots=2, max_len=32,
-                       prefill_chunk=8, tick_steps=3)
-    h = eng.submit(prompt, 8)
-    eng.drain()
-    assert h.tokens == want
 
 
 # ---------------------------------------------------------------------------
@@ -316,6 +190,33 @@ def test_submit_validation():
         eng.submit(_prompt(17), 1)           # chunk-padded 20 > 16
     with pytest.raises(ValueError, match="num_slots"):
         serve.Engine(model, params, num_slots=0, max_len=16)
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_storage_layout_is_not_an_option(paged):
+    """The page pool is the one K/V storage: ``paged=`` is an unknown
+    keyword like any other, with no shim for either value."""
+    model, params = _model_params()
+    with pytest.raises(TypeError, match="paged"):
+        serve.Engine(model, params, num_slots=2, max_len=16, paged=paged)
+
+
+def test_inflight_prefill_is_a_named_record_compared_by_identity():
+    """``st in self._prefills`` / ``.remove(st)`` must mean THIS prefill:
+    two requests with equal prompts are two records."""
+    model, params = _model_params()
+    eng = serve.Engine(model, params, num_slots=2, max_len=32,
+                       prefill_chunk=4, tick_steps=2,
+                       registry=metrics_lib.Registry())
+    h1, h2 = eng.submit(_prompt(9), 3), eng.submit(_prompt(9), 3)
+    eng.step()                                   # two prefills begun
+    a, b = eng.scheduler._prefills
+    assert (a.req.rid, b.req.rid) == (h1.rid, h2.rid)
+    assert a.next == b.next == 1 and a.lease is not b.lease
+    assert len(a.windows) == 3 and a.plan[1][:2] == (4, 4)
+    assert a != b and eng.scheduler._prefills.index(b) == 1
+    eng.drain()
+    assert h1.tokens == h2.tokens
 
 
 def test_engine_metrics_land_in_registry():
