@@ -448,7 +448,7 @@ def _paged_read_errors(model, num_slots, max_len, page_size, window, seed):
     from distributed_tensorflow_tpu.ops.attention import (
         NEG_INF, dot_product_attention, padding_mask)
     from distributed_tensorflow_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention, paged_window_attention)
+        page_walk, paged_decode_attention, paged_window_attention)
 
     c = model.config
     pps = max_len // page_size
@@ -485,8 +485,9 @@ def _paged_read_errors(model, num_slots, max_len, page_size, window, seed):
     k_g, v_g = model._paged_layer_kv(pool, layer, tab)
     mask = padding_mask(valid)
     out["decode"] = errors(
-        jax.jit(lambda q, pool, tab, valid: paged_decode_attention(
-            q, pool, layer, tab, valid))(q, pool, tab, valid),
+        jax.jit(lambda q, pool, tab, lens: paged_decode_attention(
+            q, pool, layer, page_walk(pool, tab, jnp.zeros_like(lens), lens)
+        ))(q, pool, tab, jnp.asarray(lens, jnp.int32)),
         dot_product_attention(q, k_g, v_g, mask=mask),
         truth(q, k_g, v_g, mask))
 
@@ -497,8 +498,9 @@ def _paged_read_errors(model, num_slots, max_len, page_size, window, seed):
                       <= pos + jnp.arange(window)[None, None, :, None],
                       0.0, NEG_INF)
     out["window"] = errors(
-        jax.jit(lambda q, pool, row, pos: paged_window_attention(
-            q, pool, layer, row, pos))(qw, pool, tab[0], pos),
+        jax.jit(lambda q, pool, row, end: paged_window_attention(
+            q, pool, layer, page_walk(pool, row, jnp.zeros_like(end), end)
+        ))(qw, pool, tab[:1], jnp.asarray([pos + window], jnp.int32)),
         dot_product_attention(qw, k_g, v_g, mask=wmask),
         truth(qw, k_g, v_g, wmask))
     return out

@@ -1030,7 +1030,7 @@ class GPT:
         return logits, dict(new_kv, pos=pos + 1)
 
     def decode_step_slots_paged(self, params, kv, token_ids, page_tab,
-                                write_col, kv_valid, positions,
+                                start_col, write_col, positions,
                                 adapters=None, adapter_rows=None,
                                 use_kernel: bool = False):
         """One token per row against a PAGED slot cache (continuous
@@ -1039,8 +1039,10 @@ class GPT:
         The batch dimension is a bank of SLOTS, each an independent
         request, and per-row state replaces ``decode_step``'s scalar
         ``pos``: row r's token writes at its logical column
-        ``write_col[r]``, attends ``kv_valid[r]`` plus its own column,
-        and embeds at ``positions[r]`` (the row's token count).  The
+        ``write_col[r]``, attends the columns ``start_col[r]`` up to and
+        with its own (an empty run when ``start_col[r] > write_col[r]``:
+        how the caller marks a row that is not live), and embeds at
+        ``positions[r]`` (the row's token count).  The
         K/V live in a shared page pool (``kv``: ``[L, num_pages,
         page_size, ...]`` leaves) indexed by the per-row ``page_tab``
         [b, pages_per_row]: reads gather each row's pages
@@ -1071,10 +1073,11 @@ class GPT:
         ``use_kernel`` (STATIC, resolved by the caller through
         ``attn_lib.resolve_use_paged_kernel``): read the pool through
         the fused Pallas kernel (ops/pallas/paged_attention.py) — the
-        page walk happens inside the attention loop and the gathered
-        ``[b, view_len, ...]`` operand never materializes.  The write
-        path is the same either way; tests pin kernel == gather token
-        streams bit-for-bit.
+        page walk happens inside the attention loop, over the table
+        entries a row's run holds and no others, and neither the
+        gathered ``[b, view_len, ...]`` operand nor a ``[b, view_len]``
+        mask materializes.  The write path is the same either way;
+        tests pin kernel == gather token streams bit-for-bit.
         """
         c = self.config
         emb = params["embeddings"]
@@ -1085,10 +1088,6 @@ class GPT:
         x = x.astype(c.dtype)
 
         page_size = kv["k"].shape[2]
-        view_len = page_tab.shape[1] * page_size
-        valid = kv_valid | (jnp.arange(view_len)[None, :]
-                            == write_col[:, None])
-        kv_mask = jnp.where(valid, 0.0, attn_lib.NEG_INF)[:, None, None, :]
 
         rope_cs = None
         if c.position_embedding == "rope":
@@ -1103,15 +1102,26 @@ class GPT:
                                       axis=1)[:, 0]
         paged = (w_pages, write_col % page_size)
 
-        def attention(q, k_blk, v_blk, kv, i):
-            del k_blk, v_blk   # single token: read back through the pool
-            if use_kernel:
-                from ..ops.pallas import paged_attention as paged_lib
-                return paged_lib.paged_decode_attention(q, kv, i,
-                                                        page_tab, valid)
-            k_cache, v_cache = self._paged_layer_kv(kv, i, page_tab)
-            return attn_lib.dot_product_attention(q, k_cache, v_cache,
-                                                  mask=kv_mask)
+        if use_kernel:
+            from ..ops.pallas import paged_attention as paged_lib
+            # the same pages for every layer: walked once, out here
+            walk = paged_lib.page_walk(kv, page_tab, start_col,
+                                       write_col + 1)
+
+            def attention(q, k_blk, v_blk, kv, i):
+                del k_blk, v_blk   # one token: read back through the pool
+                return paged_lib.paged_decode_attention(q, kv, i, walk)
+        else:
+            cols = jnp.arange(page_tab.shape[1] * page_size)[None, :]
+            kv_mask = jnp.where(
+                (cols >= start_col[:, None]) & (cols <= write_col[:, None]),
+                0.0, attn_lib.NEG_INF)[:, None, None, :]
+
+            def attention(q, k_blk, v_blk, kv, i):
+                del k_blk, v_blk
+                k_cache, v_cache = self._paged_layer_kv(kv, i, page_tab)
+                return attn_lib.dot_product_attention(q, k_cache, v_cache,
+                                                      mask=kv_mask)
 
         def body(carry, inputs):
             x, kv = carry
@@ -1463,8 +1473,9 @@ class GPT:
         window positions, write-then-attend per layer, same head
         modes), but the cache is the POOL — writes land on their pool
         cells via ``_cache_layer``'s page-write, reads walk ``page_row``
-        inside ``ops.pallas.paged_window_attention`` with the
-        ``col <= pos + j`` causal mask computed in-kernel."""
+        inside ``ops.pallas.paged_window_attention``, up to the page of
+        the window's last column, with the ``col <= pos + j`` causal mask
+        computed in-kernel."""
         from ..ops.pallas import paged_attention as paged_lib
         c = self.config
         b, s = token_ids.shape
@@ -1485,10 +1496,15 @@ class GPT:
         pids = jnp.take(page_row, cols // page_size)
         paged = (pids, cols % page_size)
 
+        # prefix + window: the columns up to the window's last, on the
+        # same pages for every layer
+        walk = paged_lib.page_walk(kv, page_row[None, :],
+                                   jnp.zeros((1,), jnp.int32),
+                                   jnp.reshape(pos + s, (1,)))
+
         def window_attn(q, k_blk, v_blk, kv, i):
             del k_blk, v_blk   # read back through the pool (prefix + win)
-            return paged_lib.paged_window_attention(q, kv, i, page_row,
-                                                    pos)
+            return paged_lib.paged_window_attention(q, kv, i, walk)
 
         def body(carry, inputs):
             x, kv = carry

@@ -598,14 +598,15 @@ class HybridDecoder:
         return self.logits(params, x), kv, state
 
     def decode_step_slots_paged(self, params, kv, token_ids, page_tab,
-                                write_col, kv_valid, positions, *, state,
+                                start_col, write_col, positions, *, state,
                                 live, adapters=None, adapter_rows=None,
                                 use_kernel: bool = False):
         """One token for every slot against the paged cache (the serving
         decode step): row r writes its K/V at logical column
-        ``write_col[r]`` through ``page_tab[r]``, attends ``kv_valid[r]``
-        plus its own column, and advances row r of ``state`` — unless it is
-        not ``live``: such a row's state and convolution inputs come back
+        ``write_col[r]`` through ``page_tab[r]``, attends the columns from
+        ``start_col[r]`` up to and with its own, and advances row r of
+        ``state`` — unless it is not ``live``: such a row's state and
+        convolution inputs come back
         unchanged (its K/V write lands wherever its table points: the
         trash page once retired).  ``positions`` is unused: the stack has
         no positional encoding.  Returns ``(logits [b, vocab], kv,
@@ -615,9 +616,15 @@ class HybridDecoder:
             raise ValueError("this decoder has no adapter path and reads "
                              "its pages through the gather path")
         c = self.config
-        x = self._embed(params, token_ids)[:, None, :]
         page_size = kv["k"].shape[2]
         view_len = page_tab.shape[1] * page_size
+        # the slot's own columns, then (below) the one it writes now: in
+        # this order the decoder's programs lower to the text they had
+        # (tests/test_tpu_compile.py pins it)
+        cols = jnp.arange(view_len)[None, :]
+        kv_valid = ((cols >= start_col[:, None])
+                    & (cols < write_col[:, None]))
+        x = self._embed(params, token_ids)[:, None, :]
         valid = kv_valid | (jnp.arange(view_len)[None, :]
                             == write_col[:, None])
         mask = jnp.where(valid, 0.0, attn_lib.NEG_INF)[:, None, None, :]

@@ -13,7 +13,7 @@ exercises the real kernel code paths on the virtual CPU mesh.
 from .flash_attention import flash_attention, make_flash_attention_fn
 from .fused import (fused_adam_update, fused_layernorm, fused_rmsnorm,
                     resolve_fused_ln)
-from .paged_attention import (MIN_PAGE_SIZE, page_size_kernel_ok,
+from .paged_attention import (MIN_PAGE_SIZE, page_size_kernel_ok, page_walk,
                               paged_decode_attention,
                               paged_window_attention)
 
@@ -26,6 +26,7 @@ __all__ = [
     "resolve_fused_ln",
     "MIN_PAGE_SIZE",
     "page_size_kernel_ok",
+    "page_walk",
     "paged_decode_attention",
     "paged_window_attention",
 ]

@@ -30,8 +30,7 @@ from .adapters import AdapterTable, AdapterTableFull
 from .engine import (DrainResult, Engine, QueueFullError, RequestHandle,
                      ServeMetrics)
 from .pages import (PageLease, PagePool, PagePoolExhausted,
-                    auto_page_size, decode_paged_step, init_paged_cache,
-                    paged_kv_valid)
+                    auto_page_size, decode_paged_step, init_paged_cache)
 from .scheduler import (EngineStats, Request, RequestSnapshot,
                         SlotScheduler)
 
@@ -39,5 +38,5 @@ __all__ = ["AdapterTable", "AdapterTableFull", "DrainResult", "Engine",
            "EngineStats", "PageLease", "PagePool", "PagePoolExhausted",
            "QueueFullError", "RequestHandle", "RequestSnapshot",
            "ServeMetrics", "Request", "SlotScheduler", "auto_page_size",
-           "decode_paged_step", "init_paged_cache", "paged_kv_valid",
+           "decode_paged_step", "init_paged_cache",
            "adapters", "engine", "pages", "scheduler"]

@@ -153,6 +153,14 @@ class ServeMetrics:
         self.decode_steps = reg.counter(
             "dttpu_serve_decode_steps_total",
             "Decode steps dispatched (tick_steps per decode dispatch).")
+        self.decode_pages_walked = reg.counter(
+            "dttpu_serve_decode_pages_walked_total",
+            "Page-table entries the decode steps read: with the "
+            "page-walk kernel, the pages live slots' tokens lie on.")
+        self.decode_pages_table = reg.counter(
+            "dttpu_serve_decode_pages_table_total",
+            "Page-table entries those steps' tables hold (steps x slots "
+            "x pages a slot): what a full-table read takes.")
         self.admit_backpressure = reg.counter(
             "dttpu_serve_admit_backpressure_total",
             "Admissions bounced back to the queue: every adapter row "
@@ -180,6 +188,8 @@ class ServeMetrics:
             [self.ticks, "ticks_completed", 0],
             [self.prefill_windows, "prefill_windows_total", 0],
             [self.decode_steps, "decode_steps_total", 0],
+            [self.decode_pages_walked, "decode_pages_walked_total", 0],
+            [self.decode_pages_table, "decode_pages_table_total", 0],
             [self.admit_backpressure, "admit_backpressure_total", 0]]
         # per-tenant series, created lazily at first sight of a tenant
         # (cardinality = the tenant set, which admission policy bounds)

@@ -95,7 +95,7 @@ import numpy as np
 __all__ = ["FINGERPRINT_K", "PageLease", "PagePool", "PagePoolExhausted",
            "StateSnapshot", "auto_page_size", "decode_paged_step",
            "init_paged_cache", "init_state_snapshots", "kv_pool_bytes",
-           "paged_kv_valid", "prompt_chain_keys", "state_bytes_per_slot"]
+           "prompt_chain_keys", "state_bytes_per_slot"]
 
 # default bound on the hot-chain fingerprint (entries, not pages): big
 # enough for a handful of system prompts at every chunk depth, small
@@ -171,10 +171,10 @@ def init_paged_cache(model, num_slots: int, num_pages: int,
     one flat row; int8 scale planes ``[..., kv_heads]`` included)
     plus the per-slot column state [S] — a slot's tokens occupy the
     LOGICAL column run ``[start_col, write_col)`` (validity is two ints;
-    the boolean view is derived per step, ``paged_kv_valid``, never
-    stored), ``positions`` is its next position index; all slots start
-    retired (an empty window at column 0) — and, for a
-    model with recurrent state, one ``[layers, num_slots, ...]`` block
+    the gather read derives its boolean view per step, the kernel
+    walks the run's pages), ``positions`` is its next position index;
+    all slots start retired (an empty window at column 0) — and, for
+    a model with recurrent state, one ``[layers, num_slots, ...]`` block
     per state leaf under ``"state"`` (absent otherwise, so a K/V-only
     model's programs are what they were)."""
     import jax.numpy as jnp
@@ -227,18 +227,6 @@ def state_bytes_per_slot(model) -> int:
                in model.paged_cache_spec()["state"].values())
 
 
-def paged_kv_valid(cache, view_len: int):
-    """[S, view_len] bool view of each slot's valid LOGICAL columns
-    (the pool's shape does not encode the per-slot view length, so it
-    is passed in).  Masked columns get exp(NEG_INF) = 0 attention weight:
-    whatever a previous occupant left behind is multiplied by an exact
-    zero, so retirement never scrubs a page."""
-    import jax.numpy as jnp
-    cols = jnp.arange(view_len)[None, :]
-    return ((cols >= cache["start_col"][:, None])
-            & (cols < cache["write_col"][:, None]))
-
-
 def decode_paged_step(model, params, cache, page_tab, tokens, live,
                       adapters=None, adapter_rows=None,
                       use_kernel: bool = False):
@@ -255,15 +243,18 @@ def decode_paged_step(model, params, cache, page_tab, tokens, live,
     read through the fused Pallas page-walk kernel instead of the XLA
     gather (models/gpt.py ``decode_step_slots_paged``)."""
     import jax.numpy as jnp
-    page_size = cache["kv"]["k"].shape[2]
-    view_len = page_tab.shape[1] * page_size
     # a model with recurrent state advances it here, row by row, and
     # leaves the rows that are not live exactly as they were
     stateful = ({"state": cache["state"], "live": live}
                 if "state" in cache else {})
+    start_col = cache["start_col"]
+    if use_kernel:
+        # the kernel walks the pages a row's columns lie on: a row that
+        # is not live is handed an empty run, and walks none
+        start_col = jnp.where(live, start_col, cache["write_col"] + 1)
     logits, *new = model.decode_step_slots_paged(
-        params, cache["kv"], tokens, page_tab, cache["write_col"],
-        paged_kv_valid(cache, view_len), cache["positions"],
+        params, cache["kv"], tokens, page_tab, start_col,
+        cache["write_col"], cache["positions"],
         adapters=adapters, adapter_rows=adapter_rows,
         use_kernel=use_kernel, **stateful)
     live = live.astype(jnp.int32)

@@ -49,8 +49,8 @@ resumes where the source stopped through the SAME three executables.
   lag at most one tick.
 * **Retirement**: EOS (when configured) or the request's token budget.
   A retired slot is immediately admissible; the slot's validity window
-  (``pages.paged_kv_valid``) and its own page-table row guarantee the
-  newcomer never attends the departed request's K/V.
+  (the cache's ``start_col``/``write_col``) and its own page-table row
+  guarantee the newcomer never attends the departed request's K/V.
 
 Exactness contract: with one request in flight the emitted tokens equal
 ``GPT.generate``'s greedy output token-for-token, and admission
@@ -308,6 +308,11 @@ class EngineStats:
     # (over ticks_completed: windows and decode steps a tick)
     prefill_windows_total: int = 0           # window dispatches, mid + last
     decode_steps_total: int = 0              # tick_steps per decode dispatch
+    # page-table entries those steps read, and the entries their tables
+    # hold (steps x slots x pages a slot): the page-walk kernel reads the
+    # pages a live slot's tokens lie on, the gather read all of them
+    decode_pages_walked_total: int = 0
+    decode_pages_table_total: int = 0
     admit_backpressure_total: int = 0        # admissions bounced + requeued
 
     @property
@@ -445,6 +450,8 @@ class SlotScheduler:
         # dispatch counters (stats(); written by the pump under _lock)
         self._prefill_windows = 0
         self._decode_steps = 0
+        self._decode_pages_walked = 0
+        self._decode_pages_table = 0
         self._admit_backpressure = 0
         self.metrics = metrics if metrics is not None else _NullMetrics()
         self.adapters = adapters
@@ -934,6 +941,8 @@ class SlotScheduler:
                 last_tick_duration_s=self._last_tick_s,
                 prefill_windows_total=self._prefill_windows,
                 decode_steps_total=self._decode_steps,
+                decode_pages_walked_total=self._decode_pages_walked,
+                decode_pages_table_total=self._decode_pages_table,
                 admit_backpressure_total=self._admit_backpressure)
             skipped = self._windows_skipped
         p = self.pages.stats()
@@ -1444,6 +1453,30 @@ class SlotScheduler:
                 self._slots[r] = None
                 self._page_tab[r] = 0
 
+    def _decode_walk(self, slots: List[Optional[Request]]) -> Tuple[int,
+                                                                    int]:
+        """``(walked, table)``: the page-table entries the decode steps of
+        one dispatch over ``slots`` read, and the entries their tables hold.
+        Host arithmetic on what the scheduler knows as it dispatches: a
+        slot's step reads the pages its consumed tokens and the one it is
+        fed lie on, for as many steps as its budget keeps it live (an EOS
+        mid-dispatch is not foreseen: those steps are counted); the gather
+        read takes every table whole."""
+        pps = self.max_len // self.page_size
+        table = self.tick_steps * self.num_slots * pps
+        if not self.use_paged_kernel:
+            return table, table
+        walked = 0
+        for req in slots:
+            if req is None:
+                continue
+            # tokens the device has emitted: the first comes with admission
+            fed = max(1, len(req.tokens) - req.resumed)
+            cols = _consumed(req) + 1
+            for j in range(min(self.tick_steps, req.remaining_budget - fed)):
+                walked += min(pps, -(-(cols + j) // self.page_size))
+        return walked, table
+
     def _decode_dispatch(self, active: int) -> tuple:
         """One K-step decode dispatch over the slots; what ``_decode_fetch``
         takes: ``(slots, emitted, mask, dispatch_s)``, the tokens still on
@@ -1454,10 +1487,14 @@ class SlotScheduler:
             # (admissions, retirements) between ticks never tear a
             # dispatch mid-read
             tab = self._page_tab.copy()
+            walked, table = self._decode_walk(slots)
+            self._decode_pages_walked += walked
+            self._decode_pages_table += table
         ad, ad_rows = self._adapter_args()
         with trace_lib.timed("serve.decode_dispatch",
-                             steps=self.tick_steps,
-                             active=active) as dispatch:
+                             steps=self.tick_steps, active=active,
+                             pages_walked=walked,
+                             pages_table=table) as dispatch:
             if trace_lib.active_tracer() is not None:
                 # tokens the live slots have in their caches as the
                 # dispatch starts: what a byte count of the step is made of

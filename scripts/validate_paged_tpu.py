@@ -42,7 +42,7 @@ def main():
     from distributed_tensorflow_tpu.ops.attention import (
         dot_product_attention, padding_mask)
     from distributed_tensorflow_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention, paged_window_attention)
+        page_walk, paged_decode_attention, paged_window_attention)
 
     from flash_timing import require_tpu
     if not require_tpu():
@@ -110,13 +110,17 @@ def main():
     for name, ckw in cases:
         pool = make_pool(L, NP, PG, ckw["kvh"], hd, ckw["quantized"])
         tab = jnp.asarray(rng.choice(NP, (S, P), replace=False), jnp.int32)
-        valid = jnp.asarray(rng.random((S, view)) < 0.7)
-        valid = valid.at[:, 0].set(True)
+        # a ragged column run a slot, the first from inside a page
+        lo = jnp.asarray(rng.integers(0, view // 2, S), jnp.int32)
+        hi = lo + 1 + jnp.asarray(rng.integers(0, view // 2, S), jnp.int32)
+        cols = jnp.arange(view)[None, :]
+        valid = (cols >= lo[:, None]) & (cols < hi[:, None])
         q = jnp.asarray(rng.standard_normal((S, 1, ckw["h"], hd)),
                         jnp.float32)
         try:
-            o_kern = jax.jit(lambda q, pool, tab, valid: paged_decode_attention(  # dtlint: disable=DT105
-                q, pool, 1, tab, valid, interpret=False))(q, pool, tab, valid)
+            o_kern = jax.jit(lambda q, pool, tab, lo, hi: paged_decode_attention(  # dtlint: disable=DT105
+                q, pool, 1, page_walk(pool, tab, lo, hi),
+                interpret=False))(q, pool, tab, lo, hi)
             k_g, v_g = gather(pool, 1, tab, PG)
             o_xla = dot_product_attention(q, k_g.astype(q.dtype),
                                           v_g.astype(q.dtype),
@@ -144,8 +148,10 @@ def main():
         row = jnp.asarray(rng.choice(NP, P, replace=False), jnp.int32)
         s, pos = 16, 9
         qw = jnp.asarray(rng.standard_normal((1, s, 8, hd)), jnp.float32)
-        o_kern = jax.jit(lambda q, pool, row, pos: paged_window_attention(  # dtlint: disable=DT105
-            q, pool, 0, row, pos, interpret=False))(qw, pool, row, pos)
+        o_kern = jax.jit(lambda q, pool, row, end: paged_window_attention(  # dtlint: disable=DT105
+            q, pool, 0, page_walk(pool, row, jnp.zeros_like(end), end),
+            interpret=False))(qw, pool, row[None, :],
+                              jnp.asarray([pos + s], jnp.int32))
         k_g, v_g = gather(pool, 0, row[None, :], PG)
         cols = jnp.arange(view)[None, None, None, :]
         rows = jnp.arange(s)[None, None, :, None]
@@ -201,13 +207,14 @@ def main():
         tab = jnp.asarray(
             rng.permutation(NP2 - 1)[:S2 * P2].reshape(S2, P2) + 1,
             jnp.int32)
-        valid = jnp.ones((S2, view_len), bool)
+        full = jnp.full((S2,), view_len, jnp.int32)
         q = jnp.asarray(rng.standard_normal((S2, 1, h2, hd)), jnp.float32)
 
         t_kern = time_read(
-            lambda qq: paged_decode_attention(qq, pool, 1, tab, valid,
-                                              interpret=False), q)
-        mask = padding_mask(valid)
+            lambda qq: paged_decode_attention(
+                qq, pool, 1, page_walk(pool, tab, jnp.zeros_like(full),
+                                       full), interpret=False), q)
+        mask = padding_mask(jnp.ones((S2, view_len), bool))
         t_gather = time_read(
             lambda qq: dot_product_attention(
                 qq, *gather(pool, 1, tab, PG), mask=mask), q)

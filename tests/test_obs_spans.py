@@ -267,6 +267,39 @@ def test_counters_and_request_counts_repeat_exactly(model_params, tracer):
     assert decode_steps % 2 == 0 and decode_steps >= 8
 
 
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "gather"])
+def test_decode_pages_walked_counts_what_the_steps_read(model_params,
+                                                        tracer, use_kernel):
+    """``decode_pages_walked_total`` over ``decode_pages_table_total``:
+    with the page-walk kernel a decode step reads the pages the slot's
+    tokens lie on (a 7-token prompt with a budget of 9 on 8-token pages:
+    eight steps, the first over 8 columns, the others into the second
+    page), the gather read takes both slots' whole tables; the spans, the
+    stats and the registry carry the same counts."""
+    engine = _engine(model_params, tick_steps=3, page_size=8,
+                     use_paged_kernel=use_kernel)
+    handle = engine.submit(_prompt(7, seed=3), 9)
+    engine.drain()
+    assert handle.status == "ok" and len(handle.tokens) == 9
+    stats = engine.stats()
+    table = 3 * 3 * 2 * 8            # dispatches x steps x slots x pages
+    assert stats.decode_steps_total == 9
+    assert stats.decode_pages_table_total == table
+    assert stats.decode_pages_walked_total == (1 + 7 * 2 if use_kernel
+                                               else table)
+    dispatches = [s.args for s in tracer.spans()
+                  if s.name == "serve.decode_dispatch"]
+    assert [a["pages_table"] for a in dispatches] == [table // 3] * 3
+    assert [a["pages_walked"] for a in dispatches] == (
+        [5, 6, 4] if use_kernel else [table // 3] * 3)
+    reg = engine.metrics.registry
+    assert reg.get("dttpu_serve_decode_pages_walked_total").value == \
+        stats.decode_pages_walked_total
+    assert reg.get("dttpu_serve_decode_pages_table_total").value == \
+        stats.decode_pages_table_total
+
+
 def test_a_decoding_tick_leaves_the_device_no_empty_queue(model_params,
                                                          tracer):
     """Beside a decoding request, a four-window prompt: each tick reads an

@@ -670,45 +670,113 @@ def test_auto_page_size_multiple_of():
     assert pages_lib.auto_page_size(7, multiple_of=8) == 7
 
 
-@pytest.mark.parametrize("kw", [
-    {},
-    {"position_embedding": "rope", "num_heads": 4, "hidden_size": 128,
-     "num_kv_heads": 2},
-    {"kv_cache_dtype": "int8"},
+_ROPE_GQA = {"position_embedding": "rope", "num_heads": 4,
+             "hidden_size": 128, "num_kv_heads": 2}
+# a table of 20 eight-token pages, which the kernel's 16 pages a grid step
+# do not divide: contexts of a token, a page, a page + 1, a step's pages
+# - 1 / +- 0 / + 1, and one that ends on the table's last column; the two
+# longest share their first 24 tokens (pages the radix tree maps into both
+# rows); three slots for seven requests, so rows retire onto the trash page
+# beside rows that decode on
+_LONG = dict(max_len=160, max_position=160, num_slots=3,
+             prompts=[1, 8, 9, 127, 128, 129, 150],
+             budgets=[6, 5, 5, 4, 4, 4, 10], shared=(5, 6, 24))
+_SHORT = dict(max_len=64, num_slots=2, prompts=[7, 5, 9, 3],
+              budgets=[9, 6, 4, 8])
+
+
+@pytest.mark.parametrize("kw,traffic", [
+    ({}, _SHORT),
+    (_ROPE_GQA, _SHORT),
+    ({"kv_cache_dtype": "int8"}, _SHORT),
     # K/V rows that are no multiple of 128 lanes, which the flat pool
     # layout exists for: 5 heads x 64 = 320; the same with grouped
     # queries and with int8 planes; GPT-2-XL's own 25 x 64 = 1600
-    {"num_heads": 5, "hidden_size": 320},
-    {"position_embedding": "rope", "num_heads": 10, "hidden_size": 640,
-     "num_kv_heads": 5},
-    {"num_heads": 5, "hidden_size": 320, "kv_cache_dtype": "int8"},
-    {"num_heads": 25, "hidden_size": 1600, "intermediate_size": 256},
+    ({"num_heads": 5, "hidden_size": 320}, _SHORT),
+    ({"position_embedding": "rope", "num_heads": 10, "hidden_size": 640,
+      "num_kv_heads": 5}, _SHORT),
+    ({"num_heads": 5, "hidden_size": 320, "kv_cache_dtype": "int8"},
+     _SHORT),
+    ({"num_heads": 25, "hidden_size": 1600, "intermediate_size": 256},
+     _SHORT),
+    ({}, _LONG),
+    (_ROPE_GQA, _LONG),
+    ({"kv_cache_dtype": "int8"}, _LONG),
 ], ids=["base", "rope_gqa", "int8", "w320", "w320_rope_gqa", "w320_int8",
-        "w1600_xl_heads"])
-def test_kernel_engine_matches_gather_and_generate(kw):
+        "w1600_xl_heads", "base_ragged_steps", "rope_gqa_ragged_steps",
+        "int8_ragged_steps"])
+def test_kernel_engine_matches_gather_and_generate(kw, traffic):
     """The kernel exactness contract, per config family: the fused
     page-walk read path produces token streams bit-identical to the
     XLA gather path and solo greedy generate (the kernel runs in
     interpret mode on the CPU mesh, so this executes the real kernel
     body)."""
-    model, params = _model_params(**kw)
-    prompts = [_prompt(7, seed=1), _prompt(5, seed=2), _prompt(9, seed=3),
-               _prompt(3, seed=4)]
-    budgets = [9, 6, 4, 8]
-    wants = [_generate_tokens(model, params, p, n, 64)
+    traffic = dict(traffic)
+    max_len, slots = traffic.pop("max_len"), traffic.pop("num_slots")
+    budgets = traffic.pop("budgets")
+    prompts = [_prompt(n, seed=1 + i)
+               for i, n in enumerate(traffic.pop("prompts"))]
+    if "shared" in traffic:
+        a, b, n = traffic.pop("shared")
+        prompts[b] = np.concatenate([prompts[a][:n], prompts[b][n:]])
+    model, params = _model_params(**kw, **traffic)
+    wants = [_generate_tokens(model, params, p, n, max_len)
              for p, n in zip(prompts, budgets)]
     outs = {}
     for label, ekw in (("kernel", dict(use_paged_kernel=True,
                                        page_size=8)),
                        ("gather", dict(use_paged_kernel=False,
                                        page_size=8))):
-        eng = serve.Engine(model, params, num_slots=2, max_len=64,
+        eng = serve.Engine(model, params, num_slots=slots, max_len=max_len,
                            prefill_chunk=4, tick_steps=3,
                            registry=metrics_lib.Registry(), **ekw)
         hs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
         eng.drain()
         outs[label] = [h.tokens for h in hs]
     assert outs["kernel"] == outs["gather"] == wants
+
+
+@pytest.mark.parametrize("kw", [{}, {"kv_cache_dtype": "int8"}],
+                         ids=["base", "int8"])
+def test_kernel_step_matches_gather_step_on_ragged_runs(kw):
+    """Below the engine: one decode step over slots whose runs start past
+    column 0 (mid-page, on a page boundary, a grid step's pages in), end
+    on the table's last column, hold one token, or are not live — the
+    kernel's logits are the gather path's for every live row, to float
+    round-off and to the argmax, and both advance the same state."""
+    model, params = _model_params(max_position=160, **kw)
+    pg, pps, slots = 8, 20, 6
+    rng = np.random.default_rng(5)
+    cache = pages_lib.init_paged_cache(model, slots, slots * pps + 1, pg)
+    cache["kv"] = {
+        name: (jnp.asarray(rng.integers(-127, 128, leaf.shape), leaf.dtype)
+               if leaf.dtype == jnp.int8 else
+               jnp.asarray(rng.uniform(0.01, 0.05, leaf.shape)
+                           if name.endswith("_scale")
+                           else rng.normal(size=leaf.shape), leaf.dtype))
+        for name, leaf in cache["kv"].items()}
+    tab = rng.permutation(slots * pps).reshape(slots, pps) + 1
+    tab[4] = 0                                  # retired: the trash page
+    tab[5, :3] = tab[1, :3]                     # a prefix shared with row 1
+    start = jnp.asarray([0, 5, 8, 131, 0, 0], jnp.int32)
+    write = jnp.asarray([159, 6, 140, 150, 17, 0], jnp.int32)
+    live = jnp.asarray([True, True, True, True, False, True])
+    cache = dict(cache, start_col=start, write_col=write,
+                 positions=write - start)
+    toks = jnp.asarray(rng.integers(0, 512, slots), jnp.int32)
+    outs = {use_kernel: _paged_step(model, use_kernel)(
+        params, cache, jnp.asarray(tab, jnp.int32), toks, live)
+        for use_kernel in (False, True)}
+    (want, want_cache), (got, got_cache) = outs[False], outs[True]
+    rows = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(got)[rows], np.asarray(want)[rows],
+                               atol=2e-4, rtol=2e-4)
+    assert (np.asarray(got.argmax(-1))[rows]
+            == np.asarray(want.argmax(-1))[rows]).all()
+    assert np.isfinite(np.asarray(got)).all()   # dead rows too
+    for name in ("start_col", "write_col", "positions"):
+        np.testing.assert_array_equal(np.asarray(got_cache[name]),
+                                      np.asarray(want_cache[name]))
 
 
 def test_prefix_hit_and_cow_exact_through_kernel():
@@ -785,7 +853,7 @@ def test_kernel_programs_touch_the_pool_by_scatter_and_kernel_only(
                 params, kv, jnp.zeros((slots,), jnp.int32),
                 jnp.ones((slots, pps), jnp.int32),
                 jnp.zeros((slots,), jnp.int32),
-                jnp.zeros((slots, pps * pg), bool),
+                jnp.zeros((slots,), jnp.int32),
                 jnp.zeros((slots,), jnp.int32), use_kernel=True))(kv)
     else:
         jaxpr = jax.make_jaxpr(

@@ -9,7 +9,8 @@ from distributed_tensorflow_tpu.ops.attention import (
 from distributed_tensorflow_tpu.ops.pallas import (
     MIN_PAGE_SIZE, flash_attention, make_flash_attention_fn,
     fused_adam_update, fused_layernorm, fused_rmsnorm,
-    page_size_kernel_ok, paged_decode_attention, paged_window_attention)
+    page_size_kernel_ok, page_walk, paged_decode_attention,
+    paged_window_attention)
 
 
 def _qkv(key, b=2, s=64, h=4, d=16, dtype=jnp.float32):
@@ -561,6 +562,28 @@ class TestPagedAttention:
             v = v.astype(jnp.float32) * gather(pool["v_scale"])
         return k, v
 
+    @staticmethod
+    def _runs(rng, S, view):
+        """A ragged, non-empty column run ``[lo, hi)`` a slot."""
+        lo = rng.integers(0, view // 2, S)
+        hi = lo + 1 + rng.integers(0, view - lo)
+        return jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32)
+
+    @staticmethod
+    def _run_valid(lo, hi, view):
+        cols = jnp.arange(view)[None, :]
+        return (cols >= lo[:, None]) & (cols < hi[:, None])
+
+    def _decode(self, q, pool, layer, tab, lo, hi):
+        return paged_decode_attention(q, pool, layer,
+                                      page_walk(pool, tab, lo, hi))
+
+    def _window(self, q, pool, layer, row, pos):
+        end = jnp.reshape(jnp.asarray(pos, jnp.int32) + q.shape[1], (1,))
+        return paged_window_attention(
+            q, pool, layer,
+            page_walk(pool, row[None, :], jnp.zeros_like(end), end))
+
     @pytest.mark.parametrize("kvh,h,quantized", [
         (4, 4, False), (2, 4, False), (2, 4, True)],
         ids=["base", "gqa", "int8"])
@@ -573,17 +596,97 @@ class TestPagedAttention:
                           if S * P <= self.NP else
                           rng.integers(0, self.NP, (S, P)), jnp.int32)
         view = P * self.PG
-        valid = jnp.asarray(rng.random((S, view)) < 0.6)
-        valid = valid.at[:, 0].set(True)     # no fully-masked rows
+        lo, hi = self._runs(rng, S, view)
+        valid = self._run_valid(lo, hi, view)
         q = jax.random.normal(jax.random.PRNGKey(8), (S, 1, h, self.HD))
         for layer in range(self.L):
-            got = paged_decode_attention(q, pool, layer, tab, valid)
+            got = self._decode(q, pool, layer, tab, lo, hi)
             k, v = self._dense_kv(pool, layer, tab)
             want = dot_product_attention(
                 q, k.astype(q.dtype), v.astype(q.dtype),
                 mask=padding_mask(valid))
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        atol=2e-6, rtol=2e-6)
+
+    # 16 pages a grid step at this page size (STEP_COLUMNS 128 / 8): a
+    # 40-entry table is two and a half steps
+    STEP, TABLE = 16, 40
+    RAGGED = {
+        # lengths of one batch, in columns (8 a page): a retired row
+        # first, then a token, a page, a page + 1, a step's pages - 1 / +-
+        # 0 / + 1, the whole table (which the step does not divide)
+        "from_col0": ([0, 0, 0, 0, 0, 0, 0, 0],
+                      [0, 1, 8, 9, 127, 128, 129, 320]),
+        # runs that start past column 0: mid-page, on a page boundary, a
+        # step's pages in, and one that ends where it starts
+        "start_col_gt0": ([5, 8, 128, 131, 77, 40, 311, 9],
+                          [6, 17, 129, 320, 77, 233, 320, 300]),
+    }
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["f32", "int8"])
+    @pytest.mark.parametrize("case", sorted(RAGGED))
+    def test_decode_ragged_lengths_match_gather(self, case, quantized):
+        """Rows of one batch that hold nothing, a token, a page, a page
+        + 1, a grid step's pages +- 1 and the whole table, from column 0
+        and from inside a page: each agrees with the gather read of its
+        own run, and a row that holds nothing reads zeros.  Two rows
+        share every page id (a shared prefix): a page fetched for one is
+        not confused with the other's."""
+        lo, hi = (jnp.asarray(x, jnp.int32) for x in self.RAGGED[case])
+        S, P, kvh, h = lo.size, self.TABLE, 2, 4
+        assert self.STEP * self.PG == 128 and P % self.STEP
+        pool = self._pool(jax.random.PRNGKey(31), kvh, quantized)
+        rng = np.random.default_rng(33)
+        tab = rng.integers(1, self.NP, (S, P))
+        tab[3] = tab[7]                      # a shared chain of pages
+        tab = jnp.asarray(tab, jnp.int32)
+        q = jax.random.normal(jax.random.PRNGKey(32), (S, 1, h, self.HD))
+        got = np.asarray(self._decode(q, pool, 1, tab, lo, hi))
+        k, v = self._dense_kv(pool, 1, tab)
+        want = np.asarray(dot_product_attention(
+            q, k.astype(q.dtype), v.astype(q.dtype),
+            mask=padding_mask(self._run_valid(lo, hi, P * self.PG))))
+        held = np.asarray(hi > lo)
+        assert not held.all() and held.sum() >= 6
+        np.testing.assert_allclose(got[held], want[held], atol=3e-6,
+                                   rtol=3e-6)
+        assert not got[~held].any()
+
+    def test_page_walk_fetches_held_pages_only(self):
+        """The walk has a grid step only where a row has pages to read,
+        row after row, and names no page a run does not hold: a buffer's
+        page is a held page of the step's row, the page that buffer is
+        next used for, or the one it was last used for — so the pipeline
+        copies exactly the held pages, each once."""
+        lo = jnp.asarray([0, 0, 131, 0], jnp.int32)
+        hi = jnp.asarray([129, 0, 200, 9], jnp.int32)
+        S, P, n = 4, self.TABLE, self.STEP
+        pool = self._pool(jax.random.PRNGKey(41), 2)
+        tab = jnp.arange(S * P, dtype=jnp.int32).reshape(S, P) + 100
+        walk = page_walk(pool, tab, lo, hi)
+        # 17 pages, none, pages 16..24, 2 pages: 2 + 0 + 1 + 1 steps
+        steps = int(walk.steps[0])
+        assert steps == 4 and walk.rows.shape == (S * -(-P // n),)
+        assert list(np.asarray(walk.rows[:steps])) == [0, 0, 2, 3]
+        assert list(np.asarray(walk.groups[:steps])) == [0, 1, 0, 0]
+        pages = np.asarray(walk.pages).reshape(-1, n)
+        held = [100 + r * P + np.arange(int(l) // self.PG,
+                                        -(-int(h) // self.PG))
+                for r, (l, h) in enumerate(zip(lo, hi)) if h > l]
+        assert set(pages.ravel()) == set(np.concatenate(held))
+        # a buffer's page changes only to a held page it has not held,
+        # and not at all past the last step
+        assert sum(len(set(pages[:, j])) for j in range(n)) \
+            == sum(len(x) for x in held)
+        for j in range(n):
+            col = pages[:, j]
+            assert len(set(col)) == 1 + np.count_nonzero(col[1:] != col[:-1])
+        assert (pages[steps - 1:] == pages[steps - 1]).all()
+        # row 0: its first sixteen pages, then the 17th alone beside
+        # what rows 2 and 3 will read
+        assert list(pages[0]) == list(100 + np.arange(16))
+        assert pages[1, 0] == 116 and pages[1, 1] == 100 + 2 * P + 17
 
     @pytest.mark.parametrize("kvh,h,hd,quantized", [
         (5, 5, 64, False),      # 320 lanes: two and a half lane tiles
@@ -597,18 +700,20 @@ class TestPagedAttention:
         """Rows that are no multiple of 128 lanes — the layout exists
         for them — through both variants: the decode step over several
         slots (few query rows: the row contracted whole) and a causal
-        window over one row (past 64 query rows: by 128-lane blocks)."""
+        window over one row (w1600's 200 query rows: by 128-lane
+        blocks)."""
         S, P, s, pos = 2, 3, 8, 5
         pool = self._pool(jax.random.PRNGKey(21), kvh, quantized, hd=hd)
         rng = np.random.default_rng(23)
         tab = jnp.asarray(rng.choice(self.NP, size=(S, P), replace=False),
                           jnp.int32)
         view = P * self.PG
-        valid = jnp.asarray(rng.random((S, view)) < 0.6).at[:, 0].set(True)
+        lo, hi = self._runs(rng, S, view)
+        valid = self._run_valid(lo, hi, view)
         q = jax.random.normal(jax.random.PRNGKey(22), (S, 1, h, hd))
         k, v = self._dense_kv(pool, 1, tab, hd=hd)
         np.testing.assert_allclose(
-            np.asarray(paged_decode_attention(q, pool, 1, tab, valid)),
+            np.asarray(self._decode(q, pool, 1, tab, lo, hi)),
             np.asarray(dot_product_attention(
                 q, k.astype(q.dtype), v.astype(q.dtype),
                 mask=padding_mask(valid))), atol=3e-6, rtol=3e-6)
@@ -616,21 +721,23 @@ class TestPagedAttention:
         cols = jnp.arange(view)[None, None, None, :]
         rows = jnp.arange(s)[None, None, :, None]
         np.testing.assert_allclose(
-            np.asarray(paged_window_attention(qw, pool, 1, tab[0], pos)),
+            np.asarray(self._window(qw, pool, 1, tab[0], pos)),
             np.asarray(dot_product_attention(
                 qw, k[:1].astype(q.dtype), v[:1].astype(q.dtype),
                 mask=jnp.where(cols <= pos + rows, 0.0, -1e9))),
             atol=3e-6, rtol=3e-6)
 
-    @pytest.mark.parametrize("pos", [0, 5, 17])
+    @pytest.mark.parametrize("pos", [0, 5, 17, 24])
     def test_window_matches_reference(self, pos):
+        """From column 0, from inside a page, across a page boundary,
+        and with its last row on the table's last column."""
         kvh = h = 4
         P, s = 4, 8
         pool = self._pool(jax.random.PRNGKey(3), kvh)
         row = jnp.asarray([5, 2, 9, 0], jnp.int32)
         view = P * self.PG
         q = jax.random.normal(jax.random.PRNGKey(4), (1, s, h, self.HD))
-        got = paged_window_attention(q, pool, 1, row, pos)
+        got = self._window(q, pool, 1, row, pos)
         k, v = self._dense_kv(pool, 1, row[None, :])
         cols = jnp.arange(view)[None, None, None, :]
         rows = jnp.arange(s)[None, None, :, None]
@@ -639,6 +746,31 @@ class TestPagedAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-6, rtol=2e-6)
 
+    @pytest.mark.parametrize("pos,quantized", [
+        (0, False), (124, False), (128, False), (312, False), (124, True)],
+        ids=["pos0", "across_steps", "step_boundary", "table_end",
+             "across_steps_int8"])
+    def test_window_over_several_steps_matches_reference(self, pos,
+                                                         quantized):
+        """A window on a table of two and a half grid steps: at column
+        0, across the first step's last page into the second step, from
+        the second step's first column, and ending on the table's last
+        column."""
+        kvh, h, s, P = 2, 4, 8, self.TABLE
+        pool = self._pool(jax.random.PRNGKey(51), kvh, quantized)
+        row = jnp.asarray(np.random.default_rng(53).integers(
+            0, self.NP, P), jnp.int32)
+        q = jax.random.normal(jax.random.PRNGKey(52), (1, s, h, self.HD))
+        got = self._window(q, pool, 1, row, pos)
+        k, v = self._dense_kv(pool, 1, row[None, :])
+        cols = jnp.arange(P * self.PG)[None, None, None, :]
+        rows = jnp.arange(s)[None, None, :, None]
+        want = dot_product_attention(
+            q, k.astype(q.dtype), v.astype(q.dtype),
+            mask=jnp.where(cols <= pos + rows, 0.0, -1e9))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=3e-6, rtol=3e-6)
+
     def test_gqa_window_matches_reference(self):
         kvh, h = 2, 4
         P, s, pos = 3, 6, 4
@@ -646,7 +778,7 @@ class TestPagedAttention:
         row = jnp.asarray([1, 7, 3], jnp.int32)
         view = P * self.PG
         q = jax.random.normal(jax.random.PRNGKey(6), (1, s, h, self.HD))
-        got = paged_window_attention(q, pool, 0, row, pos)
+        got = self._window(q, pool, 0, row, pos)
         k, v = self._dense_kv(pool, 0, row[None, :])
         cols = jnp.arange(view)[None, None, None, :]
         rows = jnp.arange(s)[None, None, :, None]
@@ -662,10 +794,9 @@ class TestPagedAttention:
         pool = self._pool(jax.random.PRNGKey(9), kvh)
         tab = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
         view = P * self.PG
-        rng = np.random.default_rng(13)
-        valid = jnp.asarray(rng.random((S, view)) < 0.7).at[:, 0].set(True)
+        lo, hi = self._runs(np.random.default_rng(13), S, view)
         q = jax.random.normal(jax.random.PRNGKey(10), (S, 1, h, self.HD))
-        base = np.asarray(paged_decode_attention(q, pool, 0, tab, valid))
+        base = np.asarray(self._decode(q, pool, 0, tab, lo, hi))
         trash = np.setdiff1d(np.arange(self.NP), np.asarray(tab))
         scrambled = dict(pool)
         for leaf in ("k", "v"):
@@ -673,8 +804,7 @@ class TestPagedAttention:
                 jax.random.normal(jax.random.PRNGKey(99),
                                   (self.L, trash.size, self.PG,
                                    kvh * self.HD)))
-        got = np.asarray(paged_decode_attention(q, scrambled, 0, tab,
-                                                valid))
+        got = np.asarray(self._decode(q, scrambled, 0, tab, lo, hi))
         assert np.array_equal(base, got)
 
     def test_under_jit_with_traced_layer(self):
@@ -683,19 +813,22 @@ class TestPagedAttention:
         S, P, kvh, h = 2, 2, 2, 4
         pool = self._pool(jax.random.PRNGKey(12), kvh)
         tab = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
-        valid = jnp.ones((S, P * self.PG), jnp.bool_)
+        lo = jnp.zeros((S,), jnp.int32)
+        hi = jnp.full((S,), P * self.PG, jnp.int32)
         q = jax.random.normal(jax.random.PRNGKey(13), (S, 1, h, self.HD))
 
         @jax.jit
-        def both_layers(q, pool, tab, valid):
+        def both_layers(q, pool, tab, lo, hi):
+            walk = page_walk(pool, tab, lo, hi)    # once for the scan
+
             def body(_, i):
-                return None, paged_decode_attention(q, pool, i, tab, valid)
+                return None, paged_decode_attention(q, pool, i, walk)
             _, outs = jax.lax.scan(body, None, jnp.arange(self.L))
             return outs
 
-        outs = both_layers(q, pool, tab, valid)
+        outs = both_layers(q, pool, tab, lo, hi)
         for layer in range(self.L):
-            direct = paged_decode_attention(q, pool, layer, tab, valid)
+            direct = self._decode(q, pool, layer, tab, lo, hi)
             np.testing.assert_allclose(np.asarray(outs[layer]),
                                        np.asarray(direct), atol=1e-6)
 
