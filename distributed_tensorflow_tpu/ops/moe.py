@@ -20,6 +20,12 @@ TPU-first design (GShard/Switch style, not a port):
 ``aux_loss`` (Switch load-balancing: E * Σ_e f_e·P_e, =1.0 at perfect
 balance) and ``router_z_loss`` must be added to the training loss by the
 caller to keep routing healthy.
+
+Beside that capacity-routed layer stands a DROPLESS one for serving
+(``route_top_k`` + ``apply_routed_experts``): a float32 softmax router with
+a choice bias and scaled, unrenormalised weights over FFN experts AND
+identity (zero-compute) experts, for a layer that is told which of the FFN
+experts it holds — one chip's share under expert parallelism.
 """
 from __future__ import annotations
 
@@ -32,7 +38,8 @@ from jax.sharding import PartitionSpec as P
 from . import activations as act_lib
 from . import initializers as init_lib
 
-__all__ = ["init_moe", "apply_moe", "moe_partition_rules"]
+__all__ = ["init_moe", "apply_moe", "moe_partition_rules", "route_top_k",
+           "apply_routed_experts"]
 
 
 def init_moe(key, d_model: int, d_ff: int, num_experts: int,
@@ -180,3 +187,100 @@ def apply_moe(params: Dict[str, Any], x: jnp.ndarray, *, k: int = 2,
         "dropped_fraction": 1.0 - jnp.sum(dispatch) / (k * t),
     }
     return y.reshape(*lead, d), metrics
+
+
+# ------------------------------------------------- dropless routed layer
+
+def route_top_k(router_kernel, choice_bias, x, *, top_k: int,
+                scale: float):
+    """The router's rules, in float32 whatever ``x``'s type: ``p =
+    softmax(x W_r)`` over every expert (FFN and identity alike); the
+    ``top_k`` largest of ``p + choice_bias`` are CHOSEN; the weights are the
+    UNBIASED ``p`` of the chosen, times ``scale``, not renormalised.
+    ``x`` [T, d] -> (choice [T, top_k] int32, weight [T, top_k] float32)."""
+    f32 = jnp.float32
+    logits = x.astype(f32) @ router_kernel.astype(f32)
+    p = jax.nn.softmax(logits, axis=-1)
+    _, choice = jax.lax.top_k(p + choice_bias.astype(f32), top_k)
+    weight = jnp.take_along_axis(p, choice, axis=-1) * scale
+    return choice.astype(jnp.int32), weight
+
+
+def apply_routed_experts(params: Dict[str, Any], x: jnp.ndarray, *,
+                         top_k: int, scale: float, num_ffn_experts: int,
+                         expert_offset: int = 0,
+                         valid: Optional[jnp.ndarray] = None
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A dropless top-k layer of SwiGLU FFN experts and identity experts,
+    for the share of the FFN experts that lives here.
+
+    ``params``: ``router/kernel`` [d, E] and ``router/choice_bias`` [E] over
+    ALL ``E = num_ffn_experts + identity experts`` (the published router,
+    whatever is held); ``experts/w_in/kernel`` [H, d, 2 f] (gate and up side
+    by side) and ``experts/w_out/kernel`` [H, f, d] for the ``H`` FFN experts
+    held: the published indices ``expert_offset .. expert_offset + H - 1``.
+    Indices from ``num_ffn_experts`` up are identity experts: a pick adds
+    ``w * x``, needs no weights and no exchange, and is computed here for
+    every token that lives here.  A pick on an FFN expert that is NOT held
+    adds nothing: what the absent experts would give is another chip's part
+    of the sum (with all of them held, the layer is the whole model's).
+
+    Dropless and exact for any assignment: there is no capacity and no
+    ``[T, E, C]`` tensor.  The held experts go one at a time: one that
+    received a token runs its FFN on the block's ``T`` rows with its
+    per-token weight (zero where it was not picked), its matrices sliced out
+    of the bank inside the branch (no copy of a bank); ``lax.cond`` skips
+    one nobody picked, so a decode step or a prefill window of a few dozen
+    tokens READS only the experts it touches and its time goes with the
+    routing.  Cost: at most ``T x H`` FFN rows, linear in tokens; a block of
+    thousands of tokens, where each token needs ``top_k * H / E`` experts,
+    is better served by a grouped (sorted) matmul — the perf work this
+    layer's counts size.
+
+    ``x`` [T, d]; ``valid`` [T] bool marks the real rows (pad rows of a
+    prefill window, slots that are not live): the others add nothing and
+    are not counted.  Returns ``(y [T, d] in x's type, counts [H + 2]
+    int32)``: tokens received by each held expert, picks on identity
+    experts, picks on absent FFN experts.
+    """
+    f32 = jnp.float32
+    bank_in = params["experts"]["w_in"]["kernel"]
+    bank_out = params["experts"]["w_out"]["kernel"]
+    held = bank_in.shape[0]
+    with jax.named_scope("router"):
+        choice, weight = route_top_k(
+            params["router"]["kernel"], params["router"]["choice_bias"], x,
+            top_k=top_k, scale=scale)
+        real = (jnp.ones(x.shape[:1], bool) if valid is None
+                else valid)[:, None]
+        weight = jnp.where(real, weight, 0.0)
+        local = choice - expert_offset                        # [T, k]
+        on_held = (local >= 0) & (local < held) & real
+        on_identity = (choice >= num_ffn_experts) & real
+        # [T, k, H] one-hot of the held picks: H is the share, not E
+        hit = (local[..., None] == jnp.arange(held)) & on_held[..., None]
+        held_weight = jnp.sum(jnp.where(hit, weight[..., None], 0.0), axis=1)
+        received = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)        # [H]
+        n_identity = jnp.sum(on_identity, dtype=jnp.int32)
+        n_absent = (top_k * jnp.sum(real, dtype=jnp.int32)
+                    - jnp.sum(received) - n_identity)
+        counts = jnp.concatenate([received,
+                                  jnp.stack([n_identity, n_absent])])
+    with jax.named_scope("identity_experts"):
+        y = (jnp.sum(jnp.where(on_identity, weight, 0.0), axis=-1,
+                     keepdims=True) * x.astype(f32))
+
+    def one_expert(e, y):
+        def run(y):
+            w_in = jax.lax.dynamic_index_in_dim(bank_in, e, keepdims=False)
+            w_out = jax.lax.dynamic_index_in_dim(bank_out, e, keepdims=False)
+            gate, up = jnp.split(x @ w_in.astype(x.dtype), 2, axis=-1)
+            out = (jax.nn.silu(gate) * up) @ w_out.astype(x.dtype)
+            w = jax.lax.dynamic_index_in_dim(held_weight, e, axis=1)
+            return y + w * out.astype(f32)
+
+        return jax.lax.cond(received[e] > 0, run, lambda y: y, y)
+
+    with jax.named_scope("experts"):
+        y = jax.lax.fori_loop(0, held, one_expert, y)
+    return y.astype(x.dtype), counts

@@ -191,6 +191,10 @@ class ServeMetrics:
             [self.decode_pages_walked, "decode_pages_walked_total", 0],
             [self.decode_pages_table, "decode_pages_table_total", 0],
             [self.admit_backpressure, "admit_backpressure_total", 0]]
+        # an expert layer's router statistics (scheduler.py
+        # ``_absorb_counters``): created at first sight of a pick, so a
+        # model without experts exposes none of these series
+        self._router: dict = {}
         # per-tenant series, created lazily at first sight of a tenant
         # (cardinality = the tenant set, which admission policy bounds)
         self._tenant_tokens: dict = {}
@@ -214,6 +218,37 @@ class ServeMetrics:
                 "In-flight requests (queued+prefilling+active), "
                 "by tenant.", labels={"tenant": tenant})
         return g
+
+    _ROUTER_SERIES = (
+        ("router_picks_total",
+         "Router picks made for real tokens (top-k a token and expert "
+         "layer), prefill and decode."),
+        ("router_picks_identity_total",
+         "Router picks that fell on identity (zero-compute) experts."),
+        ("router_picks_held_total",
+         "Router picks that fell on FFN experts this engine holds; the "
+         "rest fell on experts held elsewhere and add nothing here."))
+
+    def _router_counters(self, stats: EngineStats) -> None:
+        """The router's counters by delta, and the tokens each held expert
+        received as ``dttpu_serve_expert_tokens_total{layer, expert}``
+        (cardinality: expert layers x experts held, fixed at build)."""
+        series = [(field, field, help_text, None, getattr(stats, field))
+                  for field, help_text in self._ROUTER_SERIES]
+        series += [((layer, expert), "expert_tokens_total",
+                    "Tokens a held FFN expert received, by expert layer "
+                    "and held expert.",
+                    {"layer": str(layer), "expert": str(expert)}, n)
+                   for layer, row in enumerate(stats.expert_tokens_total)
+                   for expert, n in enumerate(row)]
+        for key, name, help_text, labels, now in series:
+            entry = self._router.get(key)
+            if entry is None:
+                entry = self._router[key] = [self.registry.counter(
+                    f"dttpu_serve_{name}", help_text, labels=labels), 0]
+            if now > entry[1]:
+                entry[0].inc(now - entry[1])
+                entry[1] = now
 
     # -- scheduler hooks --------------------------------------------------
 
@@ -264,6 +299,8 @@ class ServeMetrics:
             if now > last:
                 counter.inc(now - last)
                 entry[2] = now
+        if stats.router_picks_total:
+            self._router_counters(stats)
         for tenant, n in stats.inflight_per_tenant.items():
             self._tenant_gauge(tenant).set(n)
         for tenant, g in self._tenant_inflight.items():

@@ -176,7 +176,14 @@ def init_paged_cache(model, num_slots: int, num_pages: int,
     all slots start retired (an empty window at column 0) — and, for
     a model with recurrent state, one ``[layers, num_slots, ...]`` block
     per state leaf under ``"state"`` (absent otherwise, so a K/V-only
-    model's programs are what they were)."""
+    model's programs are what they were).  The per-token leaves need not
+    be keys and values: the pool has whatever leaves the spec names (a
+    latent and a shared rotary key, models/longcat_flash.py), and only a
+    model whose leaves are ``k`` / ``v`` rows can say ``paged_kernel_ok``.
+    A model that counts on the device (``spec["counters"]`` {leaf: (shape,
+    dtype)}: an expert layer's router statistics) gets those leaves, zero,
+    under ``"counters"``: its programs add to them and the scheduler reads
+    and clears them with the fetches it makes anyway."""
     import jax.numpy as jnp
     spec = model.paged_cache_spec()
     cache = {"kv": {name: jnp.zeros(
@@ -187,6 +194,9 @@ def init_paged_cache(model, num_slots: int, num_pages: int,
              "positions": jnp.zeros((num_slots,), jnp.int32)}
     if spec["state"]:
         cache["state"] = init_state_snapshots(model, num_slots)
+    if spec.get("counters"):
+        cache["counters"] = {name: jnp.zeros(shape, dtype) for name,
+                             (shape, dtype) in spec["counters"].items()}
     return cache
 
 
@@ -247,6 +257,10 @@ def decode_paged_step(model, params, cache, page_tab, tokens, live,
     # leaves the rows that are not live exactly as they were
     stateful = ({"state": cache["state"], "live": live}
                 if "state" in cache else {})
+    # a model that counts on the device adds this step's live rows to its
+    # counters (third of what it returns, after kv and any state)
+    if "counters" in cache:
+        stateful = dict(stateful, counters=cache["counters"], live=live)
     start_col = cache["start_col"]
     if use_kernel:
         # the kernel walks the pages a row's columns lie on: a row that
@@ -259,7 +273,7 @@ def decode_paged_step(model, params, cache, page_tab, tokens, live,
         use_kernel=use_kernel, **stateful)
     live = live.astype(jnp.int32)
     return logits, dict(
-        zip(("kv", "state"), new),
+        zip([n for n in ("kv", "state", "counters") if n in cache], new),
         start_col=cache["start_col"],
         write_col=cache["write_col"] + live,
         positions=cache["positions"] + live)

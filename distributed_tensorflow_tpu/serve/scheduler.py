@@ -314,6 +314,15 @@ class EngineStats:
     decode_pages_walked_total: int = 0
     decode_pages_table_total: int = 0
     admit_backpressure_total: int = 0        # admissions bounced + requeued
+    # expert-layer models only (the device's own counts, read with the
+    # tick's fetches: models/longcat_flash.py ``counters``): the router's
+    # picks for real tokens, those that fell on identity (zero-compute)
+    # experts and on experts held here (the rest fell on absent ones), and
+    # the tokens each held expert received, by expert layer
+    router_picks_total: int = 0
+    router_picks_identity_total: int = 0
+    router_picks_held_total: int = 0
+    expert_tokens_total: Tuple[Tuple[int, ...], ...] = ()
 
     @property
     def inflight(self) -> int:
@@ -463,6 +472,18 @@ class SlotScheduler:
         # in the slot's row) and its prefix hits resume from state
         # snapshots (serve/pages.py).
         self._stateful = bool(model.paged_cache_spec()["state"])
+        # a model that counts on the device (``paged_cache_spec()
+        # ["counters"]``: an expert layer's router statistics).  Its
+        # programs add to counters in the cache; the admitting window and
+        # the decode program clear them and append what they held, flat,
+        # to the int32 array of tokens the host reads anyway (no fetch or
+        # transfer of its own).  ``_counter_shapes``: the leaves in the
+        # order they are appended; host totals: [expert layers, held + 2]
+        self._counter_shapes = sorted(
+            (name, tuple(shape)) for name, (shape, _) in
+            (model.paged_cache_spec().get("counters") or {}).items())
+        self._counted = bool(self._counter_shapes)
+        self._router_counts = None
         if not getattr(model, "paged_kernel_ok", True):
             if use_paged_kernel is True:
                 raise ValueError(
@@ -631,24 +652,40 @@ class SlotScheduler:
         # build REPLACES the gather build (same 3 programs, DT405-pinned)
         use_kernel = self.use_paged_kernel
 
+        def hand_out_counters(cache, read):
+            """``(cache with its counters cleared, read with the counters
+            appended)``: a counting model's statistics leave the device
+            flat behind the int32 tokens ``read`` that the host fetches
+            anyway (``_split_read`` parts them); a model that has none
+            returns what it always did."""
+            if "counters" not in cache:
+                return cache, read
+            counters = cache["counters"]
+            read = jnp.concatenate([read.reshape(-1)] + [
+                counters[name].reshape(-1) for name in sorted(counters)])
+            return dict(cache, counters=jax.tree.map(
+                jnp.zeros_like, counters)), read
+
         def paged_window(params, cache, window, page_row, pos, head,
                          ad, ad_row, slot, valid):
             """One prefill window through the model -> (logits, cache).
-            ``slot``/``valid`` are None for a K/V-only model (empty
-            pytrees: its programs are what they always were); a model
-            with recurrent state reads the slot's state row, advances it
-            over the window's ``valid`` real tokens and writes it back."""
-            if slot is None:
-                logits, kv = model.decode_window_paged(
-                    params, cache["kv"], window, page_row, pos,
-                    head=head, adapters=ad, adapter_rows=ad_row,
-                    use_kernel=use_kernel)
-                return logits, dict(cache, kv=kv)
-            logits, kv, state = model.decode_window_paged(
+            What the model is handed beyond the pool goes by what the
+            cache holds, as in ``pages.decode_paged_step``: nothing for a
+            K/V-only model (its programs are what they always were); a
+            model with recurrent state reads the slot's state row,
+            advances it over the window's ``valid`` real tokens and writes
+            it back; one that counts adds its ``valid`` tokens' counts."""
+            held = [n for n in ("state", "counters") if n in cache]
+            extra = {n: cache[n] for n in held}
+            if held:
+                extra["valid"] = valid
+            if "state" in cache:
+                extra["slot"] = slot
+            logits, *new = model.decode_window_paged(
                 params, cache["kv"], window, page_row, pos, head=head,
-                state=cache["state"], slot=slot, valid=valid,
-                adapters=ad, adapter_rows=ad_row, use_kernel=use_kernel)
-            return logits, dict(cache, kv=kv, state=state)
+                adapters=ad, adapter_rows=ad_row, use_kernel=use_kernel,
+                **extra)
+            return logits, dict(cache, **dict(zip(["kv"] + held, new)))
 
         def paged_win_mid(params, cache, window, page_row, pos, ad,
                           ad_row, slot=None, valid=None):
@@ -669,7 +706,7 @@ class SlotScheduler:
             handed to the next tick)."""
             logits, cache = paged_window(
                 params, cache, window, page_row, pos, "all", ad, ad_row,
-                None if valid is None else slot_idx, valid)
+                slot_idx, valid)
             tok, key, tokens, finished, remaining = first_token(
                 logits, last_idx, key, tokens, finished, remaining,
                 slot_idx, budget)
@@ -679,6 +716,7 @@ class SlotScheduler:
                     jnp.int32(0)),
                 write_col=cache["write_col"].at[slot_idx].set(length),
                 positions=cache["positions"].at[slot_idx].set(length))
+            cache, tok = hand_out_counters(cache, tok)
             return tok, cache, tokens, finished, remaining, key
 
         def copy_page(kv, src, dst):
@@ -719,7 +757,8 @@ class SlotScheduler:
             carry, (em, mask) = jax.lax.scan(
                 one, (cache, tokens, finished, remaining, key), None,
                 length=tick_steps)
-            return carry, em, mask
+            cache, em = hand_out_counters(carry[0], em)
+            return (cache,) + carry[1:], em, mask
 
         def wire_gather(kv, idx):
             # page-wire device read (fleet/pagewire.py): gather the
@@ -782,16 +821,16 @@ class SlotScheduler:
         pps = self.max_len // self.page_size
         prow = jax.ShapeDtypeStruct((pps,), np.int32)
         tab = jax.ShapeDtypeStruct((self.num_slots, pps), np.int32)
-        st = (i32,) if self._stateful else ()
+        told = self._window_told(i32, i32)
         targets = [
             graph_lib.Target(
                 "prefill_window", self._win_mid,
-                (params, cache, win, prow, i32, ad, row1) + st + st,
+                (params, cache, win, prow, i32, ad, row1) + told,
                 hbm_budget=hbm_budget),
             graph_lib.Target(
                 "admit", self._last_admit,
                 (params, cache, win, prow, i32, i32, key, toks,
-                 fin, rem, i32, i32, i32, ad, row1) + st,
+                 fin, rem, i32, i32, i32, ad, row1) + told[1:],
                 hbm_budget=hbm_budget),
             graph_lib.Target(
                 "decode_tick", self._tick,
@@ -944,6 +983,15 @@ class SlotScheduler:
                 decode_pages_walked_total=self._decode_pages_walked,
                 decode_pages_table_total=self._decode_pages_table,
                 admit_backpressure_total=self._admit_backpressure)
+            if self._router_counts is not None:
+                held = self._router_counts[:, :-2]
+                base.update(
+                    router_picks_total=int(self._router_counts.sum()),
+                    router_picks_identity_total=int(
+                        self._router_counts[:, -2].sum()),
+                    router_picks_held_total=int(held.sum()),
+                    expert_tokens_total=tuple(
+                        tuple(int(n) for n in row) for row in held))
             skipped = self._windows_skipped
         p = self.pages.stats()
         base.update(
@@ -1328,10 +1376,8 @@ class SlotScheduler:
         ad, ad_row = self._adapter_args(req)
         last = i == len(st.windows) - 1
         pos, real, snap_depth = st.plan[i]
-        # a recurrent-state model's windows also name the slot whose
-        # state they advance and how many of their tokens are real
-        state_args = ((np.int32(st.slot), np.int32(real))
-                      if self._stateful else ())
+        state_args = self._window_told(
+            None if st.slot is None else np.int32(st.slot), np.int32(real))
         ctx = req.context if req.context is not None else req.prompt
         if not last:
             with trace_lib.span("serve.prefill_dispatch",
@@ -1400,8 +1446,11 @@ class SlotScheduler:
         req = st.req
         ctx = req.context if req.context is not None else req.prompt
         with trace_lib.span("serve.first_token_read",
-                            trace_id=req.trace_id):
-            first = int(tok)      # returns as the admitting window ends
+                            trace_id=req.trace_id) as read:
+            # returns as the admitting window ends
+            tok, counters = self._split_read(tok, ())
+            first = int(tok)
+            self._absorb_counters(counters, read)
         req.first_token_time = time.perf_counter()
         req._windows += 1
         with trace_lib.span("serve.register"):
@@ -1506,6 +1555,55 @@ class SlotScheduler:
                 self._finished, self._remaining, self._key, ad, ad_rows)
         return slots, em, mask, self._finished, dispatch.duration_s
 
+    def _window_told(self, slot, real) -> tuple:
+        """What a prefill window is told beyond its tokens, ``(slot,
+        real)``: a recurrent-state model's windows name the slot whose
+        state they advance and how many of their tokens are real; one
+        that only counts has no slot; a K/V-only model is told nothing."""
+        if self._stateful:
+            return slot, real
+        return (None, real) if self._counted else ()
+
+    def _split_read(self, read, shape) -> tuple:
+        """A program's tokens as a host array of ``shape`` (the host sync)
+        and, for a counting model, the device's counters that rode behind
+        them in the same array (``hand_out_counters``), by leaf."""
+        read = np.asarray(read)
+        if not self._counted:
+            return read, None
+        tokens, rest = np.split(read, [int(np.prod(shape, dtype=int))])
+        counters = {}
+        for name, leaf in self._counter_shapes:
+            head, rest = np.split(rest, [int(np.prod(leaf, dtype=int))])
+            counters[name] = head.reshape(leaf)
+        return tokens.reshape(shape), counters
+
+    def _absorb_counters(self, counters, span) -> None:
+        """Add what a program handed out of the device's counters (by
+        leaf, host arrays; None for a model that has none) to the host's
+        totals, and say on ``span`` — the read of that program's tokens,
+        which brought them — what was added: the picks by kind, the tokens
+        each held expert received ``[expert layer][held expert]``, and,
+        where decode steps ran, ``experts_touched`` (held experts that
+        received a token, over expert layers and steps) beside
+        ``experts_held_steps`` (how many they could have been)."""
+        if counters is None:
+            return
+        router = counters["router"].astype(np.int64)
+        touched, could = (int(n) for n in counters["touched"])
+        with self._lock:
+            if self._router_counts is None:
+                self._router_counts = np.zeros_like(router)
+            self._router_counts += router
+        if trace_lib.active_tracer() is None:
+            return
+        span.set(router_picks=int(router.sum()),
+                 router_picks_identity=int(router[:, -2].sum()),
+                 router_picks_held=int(router[:, :-2].sum()),
+                 expert_tokens=router[:, :-2].tolist())
+        if could:
+            span.set(experts_touched=touched, experts_held_steps=could)
+
     def _decode_fetch(self, slots, em, mask, finished,
                       dispatch_s: float) -> tuple:
         """The host sync on a decode dispatch: ``(slots, em, mask, fin,
@@ -1513,11 +1611,14 @@ class SlotScheduler:
         host-sync wall — the two spans' own durations — identical for
         every live slot in the batch."""
         with trace_lib.timed("serve.decode_fetch") as fetch:
-            em = np.asarray(em)                  # [K, S]: the host sync
+            # [K, S]: the host sync
+            em, counters = self._split_read(
+                em, (self.tick_steps, self.num_slots))
             mask = np.asarray(mask)
             fin = np.asarray(finished)
             # (slot, step) pairs that were live: what the steps computed
             fetch.set(live_steps=int(mask.sum()))
+            self._absorb_counters(counters, fetch)
         with self._lock:
             self._decode_steps += self.tick_steps
         return slots, em, mask, fin, dispatch_s + fetch.duration_s

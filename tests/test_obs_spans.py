@@ -544,3 +544,122 @@ def test_state_snapshot_spans_and_counters_of_a_recurrent_state_model(tracer):
     assert registry.get("dttpu_serve_state_restores_total").value == 1
     assert registry.get("dttpu_serve_state_snapshot_bytes").value == \
         2 * state_bytes
+
+
+def test_router_counts_ride_the_fetches_of_an_expert_layer_model(tracer):
+    """A model with an expert layer: the device's router counts come out
+    with the tokens of the admitting window (``serve.first_token_read``)
+    and of the decode program (``serve.decode_fetch``) as span arguments,
+    add up to ``Engine.stats()``'s counters and render in the registry; the
+    model's scopes name its pieces.  GPT-2 has none of it."""
+    from distributed_tensorflow_tpu.models.longcat_flash import (
+        longcat_flash_tiny)
+    from distributed_tensorflow_tpu.obs import metrics as metrics_lib
+
+    model = longcat_flash_tiny(experts_held=4, expert_offset=2)
+    c = model.config
+    params = model.init(jax.random.PRNGKey(0))
+    registry = metrics_lib.Registry()
+    engine = serve.Engine(model, params, num_slots=2, max_len=128,
+                          prefill_chunk=8, tick_steps=2, registry=registry)
+    handles = [engine.submit(_prompt(21, seed=3, vocab=128), 6),
+               engine.submit(_prompt(9, seed=4, vocab=128), 5)]
+    engine.drain()
+    assert all(h.status == "ok" for h in handles)
+
+    by_name = {}
+    for s in tracer.spans():
+        by_name.setdefault(s.name, []).append(s)
+    reads = by_name["serve.first_token_read"] + by_name["serve.decode_fetch"]
+    keys = {"router_picks", "router_picks_identity", "router_picks_held",
+            "expert_tokens"}
+    assert all(keys <= set(s.args) for s in reads)
+    stats = engine.stats()
+    consumed = 21 + 9 + sum(len(h.tokens) - 1 for h in handles)
+    assert stats.router_picks_total == consumed * c.num_layers * c.moe_topk
+    for field in ("router_picks", "router_picks_identity",
+                  "router_picks_held"):
+        assert sum(s.args[field] for s in reads) == \
+            getattr(stats, field + "_total")
+    summed = np.sum([s.args["expert_tokens"] for s in reads], axis=0)
+    assert summed.shape == (c.num_layers, c.experts_held)
+    assert summed.tolist() == [list(r) for r in stats.expert_tokens_total]
+    assert stats.router_picks_held_total == int(summed.sum()) > 0
+    # held experts x expert layers x steps, and those that got a token
+    for fetch in by_name["serve.decode_fetch"]:
+        assert fetch.args["experts_held_steps"] == 2 * c.num_layers * 4
+        assert 0 <= fetch.args["experts_touched"] <= \
+            min(fetch.args["experts_held_steps"],
+                fetch.args["live_steps"] * c.num_layers * c.moe_topk)
+    assert not any("experts_touched" in s.args
+                   for s in by_name["serve.first_token_read"])
+    for name in ("router_picks", "router_picks_identity",
+                 "router_picks_held"):
+        assert registry.get(f"dttpu_serve_{name}_total").value == \
+            getattr(stats, name + "_total")
+    assert registry.get("dttpu_serve_expert_tokens_total",
+                        labels={"layer": "1", "expert": "3"}).value == \
+        stats.expert_tokens_total[1][3]
+
+    # the model's scopes, in the decode program's lowered text
+    sched = engine.scheduler
+    tick = next(t for t in sched.graph_targets() if t.name == "decode_tick")
+    text = tick.fn.lower(*tick.args).as_text(debug_info=True)
+    for scope in ("mla_q", "mla_kv", "mla_attend", "router", "experts",
+                  "identity_experts", "shortcut_join"):
+        assert scope in text, scope
+
+
+def test_a_model_without_experts_reports_no_router_counts(model_params,
+                                                          tracer):
+    registry = metrics_lib.Registry()
+    model, params = model_params
+    engine = serve.Engine(model, params, num_slots=2, max_len=64,
+                          prefill_chunk=4, tick_steps=2, registry=registry)
+    _schedule(engine)
+    stats = engine.stats()
+    assert (stats.router_picks_total, stats.router_picks_identity_total,
+            stats.router_picks_held_total, stats.expert_tokens_total) == \
+        (0, 0, 0, ())
+    for s in tracer.spans():
+        assert not {"router_picks", "experts_touched", "expert_tokens"} \
+            & set(s.args), s.name
+    assert registry.get("dttpu_serve_router_picks_total") is None
+    assert "counters" not in engine.scheduler._cache
+
+
+def test_router_counts_leave_the_device_inside_the_token_arrays(
+        model_params):
+    """No fetch, transfer or output of their own: a counting model's admit
+    and decode programs return what a K/V-only model's return, the int32
+    array of tokens longer by the counters (``SlotScheduler._split_read``
+    parts them on the host).  GPT-2's arrays are what they were."""
+    from distributed_tensorflow_tpu.models.longcat_flash import (
+        longcat_flash_tiny)
+
+    def outputs(model, params):
+        engine = serve.Engine(model, params, num_slots=2, max_len=128,
+                              prefill_chunk=8, tick_steps=2)
+        targets = {t.name: t for t in engine.scheduler.graph_targets()}
+        admit = jax.eval_shape(targets["admit"].fn, *targets["admit"].args)
+        tick = jax.eval_shape(targets["decode_tick"].fn,
+                              *targets["decode_tick"].args)
+        return engine.scheduler, admit, tick
+
+    model = longcat_flash_tiny(experts_held=4, expert_offset=2)
+    sched, admit, tick = outputs(model, model.init(jax.random.PRNGKey(0)))
+    counters = (model.config.num_layers * (4 + 2)) + 2
+    assert sum(int(np.prod(shape)) for _, shape in
+               sched._counter_shapes) == counters
+    _, gpt_admit, gpt_tick = outputs(*model_params)
+    assert len(admit) == len(gpt_admit) and len(tick) == len(gpt_tick) == 3
+    assert (gpt_admit[0].shape, gpt_tick[1].shape) == ((), (2, 2))
+    assert (admit[0].shape, tick[1].shape) == ((1 + counters,),
+                                               (2 * 2 + counters,))
+    assert admit[0].dtype == tick[1].dtype == np.int32
+    read = np.arange(4 + counters, dtype=np.int32)
+    tokens, parted = sched._split_read(read, (2, 2))
+    assert tokens.tolist() == [[0, 1], [2, 3]]
+    assert parted["router"].shape == (model.config.num_layers, 6)
+    assert parted["router"][0, 0] == 4 and parted["touched"].tolist() == \
+        [4 + counters - 2, 4 + counters - 1]

@@ -420,3 +420,76 @@ def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
                 < 15.75e9), (target.name, memory)
         state_gb = max(state_gb, memory.alias_size_in_bytes / 1e9)
     assert 6.5 < state_gb < 6.7
+
+
+def test_latent_attention_expert_programs_fit_a_v5e_at_published_width(topo):
+    """The three hot programs of the decoder of shortcut-connected expert
+    layers over latent attention, at the benchmark's published widths and
+    deployment (64 slots x 4096 tokens, 16 of 512 experts a layer), compiled
+    for the described chip from shapes alone.  The pool's one leaf is 640
+    lanes wide — a latent, the shared rotary key and zeros to a whole lane
+    tile: with a 64-lane or a 576-lane leaf the compiler gave the pool a
+    layout of its own and every program re-laid the WHOLE pool out on entry,
+    between sublayers and on exit (2.7 GB of temporaries in the rehearsal,
+    8 % of the device's time on the chip, PR 36); this is the test that sees
+    it.  An expert's weights are sliced out of their bank inside the branch
+    that runs it, fused into the matmul: no program holds a copy of an
+    expert or of a bank.  Router statistics leave with the programs'
+    outputs: no host callback."""
+    import json
+    from chip_smoke import pool_moves
+    from distributed_tensorflow_tpu.models.longcat_flash import (
+        LongcatFlash, LongcatFlashConfig)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "longcat-flash-chat.json")) as f:
+        c = json.load(f)
+    model = LongcatFlash(LongcatFlashConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        ffn_hidden_size=c["ffn_hidden_size"],
+        expert_ffn_hidden_size=c["expert_ffn_hidden_size"],
+        num_layers=c["num_layers"],
+        num_attention_heads=c["num_attention_heads"],
+        q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        n_routed_experts_published=c["cut"]["published"]["n_routed_experts"],
+        experts_held=c["n_routed_experts"],
+        zero_expert_num=c["zero_expert_num"], moe_topk=c["moe_topk"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        rope_theta=c["rope_theta"], max_position=c["serve"]["max_len"],
+        param_dtype=jnp.bfloat16))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    weights_gb = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(params)) / 1e9
+    assert 10.3 < weights_gb < 10.4                 # the issue's 10.35 GB
+    sched = _scheduler_from_shapes(model, params,
+                                   num_slots=c["serve"]["num_slots"],
+                                   max_len=c["serve"]["max_len"])
+    pool = sched._cache["kv"]["latent_key"]
+    assert pool.shape == (8, 64 * 256 + 1, 16, 640)
+    assert sched._kv_pool_bytes[0] == sched._kv_pool_bytes[1]
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    targets = sched.graph_targets()
+    assert [t.name for t in targets] == ["prefill_window", "admit",
+                                         "decode_tick"]
+    pool_gb = pool.size * 2 / 1e9
+    for target in targets:
+        lowered = target.fn.lower(*place(target.args))
+        assert "callback" not in lowered.as_text(), target.name
+        compiled = lowered.compile()
+        text, memory = compiled.as_text(), compiled.memory_analysis()
+        assert KERNEL_MARK not in text, target.name
+        assert pool_moves(text, pool.shape) == [], target.name
+        # no copy of an expert's matrices or of a bank of them
+        assert not re.search(
+            r"= bf16\[(16,)?(6144,4096|2048,6144)\]\S* copy\(", text), \
+            target.name
+        assert memory.alias_size_in_bytes > pool_gb * 1e9, (target.name,
+                                                            memory)
+        assert memory.temp_size_in_bytes < 0.6e9, (target.name, memory)
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 15.75e9), (target.name, memory)
