@@ -1396,115 +1396,85 @@ class GPT:
 
     def decode_window_paged(self, params, kv, token_ids, page_row, pos,
                             head: str = "all", adapters=None,
-                            adapter_rows=None, use_kernel: bool = False):
-        """``decode_window`` against a PAGED cache: a batch-1 window of
-        ``s`` tokens at positions ``pos..pos+s-1``, reading and writing
-        the shared page pool through one request's ``page_row``
-        [pages_per_row] int32.
+                            adapter_rows=None, use_kernel: bool = False,
+                            valid=None):
+        """``decode_window`` against a PAGED cache, for a BATCH of
+        windows: row r of ``token_ids`` [n, s] is one request's window at
+        positions ``pos[r] .. pos[r] + s - 1``, reading and writing the
+        shared page pool through its own table row ``page_row[r]``
+        [n, pages_per_row] int32.  The batch-1 call form — a rank-1
+        ``page_row`` and a scalar ``pos`` (and ``valid``) — is the n = 1
+        case of the same code.
 
         The serve tier's chunked-prefill step under paging
-        (serve/pages.py): ``pos`` is a TRACED scalar, so a request that
-        maps shared prefix pages simply starts its first window at
-        ``pos = skip`` — the skipped windows are never dispatched, yet
-        row j still attends every cache column ``<= pos + j`` (shared
-        pages included).
+        (serve/pages.py): ``pos`` is TRACED, so a request that maps shared
+        prefix pages simply starts its first window at ``pos = skip`` —
+        the skipped windows are never dispatched, yet row j of a window
+        still attends every cache column ``<= pos + j`` (shared pages
+        included).  The windows of one dispatch read the weights once.
 
-        Structure: gather the row's pages ONCE into a contiguous
-        ``[L, 1, view_len, ...]`` stripe, run the UNMODIFIED
-        ``decode_window`` on it (so the window math is ``generate()``'s
-        to the bit — and the layer scan carries one stripe,
-        never the whole pool), then scatter the ``s`` written columns
-        back to their pool cells ``(page_row[c // page_size], c %
-        page_size)``.  Pad columns of the last window map whatever
-        ``page_row`` holds there (the reserved trash page 0 when
-        unallocated) — written but never valid: dead weight, not
-        state.
+        Structure: ``decode_window``'s (embed at ``pos + j``, RoPE at the
+        window positions, write-then-attend per layer), but the cache is
+        the POOL: K/V land on their pool cells ``(page_row[c //
+        page_size], c % page_size)`` via ``_cache_layer``'s page-write,
+        and each row reads its own pages back — gathered into the usual
+        ``[n, view_len, ...]`` operand under the ``col <= pos + j`` mask
+        (``_paged_layer_kv``), or, with ``use_kernel`` (STATIC), walked
+        inside the fused Pallas kernel
+        (``ops.pallas.paged_window_attention``), which computes the same
+        mask from each row's run and fetches no page past the window's
+        last column.
 
-        ``head`` as in ``decode_window``.  Returns (logits, new kv
-        pool) — the pool subtree carries no ``pos``; the caller owns
-        positions (serve/scheduler tracks them host-side).
+        ``valid`` [n] (None: every column of every row): how many of a
+        row's tokens are real.  The columns past them are written to the
+        reserved trash page 0 — dead weight, not state — and a row with
+        ``valid == 0`` is PADDING of the batch: it writes nothing else and
+        its kernel walk is empty.
 
-        ``use_kernel`` (STATIC): skip the stripe entirely — K/V write
-        straight into their pool cells (the same ``_cache_layer``
-        page-write the per-token step uses) and attention walks the
-        page table inside the fused Pallas kernel
-        (``ops.pallas.paged_window_attention``), causal against the
-        traced ``pos``.  No ``[L, 1, view_len, ...]`` stripe, no
-        scatter-back.
+        ``head`` as in ``decode_window``, with ``"last"`` the logits at
+        each row's last REAL position, taken before the head matmul:
+        ``[n, vocab]``.  Returns (logits, new kv pool) — the pool subtree
+        carries no ``pos``; the caller owns positions (serve/scheduler
+        tracks them host-side).
         """
         if head not in ("all", "last", "none"):
             raise ValueError(f"head must be all|last|none; got {head!r}")
-        b, s = token_ids.shape
-        if b != 1:
-            raise ValueError(f"decode_window_paged is batch-1 (one page "
-                             f"row = one request); got batch {b}")
-        page_size = kv["k"].shape[2]
-        if use_kernel:
-            return self._decode_window_paged_kernel(
-                params, kv, token_ids, page_row, pos, head=head,
-                adapters=adapters, adapter_rows=adapter_rows)
-
-        kv_heads = self.config.kv_heads
-
-        def gather(name):
-            g = jnp.take(kv[name], page_row, axis=1)  # [L, mp, pg, width]
-            return g.reshape(g.shape[0], 1, g.shape[1] * g.shape[2],
-                             kv_heads, -1)
-        view = {name: gather(name) for name in kv}
-        logits, view = self.decode_window(
-            params, dict(view, pos=pos), token_ids, head=head,
-            adapters=adapters, adapter_rows=adapter_rows)
-
-        cols = pos + jnp.arange(s)
-        pids = jnp.take(page_row, cols // page_size)
-        offs = cols % page_size
-        new_kv = {}
-        for name in kv:
-            vals = jnp.take(view[name][:, 0], cols, axis=1)  # [L,s,kvh,x]
-            new_kv[name] = kv[name].at[:, pids, offs].set(
-                vals.reshape(vals.shape[:2] + (-1,)))
-        return logits, new_kv
-
-    def _decode_window_paged_kernel(self, params, kv, token_ids,
-                                    page_row, pos, *, head,
-                                    adapters=None, adapter_rows=None):
-        """``decode_window_paged``'s fused-kernel body: the
-        ``decode_window`` structure (embed at ``pos + j``, RoPE at the
-        window positions, write-then-attend per layer, same head
-        modes), but the cache is the POOL — writes land on their pool
-        cells via ``_cache_layer``'s page-write, reads walk ``page_row``
-        inside ``ops.pallas.paged_window_attention``, up to the page of
-        the window's last column, with the ``col <= pos + j`` causal mask
-        computed in-kernel."""
-        from ..ops.pallas import paged_attention as paged_lib
         c = self.config
-        b, s = token_ids.shape
+        n, s = token_ids.shape
+        page_size = kv["k"].shape[2]
+        win = attn_lib.paged_windows(page_row, pos, valid, n, s, page_size)
         emb = params["embeddings"]
-        x = jnp.take(emb["word"], token_ids, axis=0)            # [1,s,d]
-        win_pos = pos + jnp.arange(s)
+        x = jnp.take(emb["word"], token_ids, axis=0)            # [n,s,d]
         if c.position_embedding == "learned":
-            x = x + jnp.take(emb["position"], win_pos, axis=0)
+            x = x + jnp.take(emb["position"], win.cols, axis=0)
         x = x.astype(c.dtype)
 
         rope_cs = None
         if c.position_embedding == "rope":
-            rope_cs = attn_lib.rope_tables(win_pos, c.head_dim,
+            rope_cs = attn_lib.rope_tables(win.cols, c.head_dim,
                                            base=c.rope_base)
+        paged = (win.pages, win.offs)
 
-        page_size = kv["k"].shape[2]
-        cols = pos + jnp.arange(s)
-        pids = jnp.take(page_row, cols // page_size)
-        paged = (pids, cols % page_size)
+        if use_kernel:
+            from ..ops.pallas import paged_attention as paged_lib
+            # prefix + window: the columns up to the window's last, on
+            # the same pages for every layer; a padding row has none
+            walk = paged_lib.page_walk(
+                kv, win.page_rows, jnp.zeros((n,), jnp.int32),
+                jnp.where(win.valid > 0, win.pos + s, 0))
 
-        # prefix + window: the columns up to the window's last, on the
-        # same pages for every layer
-        walk = paged_lib.page_walk(kv, page_row[None, :],
-                                   jnp.zeros((1,), jnp.int32),
-                                   jnp.reshape(pos + s, (1,)))
+            def window_attn(q, k_blk, v_blk, kv, i):
+                del k_blk, v_blk   # read back through the pool
+                return paged_lib.paged_window_attention(q, kv, i, walk)
+        else:
+            kv_mask = attn_lib.paged_window_mask(win, page_size)
 
-        def window_attn(q, k_blk, v_blk, kv, i):
-            del k_blk, v_blk   # read back through the pool (prefix + win)
-            return paged_lib.paged_window_attention(q, kv, i, walk)
+            def window_attn(q, k_blk, v_blk, kv, i):
+                del k_blk, v_blk   # read back through the pool
+                k_cache, v_cache = self._paged_layer_kv(kv, i,
+                                                        win.page_rows)
+                return attn_lib.dot_product_attention(q, k_cache, v_cache,
+                                                      mask=kv_mask)
 
         def body(carry, inputs):
             x, kv = carry
@@ -1522,7 +1492,8 @@ class GPT:
         if head == "none":
             return None, new_kv
         if head == "last":
-            x = self._norm(params["ln_f"], x[:, -1:, :])
+            x = self._norm(params["ln_f"],
+                           attn_lib.last_real_position(x, win.valid))
             return self.logits(params, x)[:, 0, :], new_kv
         x = self._norm(params["ln_f"], x)
         return self.logits(params, x), new_kv

@@ -23,9 +23,9 @@ The head is tied to the embedding.
     last ``width - 1`` inputs.  ``serve/pages.py`` builds the pool from
     this description.
   * **Serving programs** mirror ``models/gpt.py``: ``decode_window_paged``
-    (one request's chunked-prefill window, the state carried in from the
-    slot's row — zero at position 0 — and out after the window's last REAL
-    token) and ``decode_step_slots_paged`` (one token for every slot; a row
+    (a batch of requests' chunked-prefill windows, each one's state carried
+    in from its slot's row — zero at position 0 — and out after the window's
+    last REAL token) and ``decode_step_slots_paged`` (one token for every slot; a row
     that is not live keeps its state and convolution inputs exactly).  The
     attention reads gather the row's pages; see ``paged_kernel_ok``.
 
@@ -333,8 +333,9 @@ class HybridDecoder:
         xbc_conv, conv = ssm.causal_conv1d(
             xbc, m["conv"]["kernel"], m["conv"]["bias"], conv0, valid)
         xs, b_in, c_in = self._ssm_split(xbc_conv)
-        real = (None if valid is None
-                else (jnp.arange(x.shape[1]) < valid)[None, :, None])
+        real = (None if valid is None else
+                (jnp.arange(x.shape[1]) < jnp.reshape(valid, (-1, 1))
+                 )[:, :, None])
         dt = ssm.softplus_dt(dt_raw, m["dt_bias"], real)
         a = -jnp.exp(m["a_log"].astype(F32))
         y, h = ssm.ssd_chunked(xs, dt, a, b_in, c_in, h0, c.ssm_chunk)
@@ -532,70 +533,70 @@ class HybridDecoder:
                             head: str = "all", *, state, slot, valid,
                             adapters=None, adapter_rows=None,
                             use_kernel: bool = False):
-        """One request's prefill window against the paged cache: ``s``
-        tokens at positions ``pos .. pos + s - 1`` of which the first
-        ``valid`` are real, K/V written through ``page_row``, the
-        recurrent state read from row ``slot`` of ``state`` (zero when
-        ``pos`` is 0: a sequence's start) and written back after the last
-        real token.  ``pos`` need not be a page or window boundary: a
+        """A batch of prefill windows against the paged cache: row r of
+        ``token_ids`` [n, s] is one request's window, ``s`` tokens at
+        positions ``pos[r] .. pos[r] + s - 1`` of which the first
+        ``valid[r]`` are real, K/V written through ``page_row[r]`` [n,
+        pages_per_row], the recurrent state read from row ``slot[r]`` of
+        ``state`` (zero when ``pos[r]`` is 0: a sequence's start) and
+        written back after the last real token.  The batch-1 call form (a
+        rank-1 ``page_row``, scalar ``pos`` / ``slot`` / ``valid``) is the
+        n = 1 case of the same code; the windows of one dispatch read the
+        weights once.  ``pos`` need not be a page or window boundary: a
         request resumed from a state snapshot starts wherever the snapshot
-        was taken.  Pad columns are written to the reserved trash page.
-        Returns ``(logits [1, s, vocab] or None for ``head="none"``, kv,
-        state)``."""
-        if head not in ("all", "none"):
-            raise ValueError(f"head must be all|none; got {head!r}")
+        was taken.  Pad columns are written to the reserved trash page; a
+        row with ``valid == 0`` is PADDING of the batch: it writes nowhere
+        else, and no slot's state or convolution inputs (the write back is
+        a scatter by slot that drops such rows).  Returns ``(logits, kv,
+        state)``: logits ``[n, s, vocab]``, for ``head="last"`` ``[n,
+        vocab]`` at each row's last real position (taken before the head
+        matmul), None for ``head="none"``."""
+        if head not in ("all", "last", "none"):
+            raise ValueError(f"head must be all|last|none; got {head!r}")
         if adapters is not None or use_kernel:
             raise ValueError("this decoder has no adapter path and reads "
                              "its pages through the gather path")
         c = self.config
-        b, s = token_ids.shape
-        if b != 1:
-            raise ValueError(f"decode_window_paged is batch-1; got {b}")
-        x = self._embed(params, token_ids)
-        fresh = pos == 0
+        n, s = token_ids.shape
         page_size = kv["k"].shape[2]
-        view_len = page_row.shape[0] * page_size
-        j = jnp.arange(s)
-        cols = pos + j
-        pids = jnp.take(page_row, jnp.minimum(cols // page_size,
-                                              page_row.shape[0] - 1))
-        pids = jnp.where(j < valid, pids, 0)
-        offs = cols % page_size
-        # row j attends every column <= pos + j (all of them this
-        # request's own, shared prefix pages included)
-        mask = jnp.where(jnp.arange(view_len)[None, :] <= cols[:, None],
-                         0.0, attn_lib.NEG_INF)[None, None]
+        win = attn_lib.paged_windows(page_row, pos, valid, n, s, page_size)
+        valid = win.valid
+        # a padding row names no slot: it reads the last one's state and
+        # its write is dropped
+        slots = state["ssm"].shape[1]
+        slot = jnp.where(valid > 0, jnp.broadcast_to(
+            jnp.asarray(slot, jnp.int32).reshape(-1), (n,)), slots)
+        read = jnp.minimum(slot, slots - 1)
+        x = self._embed(params, token_ids)
+        fresh = win.pos == 0
+        mask = attn_lib.paged_window_mask(win, page_size)
 
         def mamba_layer(p, i, x, ssm_state, conv_state):
-            zero = jnp.zeros((), jnp.int32)
-            h0 = lax.dynamic_slice(
-                ssm_state, (i, slot, zero, zero, zero),
-                (1, 1) + ssm_state.shape[2:])[0]
-            conv0 = lax.dynamic_slice(
-                conv_state, (i, slot, zero, zero),
-                (1, 1) + conv_state.shape[2:])[0]
-            h0 = jnp.where(fresh, jnp.zeros_like(h0), h0)
-            conv0 = jnp.where(fresh, jnp.zeros_like(conv0), conv0)
-            x, h, conv = self._mamba_block(p, x, h0.astype(F32), conv0,
-                                           valid)
-            ssm_state = lax.dynamic_update_slice(
-                ssm_state, h.astype(ssm_state.dtype)[None],
-                (i, slot, zero, zero, zero))
-            conv_state = lax.dynamic_update_slice(
-                conv_state, conv.astype(conv_state.dtype)[None],
-                (i, slot, zero, zero))
+            h0 = jnp.where(fresh[:, None, None, None], 0.0,
+                           ssm_state[i, read].astype(F32))
+            conv0 = conv_state[i, read]
+            conv0 = jnp.where(fresh[:, None, None], jnp.zeros_like(conv0),
+                              conv0)
+            x, h, conv = self._mamba_block(p, x, h0, conv0, valid)
+            ssm_state = ssm_state.at[i, slot].set(
+                h.astype(ssm_state.dtype), mode="drop")
+            conv_state = conv_state.at[i, slot].set(
+                conv.astype(conv_state.dtype), mode="drop")
             return x, ssm_state, conv_state
 
         def attention_layer(p, layer, x, kv):
-            return self._cached_attention(p, layer, x, kv, pids, offs,
-                                          page_row[None], mask)
+            return self._cached_attention(p, layer, x, kv, win.pages,
+                                          win.offs, win.page_rows, mask)
 
         x, kv, state = self._run_stack(params, x, kv, state, mamba_layer,
                                        attention_layer)
         if head == "none":
             return None, kv, state
+        if head == "last":
+            x = attn_lib.last_real_position(x, valid)
         x = _rms_norm(params["ln_f"], x, c.layer_norm_eps)
-        return self.logits(params, x), kv, state
+        logits = self.logits(params, x)
+        return (logits[:, 0] if head == "last" else logits), kv, state
 
     def decode_step_slots_paged(self, params, kv, token_ids, page_tab,
                                 start_col, write_col, positions, *, state,

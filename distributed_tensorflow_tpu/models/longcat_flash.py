@@ -489,47 +489,45 @@ class LongcatFlash:
                             head: str = "all", *, valid, counters,
                             adapters=None, adapter_rows=None,
                             use_kernel: bool = False):
-        """One request's prefill window against the paged cache: ``s``
-        tokens at positions ``pos .. pos + s - 1`` of which the first
-        ``valid`` are real, latents and keys written through ``page_row``;
-        pad columns go to the reserved trash page and are neither routed to
-        an expert nor counted.  ``pos`` need not be a page or window
-        boundary.  Returns ``(logits [1, s, vocab] or None for
-        ``head="none"``, kv, counters)``: ``counters`` (the cache's, module
-        doc) with this window's router counts added."""
-        if head not in ("all", "none"):
-            raise ValueError(f"head must be all|none; got {head!r}")
+        """A batch of prefill windows against the paged cache: row r of
+        ``token_ids`` [n, s] is one request's window, ``s`` tokens at
+        positions ``pos[r] .. pos[r] + s - 1`` of which the first
+        ``valid[r]`` are real, latents and keys written through
+        ``page_row[r]`` [n, pages_per_row].  The batch-1 call form (a rank-1
+        ``page_row``, scalar ``pos`` / ``valid``) is the n = 1 case of the
+        same code; the windows of one dispatch read the weights — and each
+        expert they touch — once.  Pad columns go to the reserved trash
+        page and are neither routed to an expert nor counted; a row with
+        ``valid == 0`` is PADDING of the batch and is all pad columns.
+        ``pos`` need not be a page or window boundary.  Returns ``(logits,
+        kv, counters)``: logits ``[n, s, vocab]``, for ``head="last"`` ``[n,
+        vocab]`` at each row's last real position (taken before the head
+        matmul), None for ``head="none"``; ``counters`` (the cache's, module
+        doc) with these windows' router counts added."""
+        if head not in ("all", "last", "none"):
+            raise ValueError(f"head must be all|last|none; got {head!r}")
         if adapters is not None or use_kernel:
             raise ValueError("this decoder has no adapter path and reads "
                              "its pages through the gather path")
         c = self.config
-        b, s = token_ids.shape
-        if b != 1:
-            raise ValueError(f"decode_window_paged is batch-1; got {b}")
-        x = self._embed(params, token_ids)
+        n, s = token_ids.shape
         page_size = kv["latent_key"].shape[2]
-        view_len = page_row.shape[0] * page_size
-        j = jnp.arange(s)
-        cols = pos + j
-        real = j < valid
-        pids = jnp.take(page_row, jnp.minimum(cols // page_size,
-                                              page_row.shape[0] - 1))
-        pids = jnp.where(real, pids, 0)
-        offs = cols % page_size
-        cos, sin = attn_lib.rope_tables(cols, c.qk_rope_head_dim,
+        win = attn_lib.paged_windows(page_row, pos, valid, n, s, page_size)
+        x = self._embed(params, token_ids)
+        cos, sin = attn_lib.rope_tables(win.cols, c.qk_rope_head_dim,
                                         c.rope_theta)
-        # row j attends every column <= pos + j (all of them this
-        # request's own, shared prefix pages included)
-        mask = jnp.where(jnp.arange(view_len)[None, :] <= cols[:, None],
-                         0.0, attn_lib.NEG_INF)[None, None]
         x, kv, counts = self._run_cached(
-            params, x, kv, cos, sin, pids, offs, page_row[None], mask,
-            real[None])
+            params, x, kv, cos, sin, win.pages, win.offs, win.page_rows,
+            attn_lib.paged_window_mask(win, page_size),
+            jnp.arange(s) < win.valid[:, None])
         counters = dict(counters, router=counters["router"] + counts)
         if head == "none":
             return None, kv, counters
+        if head == "last":
+            x = attn_lib.last_real_position(x, win.valid)
         x = _rms_norm(params["ln_f"], x, c.rms_norm_eps)
-        return self.logits(params, x), kv, counters
+        logits = self.logits(params, x)
+        return (logits[:, 0] if head == "last" else logits), kv, counters
 
     def decode_step_slots_paged(self, params, kv, token_ids, page_tab,
                                 start_col, write_col, positions, *,

@@ -20,7 +20,7 @@ slots in behind the same ``dot_product_attention`` signature.
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +32,9 @@ __all__ = ["dot_product_attention", "causal_mask", "padding_mask",
            "attention_core", "ffn_core", "ffn_swiglu_core",
            "rotary_embedding", "rope_tables", "apply_rope",
            "MultiHeadAttention", "flash_wins", "resolve_use_flash",
-           "paged_kernel_wins", "resolve_use_paged_kernel"]
+           "paged_kernel_wins", "resolve_use_paged_kernel",
+           "PagedWindows", "paged_windows", "paged_window_mask",
+           "last_real_position"]
 
 NEG_INF = -1e9  # finite -inf stand-in: keeps softmax well-defined in f32
 
@@ -114,6 +116,62 @@ def resolve_use_paged_kernel(use_paged_kernel, view_len: int) -> bool:
     if use_paged_kernel == "auto":
         return paged_kernel_wins(view_len)
     return bool(use_paged_kernel)
+
+
+class PagedWindows(NamedTuple):
+    """A batch of ``n`` prefill windows of ``s`` tokens against a page
+    pool (``paged_windows``)."""
+    page_rows: jnp.ndarray   # [n, pages_per_row]: each window's table row
+    pos: jnp.ndarray         # [n]: the logical column of its first token
+    valid: jnp.ndarray       # [n]: how many of its tokens are real
+    cols: jnp.ndarray        # [n, s]: its tokens' logical columns
+    pages: jnp.ndarray       # [n * s]: the pool page each token writes
+    offs: jnp.ndarray        # [n * s]: its cell on that page
+
+
+def paged_windows(page_row, pos, valid, n: int, s: int,
+                  page_size: int) -> PagedWindows:
+    """Where a batch of prefill windows reads and writes a page pool: the
+    one place the serving models' ``decode_window_paged`` take their call
+    forms apart.  ``page_row`` [n, pages_per_row] (or rank 1 for n = 1),
+    ``pos`` / ``valid`` [n] or scalars (``valid`` None: every token real).
+    A window's pad columns — past its ``valid`` real tokens — are written
+    to the reserved trash page 0, so a row with ``valid == 0`` (padding of
+    the batch) writes nowhere else."""
+    page_rows = jnp.reshape(page_row, (-1, page_row.shape[-1]))
+    if page_rows.shape[0] != n:
+        raise ValueError(f"{n} windows need {n} page rows; got "
+                         f"{page_rows.shape[0]}")
+
+    def per_row(v):
+        return jnp.broadcast_to(jnp.asarray(v, jnp.int32).reshape(-1), (n,))
+
+    pos = per_row(pos)
+    valid = jnp.full((n,), s, jnp.int32) if valid is None else per_row(valid)
+    j = jnp.arange(s)
+    cols = pos[:, None] + j
+    pages = jnp.take_along_axis(
+        page_rows, jnp.minimum(cols // page_size, page_rows.shape[1] - 1),
+        axis=1)
+    pages = jnp.where(j < valid[:, None], pages, 0)
+    return PagedWindows(page_rows, pos, valid, cols, pages.reshape(-1),
+                        (cols % page_size).reshape(-1))
+
+
+def paged_window_mask(windows: PagedWindows, page_size: int) -> jnp.ndarray:
+    """[n, 1, s, view_len] additive mask over each window's gathered pages:
+    token j attends every column ``<= pos + j`` (all of them its request's
+    own, shared prefix pages included)."""
+    view = jnp.arange(windows.page_rows.shape[1] * page_size)
+    return jnp.where(view[None, None, :] <= windows.cols[:, :, None],
+                     0.0, NEG_INF)[:, None]
+
+
+def last_real_position(x: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
+    """``x`` [n, s, d] -> [n, 1, d]: each window's last real position (its
+    first where it has none), taken before the head matmul."""
+    return jnp.take_along_axis(
+        x, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:

@@ -52,8 +52,8 @@ def causal_conv1d(x, kernel, bias, state=None, valid=None):
     inputs just before ``x`` (zeros at a sequence's start; None = zeros).
     Returns ``(y [b, s, c], new_state)`` where ``new_state`` holds the
     ``width - 1`` inputs that end at position ``valid - 1`` (``valid`` a
-    traced scalar count of real positions; None = ``s``), so right padding
-    never enters the state.
+    traced count of real positions, a scalar or one a row ``[b]``; None =
+    ``s``), so right padding never enters the state.
     """
     width = kernel.shape[0]
     b, s, c = x.shape
@@ -64,9 +64,11 @@ def causal_conv1d(x, kernel, bias, state=None, valid=None):
         y = bias.astype(F32)
         for j in range(width):
             y = y + kernel[j].astype(F32) * ext[:, j:j + s].astype(F32)
-    start = s if valid is None else valid
-    new_state = lax.dynamic_slice_in_dim(ext, start, width - 1, axis=1)
-    return y, new_state
+    if valid is None:
+        return y, lax.dynamic_slice_in_dim(ext, s, width - 1, axis=1)
+    ends = (jnp.broadcast_to(jnp.reshape(valid, (-1, 1)), (b, 1))
+            + jnp.arange(width - 1))
+    return y, jnp.take_along_axis(ext, ends[:, :, None], axis=1)
 
 
 def ssd_step(x, dt, a, b_in, c_in, h):

@@ -148,8 +148,17 @@ class ServeMetrics:
             "dttpu_serve_ticks_total", "Scheduler ticks completed.")
         self.prefill_windows = reg.counter(
             "dttpu_serve_prefill_windows_total",
-            "Prefill window dispatches (mid windows and the admitting "
-            "last window).")
+            "Prefill windows run (mid windows and the admitting last "
+            "window).")
+        self.prefill_dispatches = reg.counter(
+            "dttpu_serve_prefill_dispatches_total",
+            "Prefill window programs dispatched: one holds the windows "
+            "a tick dispatches together, up to the ladder's largest row "
+            "count.")
+        self.prefill_rows_padded = reg.counter(
+            "dttpu_serve_prefill_rows_padded_total",
+            "Padding rows those programs carried (a group is padded up "
+            "to the next row count of the ladder).")
         self.decode_steps = reg.counter(
             "dttpu_serve_decode_steps_total",
             "Decode steps dispatched (tick_steps per decode dispatch).")
@@ -187,6 +196,8 @@ class ServeMetrics:
              "state_snapshots_evicted_total", 0],
             [self.ticks, "ticks_completed", 0],
             [self.prefill_windows, "prefill_windows_total", 0],
+            [self.prefill_dispatches, "prefill_dispatches_total", 0],
+            [self.prefill_rows_padded, "prefill_rows_padded_total", 0],
             [self.decode_steps, "decode_steps_total", 0],
             [self.decode_pages_walked, "decode_pages_walked_total", 0],
             [self.decode_pages_table, "decode_pages_table_total", 0],

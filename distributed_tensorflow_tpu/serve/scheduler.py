@@ -2,11 +2,14 @@
 
 The control plane of the continuous-batching engine (docs/SERVING.md).
 All device work goes through THREE jitted functions built once at
-construction — a mid-prefill window, a fused last-prefill window
-(+ first-token sample + slot arm), and the K-step decode tick —
-each with fully static shapes, so admitting and retiring requests never
-recompiles anything (pinned by tests/test_serve.py under the runtime
-sanitizer, and warn-checked by ``bench.py --config=gpt_serve``).
+construction — a batch of mid-prefill windows, a batch of windows of
+which some are last ones (+ first-token sample + slot arm, a row), and
+the K-step decode tick — each with fully static shapes: the two window
+programs at the few row counts of a ladder (``_rungs``), every one of
+them compiled AT construction, so admitting and retiring requests never
+recompiles anything whatever group sizes arrive (pinned by
+tests/test_serve.py under the runtime sanitizer, and warn-checked by
+``bench.py --config=gpt_serve``).
 
 One storage layout: the page pool (serve/pages.py) maps slot columns
 to fixed-size pool pages through per-slot page tables — prefill writes
@@ -31,7 +34,10 @@ resumes where the source stopped through the SAME three executables.
   fixed-width window per tick, straight into the pages the request
   leased at admission — so a long prompt never stalls in-flight decodes
   for more than one window per tick, and every prompt length reuses the
-  same two executables.
+  same two executables.  The windows a tick dispatches together — every
+  prefilling request's one — go to the device as ONE ``[rows, W]``
+  program (groups of at most ``_rungs[-1]`` rows, padded up to the next
+  rung): they read the weights once, not once a request.
   Free slots are filled eagerly: up to one prefill per free slot runs
   concurrently (each advancing one window per tick), so a burst of
   arrivals admits at slot rate, not one request per tick.  The pad
@@ -306,7 +312,12 @@ class EngineStats:
     last_tick_duration_s: float = 0.0
     # what the pump dispatched, cumulative, counted where it happens
     # (over ticks_completed: windows and decode steps a tick)
-    prefill_windows_total: int = 0           # window dispatches, mid + last
+    prefill_windows_total: int = 0           # windows run, mid + last
+    # the programs that ran them (one holds a tick's windows, up to the
+    # ladder's largest row count) and the padding rows those programs
+    # carried: windows / dispatches is how often batching engages
+    prefill_dispatches_total: int = 0
+    prefill_rows_padded_total: int = 0
     decode_steps_total: int = 0              # tick_steps per decode dispatch
     # page-table entries those steps read, and the entries their tables
     # hold (steps x slots x pages a slot): the page-walk kernel reads the
@@ -384,12 +395,36 @@ def _written_context(req: "Request") -> np.ndarray:
             if len(fresh) > 1 else ctx)
 
 
+# what a row of a window program is told beyond its tokens and its page
+# row: the columns of one int32 ``[rows, 6]`` array
+_POS, _VALID, _SLOT, _ADMIT, _LENGTH, _BUDGET = range(6)
+# a window program's largest row count: 8 windows of ``prefill_chunk`` 32
+# are 256 tokens, about the v5e's ridge (197e12 / 819e9 = 240 operations a
+# byte; with bf16 weights ~240 tokens through a matrix before the matmul
+# stops being bound by reading it), so up to here a batch of windows
+# costs about what one costs and beyond it batching buys nothing more
+_MAX_WINDOW_ROWS = 8
+
+
+def _window_rungs(num_slots: int) -> Tuple[int, ...]:
+    """The row counts the two window programs are compiled at: 1 and 4
+    where they lie under ``R = min(num_slots, _MAX_WINDOW_ROWS)``, then
+    ``R``.  A dispatch takes the smallest that holds its group.  Few,
+    because every rung is two more programs to trace, lower and load in
+    every process, whatever the compile cache holds (~0.5 s a program at
+    GPT-2-XL: with the rung at 2 as well the set-up of an 8-slot engine
+    rose by 1.7-2.1 s, with these by 1.0; a group of two pays for it with
+    two padding rows, PERF.md section 6, PR 37)."""
+    top = min(num_slots, _MAX_WINDOW_ROWS)
+    return tuple(r for r in (1, 4) if r < top) + (top,)
+
+
 @dataclasses.dataclass(slots=True, eq=False)
 class _Prefill:
     """One in-flight prefill.  Compared by identity: ``st in
     self._prefills`` and ``.remove(st)`` mean THIS prefill."""
     req: Request
-    windows: np.ndarray                      # [n, 1, W] int32
+    windows: np.ndarray                      # [n, W] int32
     next: int                                # index of the next window
     lease: pages_lib.PageLease
     plan: List[Tuple[int, int, int]]         # (pos, real, snapshot depth)
@@ -401,7 +436,8 @@ class SlotScheduler:
     """Drive a slot cache for a GPT-family ``model``/``params`` pair.
 
     Synchronous by design: callers pump ``step()`` (one tick: at most
-    one prefill window + one K-step decode dispatch) or ``drain()``.
+    one prefill window a request, the tick's windows batched a few
+    programs, + one K-step decode dispatch) or ``drain()``.
     Sampling config (temperature/top_k/top_p/eos) is static — it is
     baked into the compiled tick, like generate()'s.
     """
@@ -458,6 +494,8 @@ class SlotScheduler:
         self._last_tick_s = 0.0
         # dispatch counters (stats(); written by the pump under _lock)
         self._prefill_windows = 0
+        self._prefill_dispatches = 0
+        self._prefill_rows_padded = 0
         self._decode_steps = 0
         self._decode_pages_walked = 0
         self._decode_pages_table = 0
@@ -628,24 +666,33 @@ class SlotScheduler:
             return (cache, tokens, finished, remaining, key), \
                 (emitted, live)
 
-        def first_token(logits, last_idx, key, tokens, finished,
-                        remaining, slot_idx, budget):
-            """The last window's tail: sample the first token from the
-            prompt's final-position logits and arm the slot's
-            tokens/finished/remaining rows."""
-            row = jax.lax.dynamic_index_in_dim(logits[0], last_idx,
-                                               keepdims=False)
-            key, sub = jax.random.split(key)
-            tok = dec.sample_logits(sub, row[None], temperature,
-                                    top_k=top_k, top_p=top_p)[0]
-            tokens = tokens.at[slot_idx].set(tok)
+        def first_tokens(logits, told, key, tokens, finished, remaining):
+            """The admitting rows' tail: sample each one's first token
+            from its prompt's final-position ``logits`` [rows, vocab] and
+            arm its slot's tokens/finished/remaining rows.  The key is
+            split once an ADMITTING row, in row order — the stream of the
+            same windows dispatched one by one — and the scatters drop
+            every other row."""
+            admit = told[:, _ADMIT] > 0
+
+            def split(key, admitting):
+                new, sub = jax.random.split(key)
+                return jnp.where(admitting, new, key), sub
+
+            key, subs = jax.lax.scan(split, key, admit)
+            tok = jax.vmap(lambda sub, row: dec.sample_logits(
+                sub, row[None], temperature, top_k=top_k,
+                top_p=top_p)[0])(subs, logits)
+            budget = told[:, _BUDGET]
+            slot = jnp.where(admit, told[:, _SLOT], num_slots)
             done0 = budget <= 1
             if eos_id is not None:
                 done0 = done0 | (tok == eos_id)
-            finished = finished.at[slot_idx].set(done0)
+            tokens = tokens.at[slot].set(tok, mode="drop")
+            finished = finished.at[slot].set(done0, mode="drop")
             # the first token was already emitted from the prefill logits
-            remaining = remaining.at[slot_idx].set(budget - 1)
-            return tok, key, tokens, finished, remaining
+            remaining = remaining.at[slot].set(budget - 1, mode="drop")
+            return tok, slot, key, tokens, finished, remaining
 
         # static per-build: the fused-kernel gate resolved above — the
         # three paged executables close over the answer, so the kernel
@@ -666,56 +713,60 @@ class SlotScheduler:
             return dict(cache, counters=jax.tree.map(
                 jnp.zeros_like, counters)), read
 
-        def paged_window(params, cache, window, page_row, pos, head,
-                         ad, ad_row, slot, valid):
-            """One prefill window through the model -> (logits, cache).
-            What the model is handed beyond the pool goes by what the
-            cache holds, as in ``pages.decode_paged_step``: nothing for a
-            K/V-only model (its programs are what they always were); a
-            model with recurrent state reads the slot's state row,
-            advances it over the window's ``valid`` real tokens and writes
-            it back; one that counts adds its ``valid`` tokens' counts."""
+        def paged_windows(params, cache, windows, page_rows, told, head,
+                          ad, ad_rows):
+            """A batch of prefill windows through the model -> (logits,
+            cache): row r is one request's window ``windows[r]`` [W]
+            through its table row ``page_rows[r]``, and ``told[r]`` (the
+            ``_POS`` .. ``_BUDGET`` columns; one array, because every
+            argument is a transfer of its own) says where it starts, how
+            many of its tokens are real (0: the row is padding up to the
+            rung) and which slot's recurrent state it advances.  What the
+            model is handed beyond the pool goes by what the cache holds,
+            as in ``pages.decode_paged_step``: a model with recurrent
+            state reads each row's slot row, advances it over the row's
+            real tokens and scatters it back; one that counts adds the
+            real tokens' counts."""
             held = [n for n in ("state", "counters") if n in cache]
             extra = {n: cache[n] for n in held}
-            if held:
-                extra["valid"] = valid
             if "state" in cache:
-                extra["slot"] = slot
+                extra["slot"] = told[:, _SLOT]
             logits, *new = model.decode_window_paged(
-                params, cache["kv"], window, page_row, pos, head=head,
-                adapters=ad, adapter_rows=ad_row, use_kernel=use_kernel,
-                **extra)
+                params, cache["kv"], windows, page_rows, told[:, _POS],
+                head=head, adapters=ad, adapter_rows=ad_rows,
+                use_kernel=use_kernel, valid=told[:, _VALID], **extra)
             return logits, dict(cache, **dict(zip(["kv"] + held, new)))
 
-        def paged_win_mid(params, cache, window, page_row, pos, ad,
-                          ad_row, slot=None, valid=None):
-            """Mid prefill window straight into the request's pages —
+        def paged_win_mid(params, cache, windows, page_rows, told, ad,
+                          ad_rows):
+            """Mid prefill windows straight into their requests' pages —
             the whole cache (pool + slot state) is donated and flows
             through so win/admit/tick chain on one buffer set."""
-            return paged_window(params, cache, window, page_row, pos,
-                                "none", ad, ad_row, slot, valid)[1]
+            return paged_windows(params, cache, windows, page_rows, told,
+                                 "none", ad, ad_rows)[1]
 
-        def paged_last_admit(params, cache, window, page_row, pos,
-                             last_idx, key, tokens, finished, remaining,
-                             slot_idx, length, budget, ad, ad_row,
-                             valid=None):
-            """Last prefill window + first-token sample + slot arm in
-            ONE dispatch.  The prompt's K/V already live in
-            the request's pages — admission just points the slot's
-            column state at them (the page-table row is host state,
-            handed to the next tick)."""
-            logits, cache = paged_window(
-                params, cache, window, page_row, pos, "all", ad, ad_row,
-                slot_idx, valid)
-            tok, key, tokens, finished, remaining = first_token(
-                logits, last_idx, key, tokens, finished, remaining,
-                slot_idx, budget)
+        def paged_last_admit(params, cache, windows, page_rows, told, key,
+                             tokens, finished, remaining, ad, ad_rows):
+            """A batch of windows of which some are LAST ones (``_ADMIT``)
+            + their first-token samples + slot arms in ONE dispatch.  The
+            prompts' K/V already live in the requests' pages — admission
+            just points each slot's column state at them (the page-table
+            rows are host state, handed to the next tick).  Returns the
+            rows' tokens (an admitting row's is its first token)."""
+            logits, cache = paged_windows(
+                params, cache, windows, page_rows, told, "last", ad,
+                ad_rows)
+            tok, slot, key, tokens, finished, remaining = first_tokens(
+                logits, told, key, tokens, finished, remaining)
+            length = told[:, _LENGTH]
             cache = dict(
                 cache,
-                start_col=cache["start_col"].at[slot_idx].set(
-                    jnp.int32(0)),
-                write_col=cache["write_col"].at[slot_idx].set(length),
-                positions=cache["positions"].at[slot_idx].set(length))
+                start_col=cache["start_col"].at[slot].set(
+                    0, mode="drop"),
+                write_col=cache["write_col"].at[slot].set(
+                    length, mode="drop"),
+                positions=cache["positions"].at[slot].set(
+                    length, mode="drop"))
             cache, tok = hand_out_counters(cache, tok)
             return tok, cache, tokens, finished, remaining, key
 
@@ -777,9 +828,16 @@ class SlotScheduler:
             return {k: v.at[:, page].set(payload[k])
                     for k, v in kv.items()}
 
-        self._win_mid = jax.jit(paged_win_mid, donate_argnums=(1,))
-        self._last_admit = jax.jit(paged_last_admit,
-                                   donate_argnums=(1, 6, 7, 8, 9))
+        # the window programs' row counts, picked per dispatch from the
+        # size of the group at hand.  A jitted callable a rung, so that
+        # each still traces ONCE (the runtime sanitizer's budget of one
+        # is the proof that nothing compiles after construction)
+        self._rungs = _window_rungs(num_slots)
+        self._win_mid = {rows: jax.jit(paged_win_mid, donate_argnums=(1,))
+                         for rows in self._rungs}
+        self._last_admit = {
+            rows: jax.jit(paged_last_admit, donate_argnums=(1, 5, 6, 7, 8))
+            for rows in self._rungs}
         self._tick = jax.jit(paged_tick, donate_argnums=(1, 3, 4, 5, 6))
         self._wire_gather = jax.jit(wire_gather)
         self._wire_splice = jax.jit(wire_splice, donate_argnums=(0,))
@@ -788,14 +846,56 @@ class SlotScheduler:
         self._state_snapshot = jax.jit(state_snapshot,
                                        donate_argnums=(0, 1))
         self._state_restore = jax.jit(state_restore, donate_argnums=(0,))
+        # every rung is compiled NOW (through the persistent compile cache
+        # where there is one), so a rung first met under traffic is never
+        # a compile under traffic
+        self._compile_window_rungs()
+
+    def _padding_rows(self, rows: int) -> tuple:
+        """``(windows, page_rows, told, adapter rows)`` of a window
+        program at ``rows`` rows, every row padding: no real token, every
+        page the trash page, no slot named."""
+        pps = self.max_len // self.page_size
+        told = np.zeros((rows, 6), np.int32)
+        told[:, _SLOT] = self.num_slots
+        return (np.zeros((rows, self.prefill_chunk), np.int32),
+                np.zeros((rows, pps), np.int32), told,
+                None if self.adapters is None
+                else np.zeros((rows,), np.int32))
+
+    def _compile_window_rungs(self) -> None:
+        """One dispatch of each window program at each rung, on padding
+        rows: they write the trash page and nothing else (no slot's
+        state, no counter, no key split), so the scheduler is as it was,
+        with every shape it will ever dispatch compiled and in the jitted
+        callables' caches.  A scheduler built over shapes (the graph
+        tier's, a rehearsal's) has nothing to run them on."""
+        import jax
+        if not all(isinstance(x, (jax.Array, np.ndarray)) for x in
+                   jax.tree.leaves((self.params, self._cache))):
+            return
+        ad = None if self.adapters is None else self.adapters.arrays
+        for rows in self._rungs:
+            windows, page_rows, told, ad_rows = self._padding_rows(rows)
+            self._cache = self._win_mid[rows](
+                self.params, self._cache, windows, page_rows, told, ad,
+                ad_rows)
+            _, self._cache, self._tokens, self._finished, \
+                self._remaining, self._key = self._last_admit[rows](
+                    self.params, self._cache, windows, page_rows, told,
+                    self._key, self._tokens, self._finished,
+                    self._remaining, ad, ad_rows)
 
     # ------------------------------------------------ graph-tier targets
 
     def graph_targets(self, hbm_budget: Optional[int] = None) -> list:
         """The three hot executables as dtlint graph-tier trace targets
         (``analysis/graph.py``): abstract shape/dtype specs matching
-        exactly what ``_advance_prefill``/``_decode_dispatch`` pass, so
-        the DT4xx rules and the DT405 census lint the REAL programs.  Kept
+        exactly what ``_advance_group``/``_decode_dispatch`` pass, so
+        the DT4xx rules and the DT405 census lint the REAL programs — the
+        two window programs at the LARGEST rung of the ladder (the most
+        rows, the most temporaries; the smaller rungs are the same
+        programs at fewer rows, compiled at construction with it).  Kept
         in this file so the specs cannot drift from the call sites
         without the diff showing both.  Serializes against the pump
         (shape/dtype reads of buffers a running tick donates)."""
@@ -806,8 +906,8 @@ class SlotScheduler:
                 lambda x: jax.ShapeDtypeStruct(
                     tuple(getattr(x, "shape", ())), x.dtype), tree)
 
-        i32 = jax.ShapeDtypeStruct((), np.int32)
-        win = jax.ShapeDtypeStruct((1, self.prefill_chunk), np.int32)
+        top = self._rungs[-1]
+        win, prow, told, row_ad = sds(self._padding_rows(top))
         with self._pump_lock:
             params, cache = sds(self.params), sds(self._cache)
             toks, fin = sds(self._tokens), sds(self._finished)
@@ -815,22 +915,18 @@ class SlotScheduler:
             snaps = sds(self._snaps)
             ad, ad_rows = self._adapter_args()
         ad = sds(ad) if ad is not None else None
-        row1 = (jax.ShapeDtypeStruct((1,), np.int32)
-                if ad_rows is not None else None)
         rows = sds(ad_rows) if ad_rows is not None else None
         pps = self.max_len // self.page_size
-        prow = jax.ShapeDtypeStruct((pps,), np.int32)
         tab = jax.ShapeDtypeStruct((self.num_slots, pps), np.int32)
-        told = self._window_told(i32, i32)
         targets = [
             graph_lib.Target(
-                "prefill_window", self._win_mid,
-                (params, cache, win, prow, i32, ad, row1) + told,
+                "prefill_window", self._win_mid[top],
+                (params, cache, win, prow, told, ad, row_ad),
                 hbm_budget=hbm_budget),
             graph_lib.Target(
-                "admit", self._last_admit,
-                (params, cache, win, prow, i32, i32, key, toks,
-                 fin, rem, i32, i32, i32, ad, row1) + told[1:],
+                "admit", self._last_admit[top],
+                (params, cache, win, prow, told, key, toks, fin, rem, ad,
+                 row_ad),
                 hbm_budget=hbm_budget),
             graph_lib.Target(
                 "decode_tick", self._tick,
@@ -979,6 +1075,8 @@ class SlotScheduler:
                 last_tick_end_s=self._tick_end_t,
                 last_tick_duration_s=self._last_tick_s,
                 prefill_windows_total=self._prefill_windows,
+                prefill_dispatches_total=self._prefill_dispatches,
+                prefill_rows_padded_total=self._prefill_rows_padded,
                 decode_steps_total=self._decode_steps,
                 decode_pages_walked_total=self._decode_pages_walked,
                 decode_pages_table_total=self._decode_pages_table,
@@ -1026,8 +1124,9 @@ class SlotScheduler:
     def step(self) -> bool:
         """One tick: retire expired deadlines, advance every in-flight
         prefill by one window (starting new prefills for free slots
-        first), then one decode dispatch over the slots.  Returns False
-        when fully idle.
+        first; the tick's windows go to the device together, a program a
+        group of at most ``_rungs[-1]`` rows), then one decode dispatch
+        over the slots.  Returns False when fully idle.
 
         Thread-safe: ticks are serialized by the pump mutex (concurrent
         callers queue behind the running tick); ``submit``/``cancel``/
@@ -1067,7 +1166,10 @@ class SlotScheduler:
         """The tick's layer boundaries, one span each (children of
         ``serve.tick``; docs/OBSERVABILITY.md has the table).  The spans
         whose length the critpath ledger accrues are ``timed``: that one
-        measurement is the span's and the phase's."""
+        measurement is the span's and the phase's.  A ``serve.prefill``
+        span is a GROUP's — the windows one program holds, or the read of
+        one program's first tokens — and names its requests
+        (``trace_ids``), over which its time is split."""
         did = False
         outbox: List[tuple] = []     # tick-ordered deliveries/finishes
         with trace_lib.span("serve.housekeeping"):
@@ -1090,25 +1192,35 @@ class SlotScheduler:
         # window cost, win_by_req keys each request's OWN share (and
         # doubles as "prefilled this tick", which exempts a request
         # admitted mid-tick from interference: it was not yet decoding
-        # when the windows ran).  A window's span is its DISPATCH: the
+        # when the windows ran).  A group's span is its DISPATCH: the
         # device's time for it lands in the tick's reads and its fetch.
         prefill_s = 0.0
         windows = 0
         win_by_req: Dict[int, float] = {}
 
-        def charge(req: Request, dt: float) -> None:
+        def charge(reqs: List[Request], dt: float) -> None:
+            """A group's ``serve.prefill`` time, split over the requests
+            in it."""
             nonlocal prefill_s
             prefill_s += dt
-            win_by_req[id(req)] = win_by_req.get(id(req), 0.0) + dt
-            if req.phases is not None:
-                req.phases["prefill_compute"] += dt
+            for req in reqs:
+                share = dt / len(reqs)
+                win_by_req[id(req)] = win_by_req.get(id(req), 0.0) + share
+                if req.phases is not None:
+                    req.phases["prefill_compute"] += share
 
-        def window_of(st: _Prefill) -> int:
-            with trace_lib.timed("serve.prefill",
-                                 trace_id=st.req.trace_id) as window:
-                n = self._advance_prefill(st, firsts)
-            charge(st.req, window.duration_s)
-            return n
+        def windows_of(sts: List[_Prefill]) -> int:
+            """One window for each of ``sts``, a program a group of at
+            most the ladder's largest rung; returns the windows run."""
+            ran = 0
+            top = self._rungs[-1]
+            for k in range(0, len(sts), top):
+                with trace_lib.timed("serve.prefill") as group:
+                    reqs = self._advance_group(sts[k:k + top], firsts)
+                    group.set(trace_ids=[r.trace_id for r in reqs])
+                charge(reqs, group.duration_s)
+                ran += len(reqs)
+            return ran
 
         # The tick keeps the device's queue from running empty: admitting
         # windows go last of the windows, their tokens are read only after
@@ -1116,15 +1228,20 @@ class SlotScheduler:
         # tick would open with are dispatched behind the decode program
         # (``ran_ahead``), so deliveries, admissions and the caller's own work
         # between ticks run beside a busy device.  A request still gets one
-        # window a tick, in the same place of the device's stream.
-        firsts: List[tuple] = []     # admitting windows, tokens unread
-        pending.sort(key=lambda st: st.next == len(st.windows) - 1)
+        # window a tick, in the same place of the device's stream; the
+        # windows of each of the two places go out together, so the program
+        # that holds the tick's admitting windows is the last one before
+        # the decode program.
+        firsts: List[tuple] = []     # admitting groups, tokens unread
+        opening = []
         for st in pending:
             did = True
             if st.ran_ahead:
                 st.ran_ahead = False     # behind the last decode
-                continue
-            windows += window_of(st)
+            else:
+                opening.append(st)
+        opening.sort(key=lambda st: st.next == len(st.windows) - 1)
+        windows += windows_of(opening)
         with self._lock:
             active = sum(r is not None for r in self._slots)
         decoded = None
@@ -1134,14 +1251,16 @@ class SlotScheduler:
             with self._lock:
                 ahead = [st for st in self._prefills
                          if st.next < len(st.windows) - 1]
+            windows += windows_of(ahead)
             for st in ahead:
-                windows += window_of(st)
                 st.ran_ahead = True
-        for st, tok, slot in firsts:
-            with trace_lib.timed("serve.prefill",
-                                 trace_id=st.req.trace_id) as read:
-                self._first_token(st, tok, slot, outbox)
-            charge(st.req, read.duration_s)
+        for toks, rows, admitted in firsts:
+            with trace_lib.timed(
+                    "serve.prefill",
+                    trace_ids=[st.req.trace_id for st, _, _ in admitted]
+                    ) as read:
+                self._first_tokens(toks, rows, admitted, outbox)
+            charge([st.req for st, _, _ in admitted], read.duration_s)
         if decoded is not None:
             decoded = self._decode_fetch(*decoded)
         with trace_lib.span("serve.deliver") as deliver:
@@ -1301,8 +1420,7 @@ class SlotScheduler:
                 # window dispatches avoided by the prefix hit — the
                 # measured TTFT/FLOPs saving, reported via stats()
                 self._windows_skipped += max(0, -(-plen // w) - n_win)
-            return _Prefill(req, np.stack(rows).reshape(n_win, 1, w), 0,
-                            lease, plan, slot)
+            return _Prefill(req, np.stack(rows), 0, lease, plan, slot)
         except BaseException:
             # admission failed after the pin: pool exhaustion is the
             # common case, but begin() also raises ValueError for a
@@ -1345,47 +1463,106 @@ class SlotScheduler:
                 self._cache, self._snaps,
                 np.asarray([slot, row, src, dst], np.int32))
 
-    def _adapter_args(self, req: Optional[Request] = None):
-        """(table arrays, rows) for the executables — (None, None) when
-        adapters are off, so the compiled programs are identical to an
-        adapter-free build."""
+    def _adapter_args(self):
+        """(table arrays, the slots' rows) for the executables — (None,
+        None) when adapters are off, so the compiled programs are
+        identical to an adapter-free build.  A window program's rows are
+        its requests' own (``_advance_group``)."""
         if self.adapters is None:
             return None, None
-        if req is not None:   # batch-1 prefill window for one request
-            return self.adapters.arrays, np.asarray([req.adapter_row],
-                                                    np.int32)
         return self.adapters.arrays, self._adapter_rows
 
-    def _advance_prefill(self, st: _Prefill, firsts: List[tuple]) -> int:
-        """One window for one in-flight prefill; admits the request into
-        its slot on the last window, whose token stays on the device:
-        ``firsts`` gains ``(st, token, slot)`` for ``_first_token`` to read
-        once the tick's other work is dispatched.  Pump-only.  Returns
-        the windows dispatched (0 for a request cancelled cross-thread).
+    def _advance_group(self, group: List[_Prefill],
+                       firsts: List[tuple]) -> List[Request]:
+        """One window for each in-flight prefill of ``group`` (at most the
+        ladder's largest rung), all in ONE program: the smallest rung that
+        holds them, padded with rows that write the trash page alone.  A
+        request on its last window is admitted into its slot by the same
+        program, and its token stays on the device: ``firsts`` gains
+        ``(the program's tokens, rows, [(st, row, slot), ...])`` for
+        ``_first_tokens`` to read once the tick's other work is
+        dispatched.  Pump-only.  Returns the requests whose window ran (a
+        request cancelled cross-thread since the group was collected is
+        dropped from it).
 
-        The windows go straight into the request's leased pages
+        The windows go straight into the requests' leased pages
         (``decode_window_paged`` at ``pos = skip + i*W`` — a prefix hit
         starts past the shared pages, whose windows are simply never
         dispatched), so admission is column-state arming plus a host
-        page-table write; the request's full prompt pages are published
-        to the radix cache right after."""
-        req, lease, i = st.req, st.lease, st.next
+        page-table write; a request's full prompt pages are published to
+        the radix cache right after."""
+        live = []                        # (st, last window?, slot)
         with self._lock:
-            if st not in self._prefills:
-                return 0     # cancelled cross-thread: harvest recycles it
-        ad, ad_row = self._adapter_args(req)
-        last = i == len(st.windows) - 1
-        pos, real, snap_depth = st.plan[i]
-        state_args = self._window_told(
-            None if st.slot is None else np.int32(st.slot), np.int32(real))
-        ctx = req.context if req.context is not None else req.prompt
-        if not last:
-            with trace_lib.span("serve.prefill_dispatch",
-                                trace_id=req.trace_id, window=int(i),
-                                last=False):
-                self._cache = self._win_mid(
-                    self.params, self._cache, st.windows[i], lease.row,
-                    np.int32(pos), ad, ad_row, *state_args)
+            for st in group:
+                last = st.next == len(st.windows) - 1
+                if st not in self._prefills or (
+                        last and st.req.done.is_set()):
+                    continue     # cancelled cross-thread: harvest recycles
+                slot = st.slot
+                if last:
+                    self._prefills.remove(st)
+                    if slot is None:
+                        slot = self._slots.index(None)
+                    # reserve before the dispatch so the free-slot count
+                    # stays consistent for concurrent admissions and
+                    # stats(); admission rewrites the row, so a leftover
+                    # freeze mark from the slot's previous (cancelled)
+                    # occupant must not fire
+                    self._slots[slot] = st.req
+                    self._stale_rows.discard(slot)
+                live.append((st, last, slot))
+        if not live:
+            return []
+        rows = next(r for r in self._rungs if r >= len(live))
+        windows, page_rows, told, ad_rows = self._padding_rows(rows)
+        for r, (st, last, slot) in enumerate(live):
+            req = st.req
+            pos, real, _ = st.plan[st.next]
+            ctx = req.context if req.context is not None else req.prompt
+            windows[r] = st.windows[st.next]
+            page_rows[r] = st.lease.row
+            told[r] = (pos, real, self.num_slots if slot is None else slot,
+                       last, ctx.size, req.remaining_budget)
+            if ad_rows is not None:
+                ad_rows[r] = req.adapter_row
+                if last:
+                    self._adapter_rows[slot] = req.adapter_row
+        ad, _ = self._adapter_args()
+        admits = any(last for _, last, _ in live)
+        with trace_lib.span("serve.prefill_dispatch", rows=rows,
+                            real=len(live), last=admits):
+            if admits:
+                toks, self._cache, self._tokens, self._finished, \
+                    self._remaining, self._key = self._last_admit[rows](
+                        self.params, self._cache, windows, page_rows,
+                        told, self._key, self._tokens, self._finished,
+                        self._remaining, ad, ad_rows)
+            else:
+                self._cache = self._win_mid[rows](
+                    self.params, self._cache, windows, page_rows, told,
+                    ad, ad_rows)
+        with self._lock:
+            self._prefill_dispatches += 1
+            self._prefill_rows_padded += rows - len(live)
+        admitted = []
+        for r, (st, last, slot) in enumerate(live):
+            req, lease = st.req, st.lease
+            ctx = req.context if req.context is not None else req.prompt
+            if last:
+                if self._stateful:
+                    # publish the context's pages and snapshot the state
+                    # after its last token now, behind the admitting
+                    # window in the device's stream
+                    with trace_lib.span("serve.register"):
+                        self.pages.register(lease, ctx)
+                        self._snapshot_state(req, lease, ctx, slot,
+                                             "prompt_end")
+                with self._lock:
+                    # before the tick's decode dispatch copies the table
+                    self._page_tab[slot] = lease.row
+                admitted.append((st, r, slot))
+                continue
+            i = st.next
             req._windows += 1
             with self._lock:
                 st.next = i + 1
@@ -1393,64 +1570,34 @@ class SlotScheduler:
             if req.trace_id:
                 reqtrace.mark(req.trace_id, "prefill_window",
                               window=int(i))
+            snap_depth = st.plan[i][2]
             if snap_depth:
                 self._snapshot_state(req, lease, ctx[:snap_depth],
                                      st.slot, "chain_met")
-            return 1
-        with self._lock:
-            if st not in self._prefills or req.done.is_set():
-                return 0
-            self._prefills.remove(st)
-            slot = (st.slot if st.slot is not None
-                    else self._slots.index(None))
-            # reserve before the dispatch so the free-slot count stays
-            # consistent for concurrent admissions and stats(); admission
-            # rewrites the row, so a leftover freeze mark from the slot's
-            # previous (cancelled) occupant must not fire
-            self._slots[slot] = req
-            self._stale_rows.discard(slot)
-        if self._adapter_rows is not None:
-            self._adapter_rows[slot] = req.adapter_row
-        with trace_lib.span("serve.prefill_dispatch",
-                            trace_id=req.trace_id, window=int(i),
-                            last=True):
-            tok, self._cache, self._tokens, self._finished, \
-                self._remaining, self._key = self._last_admit(
-                    self.params, self._cache, st.windows[-1], lease.row,
-                    np.int32(pos), np.int32(real - 1), self._key,
-                    self._tokens, self._finished, self._remaining,
-                    np.int32(slot), np.int32(ctx.size),
-                    np.int32(req.remaining_budget), ad, ad_row,
-                    *state_args[1:])
-        if self._stateful:
-            # publish the context's pages and snapshot the state after its
-            # last token now, behind the admitting window in the device's
-            # stream
-            with trace_lib.span("serve.register"):
-                self.pages.register(lease, ctx)
-                self._snapshot_state(req, lease, ctx, slot, "prompt_end")
-        with self._lock:
-            # before the tick's decode dispatch copies the table
-            self._page_tab[slot] = lease.row
-        firsts.append((st, tok, slot))
-        return 1
+        if admitted:
+            firsts.append((toks, rows, admitted))
+        return [st.req for st, _, _ in live]
 
-    def _first_token(self, st: _Prefill, tok, slot: int,
+    def _first_tokens(self, toks, rows: int, admitted: List[tuple],
+                      outbox: List[tuple]) -> None:
+        """Read an admitting program's ``rows`` tokens off the device and
+        finish its admissions on the host; deliveries are queued on ``outbox``
+        (flushed at end of tick).  The tick's decode program and the next
+        tick's first windows are queued behind the program by now, so the
+        read waits beside a busy device: it is no barrier, and its span is
+        not named as the fetches that are (``serve.decode_fetch``)."""
+        with trace_lib.span("serve.first_token_read") as read:
+            # returns as the admitting program ends
+            toks, counters = self._split_read(toks, (rows,))
+            self._absorb_counters(counters, read)
+        for st, r, slot in admitted:
+            self._first_token(st, int(toks[r]), slot, outbox)
+
+    def _first_token(self, st: _Prefill, first: int, slot: int,
                      outbox: List[tuple]) -> None:
-        """Read an admitting window's token off the device and finish the
-        admission on the host; delivery is queued on ``outbox`` (flushed at
-        end of tick).  The tick's decode program and the next tick's first
-        windows are queued behind the window by now, so the read waits
-        beside a busy device: it is no barrier, and its span is not named
-        as the fetches that are (``serve.decode_fetch``)."""
+        """The host's part of one admission, its first token read."""
         req = st.req
         ctx = req.context if req.context is not None else req.prompt
-        with trace_lib.span("serve.first_token_read",
-                            trace_id=req.trace_id) as read:
-            # returns as the admitting window ends
-            tok, counters = self._split_read(tok, ())
-            first = int(tok)
-            self._absorb_counters(counters, read)
         req.first_token_time = time.perf_counter()
         req._windows += 1
         with trace_lib.span("serve.register"):
@@ -1554,15 +1701,6 @@ class SlotScheduler:
                 self.params, self._cache, tab, self._tokens,
                 self._finished, self._remaining, self._key, ad, ad_rows)
         return slots, em, mask, self._finished, dispatch.duration_s
-
-    def _window_told(self, slot, real) -> tuple:
-        """What a prefill window is told beyond its tokens, ``(slot,
-        real)``: a recurrent-state model's windows name the slot whose
-        state they advance and how many of their tokens are real; one
-        that only counts has no slot; a K/V-only model is told nothing."""
-        if self._stateful:
-            return slot, real
-        return (None, real) if self._counted else ()
 
     def _split_read(self, read, shape) -> tuple:
         """A program's tokens as a host array of ``shape`` (the host sync)
@@ -2327,10 +2465,13 @@ class SlotScheduler:
 # --------------------------------------------------- dtlint graph tier
 
 # The serving contract this whole file is built around: exactly THREE
-# hot executables, so admission/retirement never recompiles.  DT405
-# makes that a lint invariant — a fourth jitted program (or two of the
-# three collapsing into one) fails `scripts/lint.sh` statically instead
-# of surfacing as a RetraceGuard warning at serve time.
+# hot programs — the two window programs at the ladder's row counts
+# (``_window_rungs``: the same program at a few batch sizes, listed here
+# at the largest), and the decode tick — all compiled at construction, so
+# admission/retirement never recompiles.  DT405 makes that a lint
+# invariant — a fourth jitted program (or two of the three collapsing
+# into one) fails `scripts/lint.sh` statically instead of surfacing as a
+# RetraceGuard warning at serve time.
 graph_lib.expect_census("serve-hot", 3)
 
 

@@ -211,8 +211,15 @@ def test_tick_tree_self_times_sum_and_heartbeat_is_the_span(model_params,
                                         abs=1e-3)
         for c in kids[t]:
             if rows[c].name == "serve.prefill":
+                # a group's span: the windows one program holds, or the
+                # read of one program's first tokens; it names its requests
                 assert {rows[g].name for g in kids[c]} <= PREFILL_CHILDREN
-                assert rows[c].args["trace_id"]
+                assert rows[c].args["trace_ids"]
+                for g in kids[c]:
+                    if rows[g].name == "serve.prefill_dispatch":
+                        a = rows[g].args
+                        assert 1 <= a["real"] <= a["rows"] <= 2
+                        assert a["real"] == len(rows[c].args["trace_ids"])
     # the tick's args are the tick's counts
     args = [rows[t].args for t in ticks]
     assert [a["tick"] for a in args] == list(range(1, len(ticks) + 1))
@@ -329,7 +336,8 @@ def test_a_decoding_tick_leaves_the_device_no_empty_queue(model_params,
         lo = named["serve.decode_dispatch"].end_us
         hi = named["serve.decode_fetch"].start_us
         grand = [rows[g] for c in kids[t] for g in kids.get(c, ())
-                 if rows[c].args.get("trace_id") == second._req.trace_id]
+                 if second._req.trace_id in rows[c].args.get("trace_ids",
+                                                             ())]
         for g in grand:
             if g.name == "serve.first_token_read":
                 assert lo <= g.start_us and g.end_us <= hi
@@ -653,8 +661,9 @@ def test_router_counts_leave_the_device_inside_the_token_arrays(
                sched._counter_shapes) == counters
     _, gpt_admit, gpt_tick = outputs(*model_params)
     assert len(admit) == len(gpt_admit) and len(tick) == len(gpt_tick) == 3
-    assert (gpt_admit[0].shape, gpt_tick[1].shape) == ((), (2, 2))
-    assert (admit[0].shape, tick[1].shape) == ((1 + counters,),
+    # the admit program at its largest rung: a token a row (2 slots: 2)
+    assert (gpt_admit[0].shape, gpt_tick[1].shape) == ((2,), (2, 2))
+    assert (admit[0].shape, tick[1].shape) == ((2 + counters,),
                                                (2 * 2 + counters,))
     assert admit[0].dtype == tick[1].dtype == np.int32
     read = np.arange(4 + counters, dtype=np.int32)
