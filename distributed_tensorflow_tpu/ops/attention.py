@@ -34,7 +34,8 @@ __all__ = ["dot_product_attention", "causal_mask", "padding_mask",
            "MultiHeadAttention", "flash_wins", "resolve_use_flash",
            "paged_kernel_wins", "resolve_use_paged_kernel",
            "PagedWindows", "paged_windows", "paged_window_mask",
-           "last_real_position"]
+           "last_real_position", "differential_attention",
+           "ring_positions", "window_mask"]
 
 NEG_INF = -1e9  # finite -inf stand-in: keeps softmax well-defined in f32
 
@@ -172,6 +173,90 @@ def last_real_position(x: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     first where it has none), taken before the head matmul."""
     return jnp.take_along_axis(
         x, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)
+
+
+def ring_positions(last: jnp.ndarray, ring: int) -> jnp.ndarray:
+    """[b, ring] positions held by a per-row ring of ``ring`` entries
+    written at ``position % ring``, every position up to ``last`` [b] in
+    order: entry ``r`` holds the latest such position ``<= last``
+    (negative: never written)."""
+    r = jnp.arange(ring)
+    return last[:, None] - jnp.mod(last[:, None] - r[None, :], ring)
+
+
+def window_mask(query_pos: jnp.ndarray, key_pos: jnp.ndarray,
+                window: int) -> jnp.ndarray:
+    """[b, sq, t] additive mask: the query at position p [b, sq] sees the
+    keys at positions ``p - window + 1 .. p`` among ``key_pos`` [b, t]
+    (never a negative one)."""
+    k, q = key_pos[:, None, :], query_pos[:, :, None]
+    return jnp.where((k <= q) & (k > q - window) & (k >= 0), 0.0, NEG_INF)
+
+
+def differential_attention(q: jnp.ndarray, k_rows: jnp.ndarray,
+                           v_rows: jnp.ndarray, lam, mask: jnp.ndarray,
+                           scale: float) -> jnp.ndarray:
+    """Differential attention over flat K/V rows -> ``[b, sq, heads / 2,
+    2 * head_dim]`` float32, before the heads' norm.
+
+    ``q`` [b, sq, heads, head_dim]: query heads ``2i`` and ``2i + 1`` are
+    differential head ``i``'s two maps; ``k_rows`` / ``v_rows`` [b, t,
+    kv_heads * head_dim]; ``mask`` [b, sq, t] additive; ``lam`` a scalar.
+    Differential head ``i`` reads the pair of K/V heads ``j = i // g``
+    (``g = heads / kv_heads``): its first map scores against K head ``2j``,
+    its second against ``2j + 1``, both weigh the pair's values ``[v_2j,
+    v_2j+1]``, and ``o_i = softmax_1 V - lam softmax_2 V``.
+
+    Two forms of the same arithmetic, chosen by the query block's length.
+    A block of queries (a prefill window, the full forward) reads the rows
+    as ``[t, pairs, 2 * head_dim]``: a pair's two K heads are one 128-lane
+    block, a query vector is placed in its K head's half of the block
+    (zeros in the other), which doubles the score matmul, and the rows are
+    re-laid out once for it.  A single query (a decode step, whose rows are
+    every slot's whole gathered cache) reads the rows as they are: each
+    query vector is placed in its K head's lanes of the whole row, so the
+    matmuls are ``kv_heads`` (scores) and ``pairs`` (values) times larger
+    than needed and no row is moved."""
+    b, sq, heads, hd = q.shape
+    pairs = k_rows.shape[-1] // (2 * hd)
+    group = heads // (2 * pairs)
+    pair_of = jnp.arange(heads) // 2 // group        # a query head's pair
+    k, v = k_rows.astype(q.dtype), v_rows.astype(q.dtype)
+    with jax.named_scope("diff_attention"):
+        if sq == 1:
+            kv_head = 2 * pair_of + jnp.arange(heads) % 2
+            owner = jax.nn.one_hot(kv_head, 2 * pairs, dtype=q.dtype)
+            q = (q[:, :, :, None, :] * owner[None, None, :, :, None]
+                 ).reshape(b, sq, heads, 2 * pairs * hd)
+            scores = jnp.einsum("bqhc,btc->bhqt", q, k,
+                                preferred_element_type=jnp.float32)
+            probs = jax.nn.softmax(scores * scale + mask[:, None],
+                                   axis=-1).astype(q.dtype)
+            ctx = jnp.einsum("bhqt,btc->bqhc", probs, v,
+                             preferred_element_type=jnp.float32)
+            ctx = jnp.take_along_axis(
+                ctx.reshape(b, sq, heads, pairs, 2 * hd),
+                pair_of[None, None, :, None, None], axis=3)
+            ctx = ctx.reshape(b, sq, heads // 2, 2, 2 * hd)
+        else:
+            # [b, sq, pair, group, map, 2 hd]: map c in lanes c*hd ..
+            q = q.reshape(b, sq, pairs, group, 2, hd)
+            zero = jnp.zeros_like(q[..., 0, :])
+            q = jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1),
+                           jnp.concatenate([zero, q[..., 1, :]], -1)],
+                          axis=-2)
+            k = k.reshape(b, -1, pairs, 2 * hd)
+            v = v.reshape(b, -1, pairs, 2 * hd)
+            scores = jnp.einsum("bqpgcl,btpl->bpgcqt", q, k,
+                                preferred_element_type=jnp.float32)
+            probs = jax.nn.softmax(
+                scores * scale + mask[:, None, None, None],
+                axis=-1).astype(q.dtype)
+            ctx = jnp.einsum("bpgcqt,btpl->bqpgcl", probs, v,
+                             preferred_element_type=jnp.float32
+                             ).reshape(b, sq, heads // 2, 2, 2 * hd)
+        return ctx[:, :, :, 0] - jnp.asarray(lam, jnp.float32) \
+            * ctx[:, :, :, 1]
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:

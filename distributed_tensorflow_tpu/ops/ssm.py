@@ -22,6 +22,20 @@ front of it sits a short depthwise causal convolution whose last
 * :func:`causal_conv1d` — the convolution as shifted multiply-adds over the
   carried inputs followed by the new ones.
 
+The Mamba-1 selective scan keeps per channel ``c`` a state vector of
+``d_state`` entries, each with its own decay,
+
+    h_t[:, c] = exp(delta_t[c] * A[:, c]) * h_{t-1}[:, c]
+                + delta_t[c] * x_t[c] * B_t
+    y_t[c] = h_t[:, c] . C_t
+
+so one scalar decay per head, which the chunked matrix form above rests
+on, does not hold.  Two forms: :func:`selective_step` (one token for every
+row, the decode step) and :func:`selective_scan` (a sequential scan of that
+step over a block, the prefill window and the full forward).  The state is
+laid out ``[d_state, channels]``: 16 rows of 5,120 lanes tile without
+padding where 16 lanes would be padded to 128.
+
 Padding: a position whose ``dt`` is 0 leaves ``H`` exactly as it was
 (``exp(0) = 1``, ``0 * x = 0``), which is how a right-padded prefill
 window and a frozen decode row keep their state; the convolution's state
@@ -37,8 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["causal_conv1d", "gated_rms_norm", "softplus_dt", "ssd_chunked",
-           "ssd_step"]
+__all__ = ["causal_conv1d", "gated_rms_norm", "selective_scan",
+           "selective_step", "softplus_dt", "ssd_chunked", "ssd_step"]
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -138,6 +152,36 @@ def ssd_chunked(x, dt, a, b_in, c_in, h0, chunk: int):
                                    chunks(c_in)))
         y = jnp.moveaxis(y, 0, 1).reshape((b, s + pad) + y.shape[3:])
         return y[:, :s], h
+
+
+def selective_step(x, dt, a, b_in, c_in, h):
+    """One token of the Mamba-1 recurrence for every row.
+
+    ``x`` [b, c]; ``dt`` [b, c] (0 freezes the row); ``a`` [n, c]
+    (negative); ``b_in``/``c_in`` [b, n]; ``h`` [b, n, c] float32.  Returns
+    ``(y [b, c] float32, new h)``, ``y`` without the skip ``D x``."""
+    with jax.named_scope("ssm_selective_scan"):
+        x, dt = x.astype(F32), dt.astype(F32)
+        decay = jnp.exp(dt[:, None, :] * a.astype(F32)[None])
+        h = decay * h + (dt * x)[:, None, :] * b_in.astype(F32)[:, :, None]
+        y = jnp.sum(h * c_in.astype(F32)[:, :, None], axis=1)
+    return y, h
+
+
+def selective_scan(x, dt, a, b_in, c_in, h0):
+    """The Mamba-1 recurrence over a block, token by token.
+
+    ``x`` / ``dt`` [b, s, c] (``dt`` 0 on padding); ``a`` [n, c];
+    ``b_in`` / ``c_in`` [b, s, n]; ``h0`` [b, n, c] float32, the state
+    before the first token.  Returns ``(y [b, s, c] float32, state after the
+    last token)``."""
+    def body(h, inputs):
+        y, h = selective_step(*inputs[:2], a, *inputs[2:], h)
+        return h, y
+
+    h, y = lax.scan(body, h0, tuple(jnp.moveaxis(t, 1, 0)
+                                    for t in (x, dt, b_in, c_in)))
+    return jnp.moveaxis(y, 0, 1), h
 
 
 def gated_rms_norm(y, z, gamma, eps: float):

@@ -170,6 +170,11 @@ class ServeMetrics:
             "dttpu_serve_decode_pages_table_total",
             "Page-table entries those steps' tables hold (steps x slots "
             "x pages a slot): what a full-table read takes.")
+        self.decode_window_tokens = reg.counter(
+            "dttpu_serve_decode_window_tokens_total",
+            "Keys the window layers' decode reads covered (models with "
+            "sliding-window layers): min(position + 1, window) a live "
+            "slot's step.")
         self.admit_backpressure = reg.counter(
             "dttpu_serve_admit_backpressure_total",
             "Admissions bounced back to the queue: every adapter row "
@@ -201,6 +206,7 @@ class ServeMetrics:
             [self.decode_steps, "decode_steps_total", 0],
             [self.decode_pages_walked, "decode_pages_walked_total", 0],
             [self.decode_pages_table, "decode_pages_table_total", 0],
+            [self.decode_window_tokens, "decode_window_tokens_total", 0],
             [self.admit_backpressure, "admit_backpressure_total", 0]]
         # an expert layer's router statistics (scheduler.py
         # ``_absorb_counters``): created at first sight of a pick, so a

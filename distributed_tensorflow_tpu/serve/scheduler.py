@@ -324,6 +324,10 @@ class EngineStats:
     # pages a live slot's tokens lie on, the gather read all of them
     decode_pages_walked_total: int = 0
     decode_pages_table_total: int = 0
+    # models with sliding-window layers only: the keys those steps' window
+    # reads cover, each live slot's step at position p reading min(p + 1,
+    # window) (a window layer's read; every such layer reads as many)
+    decode_window_tokens_total: int = 0
     admit_backpressure_total: int = 0        # admissions bounced + requeued
     # expert-layer models only (the device's own counts, read with the
     # tick's fetches: models/longcat_flash.py ``counters``): the router's
@@ -499,6 +503,7 @@ class SlotScheduler:
         self._decode_steps = 0
         self._decode_pages_walked = 0
         self._decode_pages_table = 0
+        self._decode_window_tokens = 0
         self._admit_backpressure = 0
         self.metrics = metrics if metrics is not None else _NullMetrics()
         self.adapters = adapters
@@ -522,6 +527,9 @@ class SlotScheduler:
             (model.paged_cache_spec().get("counters") or {}).items())
         self._counted = bool(self._counter_shapes)
         self._router_counts = None
+        # a model with sliding-window layers says how many keys a query of
+        # theirs sees (models/sambay.py): the decode dispatch counts them
+        self._attention_window = getattr(model, "attention_window", None)
         if not getattr(model, "paged_kernel_ok", True):
             if use_paged_kernel is True:
                 raise ValueError(
@@ -1080,6 +1088,7 @@ class SlotScheduler:
                 decode_steps_total=self._decode_steps,
                 decode_pages_walked_total=self._decode_pages_walked,
                 decode_pages_table_total=self._decode_pages_table,
+                decode_window_tokens_total=self._decode_window_tokens,
                 admit_backpressure_total=self._admit_backpressure)
             if self._router_counts is not None:
                 held = self._router_counts[:, :-2]
@@ -1673,6 +1682,21 @@ class SlotScheduler:
                 walked += min(pps, -(-(cols + j) // self.page_size))
         return walked, table
 
+    def _decode_window_reads(self, slots: List[Optional[Request]]) -> int:
+        """Keys a window layer's queries read in one dispatch over
+        ``slots``: ``min(p + 1, window)`` for each step a live slot takes at
+        position ``p``, for as many steps as its budget keeps it live, as
+        ``_decode_walk`` counts them."""
+        reads = 0
+        for req in slots:
+            if req is None:
+                continue
+            fed = max(1, len(req.tokens) - req.resumed)
+            pos = _consumed(req)
+            for j in range(min(self.tick_steps, req.remaining_budget - fed)):
+                reads += min(pos + j + 1, self._attention_window)
+        return reads
+
     def _decode_dispatch(self, active: int) -> tuple:
         """One K-step decode dispatch over the slots; what ``_decode_fetch``
         takes: ``(slots, emitted, mask, dispatch_s)``, the tokens still on
@@ -1686,11 +1710,17 @@ class SlotScheduler:
             walked, table = self._decode_walk(slots)
             self._decode_pages_walked += walked
             self._decode_pages_table += table
+            window = (self._decode_window_reads(slots)
+                      if self._attention_window else None)
+            if window is not None:
+                self._decode_window_tokens += window
         ad, ad_rows = self._adapter_args()
         with trace_lib.timed("serve.decode_dispatch",
                              steps=self.tick_steps, active=active,
                              pages_walked=walked,
                              pages_table=table) as dispatch:
+            if window is not None:
+                dispatch.set(window_tokens=window)
             if trace_lib.active_tracer() is not None:
                 # tokens the live slots have in their caches as the
                 # dispatch starts: what a byte count of the step is made of

@@ -3,7 +3,8 @@
 Two halves, neither of which shows anything alone:
 
 * the model methods (``GPT`` through the gather read and through the page
-  kernel in interpret mode, ``HybridDecoder``, ``LongcatFlash``): ``n``
+  kernel in interpret mode, ``HybridDecoder``, ``LongcatFlash``,
+  ``SambaY``): ``n``
   windows of different requests at different ``pos`` / ``valid`` in ONE
   ``decode_window_paged`` call equal the same windows called one by one —
   logits of the rows, every written pool cell, every state row, the
@@ -26,12 +27,13 @@ from distributed_tensorflow_tpu import serve
 from distributed_tensorflow_tpu.models.gpt import gpt_tiny
 from distributed_tensorflow_tpu.models.hybrid import hybrid_tiny
 from distributed_tensorflow_tpu.models.longcat_flash import longcat_flash_tiny
+from distributed_tensorflow_tpu.models.sambay import sambay_tiny
 from distributed_tensorflow_tpu.obs import reqtrace
 from distributed_tensorflow_tpu.obs import trace as trace_lib
 from distributed_tensorflow_tpu.serve import pages as pages_lib
 
 W, PG, PPS, SLOTS = 8, 8, 6, 5           # window, page, pages a row, slots
-KINDS = ["gpt_gather", "gpt_kernel", "hybrid", "longcat"]
+KINDS = ["gpt_gather", "gpt_kernel", "hybrid", "longcat", "sambay"]
 # (first column, real tokens) of the batch's windows: a sequence's start, a
 # start that is no page or window boundary, a short last window; rows 3 and
 # 4 of the batch are padding
@@ -53,7 +55,9 @@ def models():
                            max_position=PG * PPS)),
                        ("longcat", lambda: longcat_flash_tiny(
                            max_position=PG * PPS, experts_held=4,
-                           expert_offset=2))):
+                           expert_offset=2)),
+                       ("sambay", lambda: sambay_tiny(
+                           max_position=PG * PPS))):
         model = make()
         built[kind] = (model, model.init(jax.random.PRNGKey(0)))
     return built

@@ -552,3 +552,96 @@ def test_latent_attention_expert_programs_fit_a_v5e_at_published_width(topo):
         _fits_beside(LONGCAT_IN_USE, memory, target.name)
         assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 < 15.75e9), (target.name, memory)
+
+
+# what the decoder-hybrid-decoder's deployment holds on the chip (weights
+# 7.71 GB, layer 17's pool 1.34, 32 slot blocks 0.82, 40 snapshot rows 1.02)
+# and the gathered view of its shared plane beside them: the configuration's
+# 12.2 GB
+SAMBAY_IN_USE = 12.2e9
+
+
+def test_decoder_hybrid_decoder_programs_fit_a_v5e_at_published_width(topo):
+    """The three hot programs and the two state copies of the SambaY
+    decoder-hybrid-decoder at the benchmark's published widths and
+    deployment (32 slots x 8192 tokens), compiled for the described chip
+    from shapes alone.  The window layers' K/V live in rings of 544 rows in
+    the slot block and nowhere else: the pool has ONE plane, layer 17's.
+    The view of that plane is gathered once a program — a K and a V gather
+    in the decode tick and in the admitting windows, read by layer 17 and
+    the seven cross layers alike — and not at all in the mid windows, whose
+    outputs no layer past 17 reaches (the compiler drops those layers).  No
+    program copies the pool, a state leaf or a ring, the decode tick's
+    single queries read the 1.34 GB view as it lies (the admitting windows'
+    blocks of queries re-lay their 8-row view out once, 0.34 GB), and the
+    temporaries fit beside what the deployment holds."""
+    import json
+    from chip_smoke import pool_moves
+    from distributed_tensorflow_tpu.models.sambay import SambaY, SambaYConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        c = json.load(f)
+    a = c["assumed"]
+    model = SambaY(SambaYConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        num_layers=c["num_hidden_layers"],
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"],
+        intermediate_size=c["intermediate_size"],
+        sliding_window=c["sliding_window"], d_state=a["mamba_d_state"],
+        d_conv=a["mamba_d_conv"], expand=a["mamba_expand"],
+        dt_rank=a["mamba_dt_rank"], max_position=c["serve"]["max_len"],
+        param_dtype=jnp.bfloat16))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    weights_gb = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(params)) / 1e9
+    assert 7.70 < weights_gb < 7.71                  # 3,852,562,944 x 2 B
+    sched = _scheduler_from_shapes(model, params,
+                                   num_slots=c["serve"]["num_slots"],
+                                   max_len=c["serve"]["max_len"])
+    cache = sched._cache
+    pool = cache["kv"]["k"]
+    assert pool.shape == (1, 32 * 512 + 1, 16, 1280)
+    assert {k: v.shape for k, v in cache["state"].items()} == {
+        "ssm": (9, 32, 16, 5120), "conv": (9, 32, 3, 5120),
+        "window_k": (8, 32, 544, 1280), "window_v": (8, 32, 544, 1280)}
+    assert sched._snaps["window_k"].shape == (8, 40, 544, 1280)
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    targets = sched.graph_targets()
+    assert [t.name for t in targets] == ["prefill_window", "admit",
+                                         "decode_tick", "state_snapshot",
+                                         "state_restore"]
+    assert targets[0].args[2].shape == (8, 32)       # 8 windows of 32
+    view = {"prefill_window": 8, "admit": 8, "decode_tick": 32}
+    for target in targets:
+        compiled = target.fn.lower(*place(target.args)).compile()
+        text, memory = compiled.as_text(), compiled.memory_analysis()
+        assert KERNEL_MARK not in text, target.name
+        copied = [line for line in text.splitlines() if re.search(
+            r"= bf16\[(1,16385,16|8,32,544|32,544|32,8192),1280\]\S* "
+            r"copy\(|= f32\[9,32,16,5120\]\S* copy\(", line)]
+        assert copied == [], (target.name, copied[:2])
+        if target.name in view:
+            gathers = re.findall(
+                rf"= bf16\[{view[target.name]},512,16,1280\]\S* gather\(",
+                text)
+            mid = target.name == "prefill_window"
+            assert len(gathers) == (0 if mid else 2), target.name
+            # the device scopes a trace names the work by; the mid windows
+            # hold no shared read and no memory unit
+            for scope in ("ssm_selective_scan", "diff_attention",
+                          "window_attention", "shared_attention", "gmu"):
+                assert (scope in text) != (
+                    mid and scope in ("shared_attention", "gmu")), (
+                    target.name, scope)
+            for leaf in (pool, cache["state"]["window_k"]):
+                moves = pool_moves(text, leaf.shape)
+                assert target.name == "decode_tick" or moves == [], (
+                    target.name, moves)
+        assert memory.temp_size_in_bytes < 1.6e9, (target.name, memory)
+        _fits_beside(SAMBAY_IN_USE, memory, target.name)
+        assert memory.alias_size_in_bytes > 2.1e9, (target.name, memory)
