@@ -142,6 +142,10 @@ class ServeMetrics:
             "dttpu_serve_kv_pool_tiled_bytes",
             "Bytes the page pool's K/V leaves take once tiled on the "
             "TPU.")
+        self.weights_relaid_bytes = reg.gauge(
+            "dttpu_serve_weights_relaid_bytes",
+            "Bytes of weight leaves the scheduler placed in another format "
+            "than the one they came in: the one its decode program reads.")
         # what the pump dispatched (scheduler.py counts each where it
         # happens): over ticks, windows and decode steps a tick
         self.ticks = reg.counter(
@@ -309,6 +313,7 @@ class ServeMetrics:
         self.state_snapshot_bytes.set(stats.state_snapshot_bytes)
         self.kv_pool_bytes.set(stats.kv_pool_bytes)
         self.kv_pool_tiled_bytes.set(stats.kv_pool_tiled_bytes)
+        self.weights_relaid_bytes.set(stats.weights_relaid_bytes)
         self.pages_per_request.set(stats.pages_per_request)
         for entry in self._by_delta:
             counter, field, last = entry

@@ -88,6 +88,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import logging
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -105,6 +106,8 @@ from .adapters import AdapterTableFull
 
 __all__ = ["EngineStats", "QueueFullError", "Request", "RequestSnapshot",
            "SlotScheduler"]
+
+log = logging.getLogger(__name__)
 
 
 class QueueFullError(RuntimeError):
@@ -290,6 +293,10 @@ class EngineStats:
     # TPU's tiling makes of it (pages.kv_pool_bytes; fixed at build)
     kv_pool_bytes: int = 0
     kv_pool_tiled_bytes: int = 0
+    # the weight leaves the scheduler put in another format than the one
+    # they came in: the decode tick's choice (``_place_weights``; fixed at
+    # build, 0 where every leaf came as the programs read it)
+    weights_relaid_bytes: int = 0
     prefill_windows_skipped_total: int = 0   # window dispatches avoided
     # prefix-affinity placement inputs (fleet/router.py): the pool's
     # bounded hot-chain digest (chain hash -> cached tokens, already a
@@ -421,6 +428,86 @@ def _window_rungs(num_slots: int) -> Tuple[int, ...]:
     two padding rows, PERF.md section 6, PR 37)."""
     top = min(num_slots, _MAX_WINDOW_ROWS)
     return tuple(r for r in (1, 4) if r < top) + (top,)
+
+
+def _shapes(tree, carry: str = ""):
+    """``tree``'s leaves as ``jax.ShapeDtypeStruct``; ``carry`` ``"sharding"``
+    or ``"format"`` keeps each leaf's sharding, or its sharding and layout,
+    where it has one (a program lowered with them is the one a dispatch on
+    the arrays compiles)."""
+    import jax
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            tuple(getattr(x, "shape", ())), x.dtype,
+            sharding=getattr(x, carry, None) if carry else None), tree)
+
+
+def _layout_of(x):
+    """A weight leaf's own layout: an array's, or for a shape placed on a
+    device with none given, the one that device gives such an array."""
+    from jax.experimental.layout import Layout
+    fmt = x.format
+    if fmt.layout is not None:
+        return fmt.layout
+    dev = next(iter(fmt.sharding.device_set))
+    return Layout.from_pjrt_layout(dev.client.get_default_layout(
+        x.dtype, fmt.sharding.shard_shape(x.shape), dev))
+
+
+def _relay(leaves, formats) -> tuple:
+    """``leaves`` (device arrays) copied into ``formats``, by one program
+    compiled here and kept out of the persistent compile cache.  An
+    executable read back from that cache reports its outputs in the
+    default format (jax 0.9): the copies it returned would be labelled in
+    a format they are not in, and every program compiled for them would
+    expect other bytes than they hold (on a TPU, a size check refuses the
+    dispatch).  ``jax.device_put`` to a format is such a program, cached
+    like any other.  The copy compiles in well under a second."""
+    import jax
+    from jax._src import config
+
+    def relaid(*xs):
+        return xs
+
+    with config.persistent_cache_min_compile_time_secs(float("inf")):
+        return jax.jit(relaid, out_shardings=tuple(formats))(*leaves)
+
+
+class _AutoFormatProgram:
+    """The decode tick: a jitted callable that leaves the weights' formats
+    to the compiler (``Layout.AUTO``), compiled ONCE, by ``compile``, and
+    dispatched as that executable.  A jitted callable refuses a committed
+    array where it was told ``AUTO``, and one told the formats the compiler
+    chose compiles the program a second time — and not to the same
+    program: for the TPU, GPT-2-XL's decode tick compiled for the chosen
+    formats copies its FFN out-projection again, the AUTO one does not.
+    ``lower``, and a call under a trace (the graph tier's, ``eval_shape``),
+    go to the jitted callable, so what is analysed is what is dispatched;
+    ``_cache_size`` says, as a jitted callable's does, whether a program
+    has been dispatched."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._compiled = None
+        self._dispatched = False
+
+    def compile(self, *args):
+        self._compiled = self._jitted.lower(*args).compile()
+        return self._compiled
+
+    def lower(self, *args):
+        return self._jitted.lower(*args)
+
+    def __call__(self, *args):
+        import jax
+        if self._compiled is None or any(
+                isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args)):
+            return self._jitted(*args)
+        self._dispatched = True
+        return self._compiled(*args)
+
+    def _cache_size(self) -> int:
+        return int(self._dispatched)
 
 
 @dataclasses.dataclass(slots=True, eq=False)
@@ -846,7 +933,13 @@ class SlotScheduler:
         self._last_admit = {
             rows: jax.jit(paged_last_admit, donate_argnums=(1, 5, 6, 7, 8))
             for rows in self._rungs}
-        self._tick = jax.jit(paged_tick, donate_argnums=(1, 3, 4, 5, 6))
+        # the weights' formats are left to the compiler: compiled below, by
+        # ``_place_weights``, and dispatched as compiled
+        from jax.experimental.layout import Format, Layout
+        self._tick = _AutoFormatProgram(jax.jit(
+            paged_tick, donate_argnums=(1, 3, 4, 5, 6),
+            in_shardings=(jax.tree.map(lambda _: Format(Layout.AUTO), params),)
+            + (None,) * 8))
         self._wire_gather = jax.jit(wire_gather)
         self._wire_splice = jax.jit(wire_splice, donate_argnums=(0,))
         # recurrent-state models: two more pinned programs, both row
@@ -854,6 +947,9 @@ class SlotScheduler:
         self._state_snapshot = jax.jit(state_snapshot,
                                        donate_argnums=(0, 1))
         self._state_restore = jax.jit(state_restore, donate_argnums=(0,))
+        # the weights as the decode tick reads them: a tree of its own (the
+        # caller's is left as it came), sharing every leaf that needs no move
+        self.params, self._relaid = self._place_weights(params)
         # every rung is compiled NOW (through the persistent compile cache
         # where there is one), so a rung first met under traffic is never
         # a compile under traffic
@@ -870,6 +966,80 @@ class SlotScheduler:
                 np.zeros((rows, pps), np.int32), told,
                 None if self.adapters is None
                 else np.zeros((rows,), np.int32))
+
+    def _decode_args(self, params, cache, carry, adapter) -> tuple:
+        """The decode tick's arguments as ``_decode_dispatch`` passes them
+        (``carry``: tokens, finished, remaining, key; ``adapter``: the
+        table and the slots' rows), as shapes: the weights' with their
+        shardings, their formats being the compiler's to choose."""
+        import jax
+        pps = self.max_len // self.page_size
+        return (_shapes(params, "sharding"), _shapes(cache),
+                jax.ShapeDtypeStruct((self.num_slots, pps), np.int32),
+                *_shapes(carry), *_shapes(adapter))
+
+    def _place_weights(self, params) -> Tuple[object, list]:
+        """Compile the decode tick, the weights' formats left to the
+        compiler, and put each weight leaf it wants in another format than
+        its own there, once: ``(the weights as the hot programs take them,
+        [(leaf, shape, from, to, bytes)] for each leaf moved)``.  The window
+        programs are then compiled for the leaves as placed (a jitted
+        callable takes a committed array's format as its own), so no hot
+        program re-lays a weight out in every dispatch.  With the formats
+        the arrays came in, the TPU's compiler copied GPT-2-XL's whole stack
+        of FFN out-projections (0.98 GB) and its word embedding (161 MB) in
+        each decode dispatch and the embedding again in each window program
+        (PERF.md).  A leaf already in the wanted format is the caller's own
+        array; a scheduler built over shapes that name no device has nothing
+        to compile for and keeps what it was given."""
+        import jax
+        leaves = jax.tree.leaves(params)
+        if any(getattr(x, "sharding", None) is None for x in leaves):
+            return params, []
+        devices = {d for x in leaves for d in x.sharding.device_set}
+        if isinstance(leaves[0], jax.Array) and len(devices) == 1:
+            # the state the programs take, committed to the weights'
+            # device: a committed weight (a placed one is) commits every
+            # output, and a program first dispatched on uncommitted state
+            # would compile again at its second dispatch
+            (self._cache, self._snaps, self._tokens, self._finished,
+             self._remaining, self._key) = jax.device_put(
+                (self._cache, self._snaps, self._tokens, self._finished,
+                 self._remaining, self._key),
+                jax.sharding.SingleDeviceSharding(devices.pop()))
+        wanted = self._tick.compile(*self._decode_args(
+            params, self._cache, (self._tokens, self._finished,
+                                  self._remaining, self._key),
+            (None if self.adapters is None else self.adapters.arrays,
+             self._adapter_rows))).input_formats
+        flat, tree = jax.tree_util.tree_flatten_with_path(params)
+        placed = [x for _, x in flat]
+        relaid, moved = [], []
+        for i, ((path, x), fmt) in enumerate(
+                zip(flat, tree.flatten_up_to(wanted[0][0]))):
+            own = _layout_of(x)
+            if fmt.layout is None or own == fmt.layout:
+                continue
+            relaid.append((jax.tree_util.keystr(path), tuple(x.shape),
+                           own.major_to_minor, fmt.layout.major_to_minor,
+                           x.size * x.dtype.itemsize))
+            if isinstance(x, jax.Array):
+                moved.append((i, fmt))
+            else:
+                placed[i] = jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                 sharding=fmt)
+        if moved:
+            copies = _relay([placed[i] for i, _ in moved],
+                            [fmt for _, fmt in moved])
+            for (i, _), copy in zip(moved, copies):
+                placed[i] = copy
+        placed = tree.unflatten(placed)
+        if relaid:
+            log.info("weights placed in the formats the decode tick reads, "
+                     "%d bytes: %s", sum(r[-1] for r in relaid), "; ".join(
+                         f"{name} {list(shape)} {a} -> {b}"
+                         for name, shape, a, b, _ in relaid))
+        return placed, relaid
 
     def _compile_window_rungs(self) -> None:
         """One dispatch of each window program at each rung, on padding
@@ -909,23 +1079,21 @@ class SlotScheduler:
         (shape/dtype reads of buffers a running tick donates)."""
         import jax
 
-        def sds(tree):
-            return jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(
-                    tuple(getattr(x, "shape", ())), x.dtype), tree)
-
         top = self._rungs[-1]
-        win, prow, told, row_ad = sds(self._padding_rows(top))
+        win, prow, told, row_ad = _shapes(self._padding_rows(top))
         with self._pump_lock:
-            params, cache = sds(self.params), sds(self._cache)
-            toks, fin = sds(self._tokens), sds(self._finished)
-            rem, key = sds(self._remaining), sds(self._key)
-            snaps = sds(self._snaps)
-            ad, ad_rows = self._adapter_args()
-        ad = sds(ad) if ad is not None else None
-        rows = sds(ad_rows) if ad_rows is not None else None
-        pps = self.max_len // self.page_size
-        tab = jax.ShapeDtypeStruct((self.num_slots, pps), np.int32)
+            # the window programs take the weights as placed
+            params, cache = _shapes(self.params, "format"), _shapes(
+                self._cache)
+            toks, fin = _shapes(self._tokens), _shapes(self._finished)
+            rem, key = _shapes(self._remaining), _shapes(self._key)
+            snaps = _shapes(self._snaps)
+            adapter = self._adapter_args()
+            decode = self._decode_args(
+                self.params, self._cache, (self._tokens, self._finished,
+                                           self._remaining, self._key),
+                adapter)
+        ad = _shapes(adapter[0])
         targets = [
             graph_lib.Target(
                 "prefill_window", self._win_mid[top],
@@ -936,10 +1104,8 @@ class SlotScheduler:
                 (params, cache, win, prow, told, key, toks, fin, rem, ad,
                  row_ad),
                 hbm_budget=hbm_budget),
-            graph_lib.Target(
-                "decode_tick", self._tick,
-                (params, cache, tab, toks, fin, rem, key, ad, rows),
-                hbm_budget=hbm_budget),
+            graph_lib.Target("decode_tick", self._tick, decode,
+                             hbm_budget=hbm_budget),
         ]
         if self._stateful:
             # the snapshot copies are programs of their own (a turn's
@@ -1117,6 +1283,7 @@ class SlotScheduler:
             state_snapshot_bytes=p["state_snapshot_bytes"],
             kv_pool_bytes=self._kv_pool_bytes[0],
             kv_pool_tiled_bytes=self._kv_pool_bytes[1],
+            weights_relaid_bytes=sum(r[-1] for r in self._relaid),
             prefill_windows_skipped_total=skipped,
             page_size=p["page_size"],
             prefix_fingerprint=p["prefix_fingerprint"])

@@ -245,8 +245,10 @@ def test_counters_and_request_counts_repeat_exactly(model_params, tracer):
         # hold a lease, so the second bounces until the first retires
         engine = _engine(model_params, max_len=32, prefill_chunk=8,
                          page_size=8, num_pages=6)
-        a = engine.submit(_prompt(16, seed=7), 8)
-        b = engine.submit(_prompt(15, seed=8), 8)
+        # both prompts made before either submit: a's wait is its own
+        prompts = _prompt(16, seed=7), _prompt(15, seed=8)
+        a = engine.submit(prompts[0], 8)
+        b = engine.submit(prompts[1], 8)
         engine.drain()
         assert a.status == b.status == "ok"
         stats = engine.stats()

@@ -257,6 +257,135 @@ def test_generate_batch_convenience_and_queueing():
 
 
 # ---------------------------------------------------------------------------
+# the weights in the formats the decode program reads
+
+
+def _serve_tokens(model, params, plain_tick=False):
+    """Three concurrent requests through an engine; with ``plain_tick``
+    its decode tick is the same function jitted plainly, each weight read
+    in the format it came in."""
+    reg = metrics_lib.Registry()
+    eng = serve.Engine(model, params, num_slots=3, max_len=32,
+                       prefill_chunk=4, tick_steps=3, registry=reg)
+    if plain_tick:
+        eng.scheduler._tick = jax.jit(
+            eng.scheduler._tick._jitted.__wrapped__,
+            donate_argnums=(1, 3, 4, 5, 6))
+    handles = [eng.submit(_prompt(7, seed=1), 9),
+               eng.submit(_prompt(5, seed=2), 12),
+               eng.submit(_prompt(3, seed=4), 6)]
+    eng.drain()
+    return eng, reg, [h.tokens for h in handles]
+
+
+def test_decode_program_as_compiled_keeps_the_callers_weights_on_the_cpu():
+    """The decode tick is compiled once, the weights' formats left to the
+    compiler, and dispatched as that executable.  On the CPU the compiler
+    keeps every weight in the format it came in: nothing is moved
+    (``weights_relaid_bytes`` and its gauge read 0), the scheduler holds
+    the caller's own arrays, and the tokens are bit for bit those of the
+    same program jitted plainly."""
+    model, params = _model_params()
+    eng, reg, got = _serve_tokens(model, params)
+    _, _, want = _serve_tokens(model, params, plain_tick=True)
+    assert got == want
+    assert eng.stats().weights_relaid_bytes == 0
+    assert reg.get("dttpu_serve_weights_relaid_bytes").value == 0
+    assert all(a is b for a, b in zip(jax.tree.leaves(eng.scheduler.params),
+                                      jax.tree.leaves(params)))
+    assert eng.scheduler._tick._cache_size() == 1
+
+
+def test_weight_in_another_format_is_placed_once_callers_tree_untouched(
+        caplog):
+    """A weight that comes in another format than the one the decode
+    program reads (here the word embedding column-major; on a TPU, the
+    formats the chip gives GPT-2-XL's FFN out-projection and embedding) is
+    put in the program's format ONCE, at construction, in a tree of the
+    scheduler's own: counted by ``weights_relaid_bytes`` and its gauge,
+    named on the log, the caller's tree left as it was (the same arrays,
+    in their format, not donated), every other leaf shared, the tokens
+    those of ``generate``, and no program compiled twice."""
+    import logging
+    from jax.experimental.layout import Format, Layout
+    model, params = _model_params()
+    word = params["embeddings"]["word"]
+    col = jax.device_put(word, Format(Layout(major_to_minor=(1, 0)),
+                                      word.sharding))
+    given = dict(params, embeddings=dict(params["embeddings"], word=col))
+    with caplog.at_level(logging.INFO,
+                         logger="distributed_tensorflow_tpu.serve.scheduler"):
+        eng, reg, got = _serve_tokens(model, given)
+    prompts = [(_prompt(7, seed=1), 9), (_prompt(5, seed=2), 12),
+               (_prompt(3, seed=4), 6)]
+    assert got == [_generate_tokens(model, params, p, n, 32)
+                   for p, n in prompts]
+    sched = eng.scheduler
+    assert sched._relaid == [("['embeddings']['word']", tuple(col.shape),
+                              (1, 0), (0, 1), col.nbytes)]
+    assert eng.stats().weights_relaid_bytes == col.nbytes
+    assert reg.get("dttpu_serve_weights_relaid_bytes").value == col.nbytes
+    assert "['embeddings']['word'] [512, 128] (1, 0) -> (0, 1)" in caplog.text
+    placed = sched.params["embeddings"]["word"]
+    assert placed is not col and placed.format.layout.major_to_minor == (0, 1)
+    assert given["embeddings"]["word"] is col and not col.is_deleted()
+    assert col.format.layout.major_to_minor == (1, 0)
+    np.testing.assert_array_equal(np.asarray(col), np.asarray(word))
+    moved = {id(col)}
+    for a, b in zip(jax.tree.leaves(sched.params), jax.tree.leaves(given)):
+        assert (a is b) != (id(b) in moved)
+    # as many programs as an engine over the weights as they came
+    plain = _serve_tokens(model, params)[0].scheduler
+
+    def programs(s):
+        return [s._tick._cache_size()] + [
+            fn._cache_size() for fns in (s._win_mid, s._last_admit)
+            for fn in fns.values()]
+    assert programs(sched) == programs(plain) and programs(sched)[0] == 1
+
+
+# one process of two sharing a persistent compile cache: a weight copied
+# into a format other than the device's own, and a program compiled for it
+_RELAY_RUN = """
+import jax, numpy as np
+from jax.experimental.layout import Format, Layout
+from distributed_tensorflow_tpu.serve.scheduler import _relay
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+x = np.arange(24, dtype=np.float32).reshape(4, 3, 2)
+one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+(y,) = _relay([jax.device_put(x, one)], [Format(Layout((1, 0, 2)), one)])
+doubled = jax.jit(lambda a: a * 2)(y)
+print(y.format.layout.major_to_minor, np.array_equal(np.asarray(y), x),
+      np.array_equal(np.asarray(doubled), 2 * x))
+"""
+
+
+def test_weights_placed_alike_when_the_compile_cache_serves_a_second_run(
+        tmp_path):
+    """The copy that puts a weight in the decode program's format stays out
+    of the persistent compile cache: an executable read back from it gives
+    its outputs the default format, so a second run's copy would be
+    labelled in a format it is not in (on the chip, every window program
+    then refused the weights).  Two runs over one cache: both hold the
+    copy in the format asked for, with the caller's values, and a program
+    compiled for it (served from the cache the second time) reads it
+    right."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    runs = [subprocess.run([sys.executable, "-c", _RELAY_RUN], env=env,
+                           capture_output=True, text=True, check=True,
+                           timeout=120).stdout.splitlines()[-1]
+            for _ in range(2)]
+    assert runs == ["(1, 0, 2) True True"] * 2
+    cached = os.listdir(tmp_path / "cache")
+    assert any(name.startswith("jit__lambda") for name in cached), cached
+    assert not any(name.startswith("jit_relaid") for name in cached), cached
+
+
+# ---------------------------------------------------------------------------
 # graceful degradation: backpressure, deadlines, failure isolation
 # (docs/RESILIENCE.md)
 

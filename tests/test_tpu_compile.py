@@ -11,6 +11,7 @@ The code under test asks ``jax.default_backend()`` (which is the CPU here)
 whether to interpret; the cases steer that by passing the kernels' own
 ``interpret=False`` argument, never through a new option of the program.
 """
+import contextlib
 import hashlib
 import importlib
 import os
@@ -261,7 +262,36 @@ def _scheduler_from_shapes(model, params, **kw):
         pages_lib.init_paged_cache, pages_lib.init_state_snapshots = real
 
 
-def test_gpt2_xl_serving_programs_move_no_pool_sized_copy_on_v5e(topo):
+def _on_chip(one, tree):
+    """``tree``'s leaves as shapes on the described chip ``one``; a leaf
+    that names its device keeps its format (the weights as a scheduler
+    built on the chip placed them: what its programs are dispatched on)."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        tuple(x.shape), x.dtype,
+        sharding=one if getattr(x, "sharding", None) is None else x.format),
+        tree)
+
+
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+          "u8": 1, "pred": 1}
+
+
+def _weight_copies(text, least=1 << 20):
+    """``[(parameter, shape, bytes)]``: the compiled program's copies of a
+    weight leaf (an entry parameter ``params__...``) of ``least`` bytes or
+    more — a layout change the program pays for in every dispatch."""
+    found = []
+    for m in re.finditer(
+            r"= (\w+)\[([\d,]*)\]\S* copy\(%?(params__[\w.]+)\)", text):
+        size = _BYTES[m.group(1)]
+        for d in filter(None, m.group(2).split(",")):
+            size *= int(d)
+        if size >= least:
+            found.append((m.group(3), m.group(2), size))
+    return found
+
+
+def test_gpt2_xl_serving_programs_move_no_pool_sized_copy_on_v5e(one_chip):
     """The three hot programs of the GPT-2-XL serving cell (8 slots x 1024,
     25 heads x 64, the Mosaic kernel on) compiled for the described chip
     from shapes alone.  With ``[.., 25, 64]`` minor dimensions every one of
@@ -271,29 +301,13 @@ def test_gpt2_xl_serving_programs_move_no_pool_sized_copy_on_v5e(topo):
     of the pool or of a layer of it, the pool is updated in place, and the
     temporaries are far under a second pool."""
     from chip_smoke import pool_moves
-    from distributed_tensorflow_tpu.models.gpt import GPT, GPTConfig
 
-    model = GPT(GPTConfig(vocab_size=50257, hidden_size=1600, num_layers=48,
-                          num_heads=25, intermediate_size=6400,
-                          max_position=MAX_LEN, dtype=jnp.bfloat16,
-                          dropout_rate=0.0))
-    params = jax.eval_shape(lambda key: jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16), model.init(key)),
-        jax.random.PRNGKey(0))
-    sched = _scheduler_from_shapes(model, params, num_slots=8,
-                                   max_len=MAX_LEN, use_paged_kernel=True)
+    sched = _xl_serving_scheduler(one_chip)
     pool = sched._cache["kv"]["k"]
     assert pool.shape == (48, 8 * (MAX_LEN // 16) + 1, 16, 1600)
-    one = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
-    real = paged_mod.use_interpret
-    paged_mod.use_interpret = lambda: False    # the backend here is the CPU
-    try:
-        compiled = {t.name: t.fn.lower(*place(t.args)).compile()
+    with _mosaic():
+        compiled = {t.name: t.fn.lower(*_on_chip(one_chip, t.args)).compile()
                     for t in sched.graph_targets()}
-    finally:
-        paged_mod.use_interpret = real
     pool_gb = 2 * pool.size * 2 / 1e9
     for name, program in compiled.items():
         text, memory = program.as_text(), program.memory_analysis()
@@ -304,43 +318,52 @@ def test_gpt2_xl_serving_programs_move_no_pool_sized_copy_on_v5e(topo):
                                                                  memory)
 
 
-def _xl_serving_scheduler():
+@contextlib.contextmanager
+def _mosaic():
+    """The paged kernels lowered for Mosaic, as on the chip: the code asks
+    the backend, which is the CPU here."""
+    real = paged_mod.use_interpret
+    paged_mod.use_interpret = lambda: False
+    try:
+        yield
+    finally:
+        paged_mod.use_interpret = real
+
+
+def _xl_serving_scheduler(one):
+    """The GPT-2-XL serving cell's scheduler over bf16 weights placed on
+    the described chip ``one``: built there, it compiles its decode tick
+    and puts the weights in the formats that program reads, as on the
+    chip."""
     from distributed_tensorflow_tpu.models.gpt import GPT, GPTConfig
     model = GPT(GPTConfig(vocab_size=50257, hidden_size=1600, num_layers=48,
                           num_heads=25, intermediate_size=6400,
                           max_position=MAX_LEN, dtype=jnp.bfloat16,
                           dropout_rate=0.0))
-    params = jax.eval_shape(lambda key: jax.tree.map(
+    params = _on_chip(one, jax.eval_shape(lambda key: jax.tree.map(
         lambda x: x.astype(jnp.bfloat16), model.init(key)),
-        jax.random.PRNGKey(0))
-    return _scheduler_from_shapes(model, params, num_slots=8,
-                                  max_len=MAX_LEN, use_paged_kernel=True)
+        jax.random.PRNGKey(0)))
+    with _mosaic():
+        return _scheduler_from_shapes(model, params, num_slots=8,
+                                      max_len=MAX_LEN, use_paged_kernel=True)
 
 
 def _lowered_for(one, sched):
-    """{(program, rows): lowered text} of a scheduler's hot programs — the
-    two window programs at EVERY rung of its ladder, the decode tick (rows
-    0) — the Mosaic kernel in (the backend here is the CPU)."""
-    def place(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            tuple(x.shape), x.dtype, sharding=one), tree)
-
+    """{(program, rows): lowered} of a scheduler's hot programs, on the
+    weights as it placed them — the two window programs at EVERY rung of
+    its ladder, the decode tick (rows 0) — the Mosaic kernel in."""
     targets = {t.name: t for t in sched.graph_targets()}
-    real = paged_mod.use_interpret
-    paged_mod.use_interpret = lambda: False
-    try:
+    with _mosaic():
         tick = targets["decode_tick"]
-        out = {("decode_tick", 0): tick.fn.lower(*place(tick.args)).as_text()}
+        out = {("decode_tick", 0): tick.fn.lower(*_on_chip(one, tick.args))}
         for name, fns in (("prefill_window", sched._win_mid),
                           ("admit", sched._last_admit)):
             for rows, fn in fns.items():
                 args = list(targets[name].args)
                 # windows, page rows, what the rows are told[, adapter rows]
                 args[2:5] = sched._padding_rows(rows)[:3]
-                out[name, rows] = fn.lower(*place(tuple(args))).as_text()
+                out[name, rows] = fn.lower(*_on_chip(one, tuple(args)))
         return out
-    finally:
-        paged_mod.use_interpret = real
 
 
 # the lowered text of the three GPT-2-XL hot programs at commit 65f26fc
@@ -366,10 +389,11 @@ def test_gpt2_xl_serving_programs_stay_cheap_to_set_up(one_chip):
     scheduler's programs — and a second run's — are the first's byte for
     byte and the persistent cache serves them; and none holds a host
     callback, whose pointer the cache key would carry."""
-    sched = _xl_serving_scheduler()
+    sched = _xl_serving_scheduler(one_chip)
     assert sched._rungs == XL_RUNGS and len(sched._rungs) <= 3
-    first = _lowered_for(one_chip, sched)
-    second = _lowered_for(one_chip, _xl_serving_scheduler())
+    first = {k: v.as_text() for k, v in _lowered_for(one_chip, sched).items()}
+    second = {k: v.as_text() for k, v in _lowered_for(
+        one_chip, _xl_serving_scheduler(one_chip)).items()}
     assert sorted(first) == sorted(
         [("decode_tick", 0)] + [(name, rows) for rows in XL_RUNGS
                                 for name in ("prefill_window", "admit")])
@@ -382,10 +406,66 @@ def test_gpt2_xl_serving_programs_stay_cheap_to_set_up(one_chip):
         assert text == second[name, rows], (name, rows)
 
 
+def test_gpt2_xl_serving_programs_copy_no_weight_on_v5e(one_chip):
+    """No hot program of the GPT-2-XL serving cell re-lays a weight out in
+    every dispatch.  On the weights in the formats the chip gives such
+    arrays the decode program copied, at its entry, the whole stack of FFN
+    out-projections (``bf16[48,6400,1600]``, 0.98 GB: the chip's format
+    puts 6400 minor, the program read 1600 minor; 3.2 ms of a ~49 ms tick,
+    ledger PR 39) and the word embedding (161 MB), and each window program
+    copied the embedding again.  The scheduler compiles its decode tick
+    with the weights' formats left to the compiler and puts each weight the
+    program wants in another format there, once; then the decode tick and
+    both window programs, at every rung, hold no copy of a weight leaf of
+    1 MB or more, the decode tick's temporaries fall by 0.9 GB or more,
+    every program reads each weight in the format it was placed in, and
+    what ``graph_targets()`` lowers is the program that is dispatched."""
+    from distributed_tensorflow_tpu.serve.scheduler import _layout_of
+
+    sched = _xl_serving_scheduler(one_chip)
+    assert "['embeddings']['word']" in {name for name, *_ in sched._relaid}
+    assert sched.stats().weights_relaid_bytes == sum(
+        size for *_, size in sched._relaid)
+    placed = [_layout_of(x) for x in jax.tree.leaves(sched.params)]
+    tick = sched._tick._compiled                     # what is dispatched
+    # what the benchmark's analysis compiles: the target as it stands
+    decode = {t.name: t for t in sched.graph_targets()}["decode_tick"]
+    with _mosaic():
+        assert decode.fn.lower(*decode.args).compile().as_text() \
+            == tick.as_text()
+        # the same program on the weights in the formats they came in
+        before = jax.jit(sched._tick._jitted.__wrapped__,
+                         donate_argnums=(1, 3, 4, 5, 6)).lower(
+            *_on_chip(one_chip, decode.args)).compile()
+    copied = [name for name, *_ in _weight_copies(before.as_text())]
+    assert any("w_out" in name for name in copied), copied
+    assert any("word" in name for name in copied), copied
+    assert (tick.memory_analysis().temp_size_in_bytes
+            < before.memory_analysis().temp_size_in_bytes - 0.9e9)
+    programs = {key: program.compile()
+                for key, program in _lowered_for(one_chip, sched).items()
+                if key[0] != "decode_tick"}
+    programs["decode_tick", 0] = tick
+    for key, program in programs.items():
+        text = program.as_text()
+        assert KERNEL_MARK in text, key
+        assert _weight_copies(text) == [], key
+        reads = [f.layout for f in jax.tree.leaves(program.input_formats[0][0])]
+        assert len(reads) == len(placed), key
+        # a window program does not read every weight (the final norm's
+        # are a mid window's dead code): those it leaves out have no format
+        assert all(layout == own for layout, own in zip(reads, placed)
+                   if layout is not None), key
+        assert key[0] != "decode_tick" or None not in reads, key
+
+
 # (PR 37: the two window programs hold a batch of windows, here 8; the
-# decode tick and the two copies are what they were)
+# decode tick and the two copies are what they were.  PR 40: the decode
+# tick leaves the weights' formats to the compiler, so its entry marks
+# each weight parameter ``mhlo.layout_mode = "auto"``; nothing else in its
+# text moved, and the windows' and the copies' texts are what they were)
 HYBRID_TEXT_SHA256 = {"prefill_window": "bbeac5bb008b80fb", "admit": "0ce9bc7e9a648e55",
-                      "decode_tick": "31dac948295ae229", "state_snapshot": "8ba7368593677a89",
+                      "decode_tick": "695251f020be7c28", "state_snapshot": "8ba7368593677a89",
                       "state_restore": "d0c9800092c0a6ae"}
 
 
@@ -399,6 +479,11 @@ def _fits_beside(in_use: float, memory, name: str) -> None:
 # bytes in use in the two agent cells (weights, pool, slot state, snapshot
 # rows; PERF.md section 5)
 GRANITE_IN_USE, LONGCAT_IN_USE = 12.99e9, 13.06e9
+# the weights a scheduler holds a second time, in the formats its decode
+# program reads (the caller keeps its own): a sliver beside what these
+# deployments hold (0 / 0.134 / 0.092 GB here, rehearsal; GPT-2-XL's
+# 0.165 GB is its embedding)
+RELAID_AT_MOST = 0.2e9
 
 
 def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
@@ -434,18 +519,17 @@ def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
         ssm_heads=c["mamba_n_heads"], ssm_head_dim=c["mamba_d_head"],
         ssm_state=c["mamba_d_state"], conv_width=c["mamba_d_conv"],
         max_position=c["serve"]["max_len"], param_dtype=jnp.bfloat16))
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on_chip(one, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     sched = _scheduler_from_shapes(model, params,      # 6.6 GB of shapes
                                    num_slots=c["serve"]["num_slots"],
                                    max_len=c["serve"]["max_len"])
-    one = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    assert sched.stats().weights_relaid_bytes < RELAID_AT_MOST
     state_gb = 0.0
     targets = sched.graph_targets()
     assert targets[0].args[2].shape == (8, 32)       # 8 windows of 32
     for target in targets:
-        lowered = target.fn.lower(*place(target.args))
+        lowered = target.fn.lower(*_on_chip(one, target.args))
         # the programs this decoder has lowered to since PR 32: a change to
         # the tier it shares with GPT-2 (the scheduler, ``decode_paged_step``,
         # the page kernels) that is not meant for it leaves their text as it
@@ -456,6 +540,7 @@ def test_state_space_serving_programs_fit_a_v5e_at_published_width(topo):
         memory = compiled.memory_analysis()
         text = compiled.as_text()
         assert KERNEL_MARK not in text, target.name
+        assert _weight_copies(text) == [], target.name
         assert memory.temp_size_in_bytes < 0.5e9, (target.name, memory)
         _fits_beside(GRANITE_IN_USE, memory, target.name)
         if target.name in ("prefill_window", "admit"):
@@ -514,30 +599,30 @@ def test_latent_attention_expert_programs_fit_a_v5e_at_published_width(topo):
         routed_scaling_factor=c["routed_scaling_factor"],
         rope_theta=c["rope_theta"], max_position=c["serve"]["max_len"],
         param_dtype=jnp.bfloat16))
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on_chip(one, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     weights_gb = sum(x.size * x.dtype.itemsize
                      for x in jax.tree.leaves(params)) / 1e9
     assert 10.3 < weights_gb < 10.4                 # the issue's 10.35 GB
     sched = _scheduler_from_shapes(model, params,
                                    num_slots=c["serve"]["num_slots"],
                                    max_len=c["serve"]["max_len"])
+    assert sched.stats().weights_relaid_bytes < RELAID_AT_MOST
     pool = sched._cache["kv"]["latent_key"]
     assert pool.shape == (8, 64 * 256 + 1, 16, 640)
     assert sched._kv_pool_bytes[0] == sched._kv_pool_bytes[1]
-    one = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
     targets = sched.graph_targets()
     assert [t.name for t in targets] == ["prefill_window", "admit",
                                          "decode_tick"]
     assert targets[0].args[2].shape == (8, 32)       # 8 windows of 32
     pool_gb = pool.size * 2 / 1e9
     for target in targets:
-        lowered = target.fn.lower(*place(target.args))
+        lowered = target.fn.lower(*_on_chip(one, target.args))
         assert "callback" not in lowered.as_text(), target.name
         compiled = lowered.compile()
         text, memory = compiled.as_text(), compiled.memory_analysis()
         assert KERNEL_MARK not in text, target.name
+        assert _weight_copies(text) == [], target.name
         assert pool_moves(text, pool.shape) == [], target.name
         # no copy of an expert's matrices or of a bank of them
         assert not re.search(
@@ -594,13 +679,15 @@ def test_decoder_hybrid_decoder_programs_fit_a_v5e_at_published_width(topo):
         d_conv=a["mamba_d_conv"], expand=a["mamba_expand"],
         dt_rank=a["mamba_dt_rank"], max_position=c["serve"]["max_len"],
         param_dtype=jnp.bfloat16))
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on_chip(one, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     weights_gb = sum(x.size * x.dtype.itemsize
                      for x in jax.tree.leaves(params)) / 1e9
     assert 7.70 < weights_gb < 7.71                  # 3,852,562,944 x 2 B
     sched = _scheduler_from_shapes(model, params,
                                    num_slots=c["serve"]["num_slots"],
                                    max_len=c["serve"]["max_len"])
+    assert sched.stats().weights_relaid_bytes < RELAID_AT_MOST
     cache = sched._cache
     pool = cache["kv"]["k"]
     assert pool.shape == (1, 32 * 512 + 1, 16, 1280)
@@ -608,9 +695,6 @@ def test_decoder_hybrid_decoder_programs_fit_a_v5e_at_published_width(topo):
         "ssm": (9, 32, 16, 5120), "conv": (9, 32, 3, 5120),
         "window_k": (8, 32, 544, 1280), "window_v": (8, 32, 544, 1280)}
     assert sched._snaps["window_k"].shape == (8, 40, 544, 1280)
-    one = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
     targets = sched.graph_targets()
     assert [t.name for t in targets] == ["prefill_window", "admit",
                                          "decode_tick", "state_snapshot",
@@ -618,9 +702,10 @@ def test_decoder_hybrid_decoder_programs_fit_a_v5e_at_published_width(topo):
     assert targets[0].args[2].shape == (8, 32)       # 8 windows of 32
     view = {"prefill_window": 8, "admit": 8, "decode_tick": 32}
     for target in targets:
-        compiled = target.fn.lower(*place(target.args)).compile()
+        compiled = target.fn.lower(*_on_chip(one, target.args)).compile()
         text, memory = compiled.as_text(), compiled.memory_analysis()
         assert KERNEL_MARK not in text, target.name
+        assert _weight_copies(text) == [], target.name
         copied = [line for line in text.splitlines() if re.search(
             r"= bf16\[(1,16385,16|8,32,544|32,544|32,8192),1280\]\S* "
             r"copy\(|= f32\[9,32,16,5120\]\S* copy\(", line)]
