@@ -41,11 +41,26 @@ Module-level *active tracer*: ``activate(tracer)`` makes a tracer the
 process-wide sink for code that cannot thread a handle through its API
 (``analysis.sanitizer.RetraceGuard`` emits retrace instants this way).
 ``instant(...)``/``span(...)`` module functions route to it and no-op
-when nothing is active.
+when nothing is active.  While a tracer is active, each collection of
+Python's garbage collector is a retroactive ``host.gc`` span.
+
+The device's queue: a program dispatched to one device runs after every
+program dispatched before it, so the queue is empty exactly when the
+newest dispatched program has finished.  ``dispatched(name, out)``, called
+right after each dispatch, names that program and keeps one of its output
+arrays; every recorded span's enter and exit asks that one array
+``is_ready()`` (no wait), and the polls bracket the moment the queue ran
+dry: the last poll that found it busy (``earliest_us``), the first that
+found it ready (``drained_us``).  The next dispatch records the wait as a
+retroactive ``device.wait_host`` span from ``drained_us`` to the start of
+the span that holds that dispatch, so what the host was doing while the
+device had nothing to run is the spans that cover it.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -53,10 +68,14 @@ import time
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 __all__ = ["Tracer", "SpanRecord", "activate", "activated", "deactivate",
-           "active_tracer", "span", "timed", "instant", "now_us",
-           "to_perf_counter_s", "self_times_us", "ANNOTATION_PREFIX"]
+           "active_tracer", "span", "timed", "instant", "dispatched",
+           "waited_host_us", "now_us", "to_perf_counter_s", "self_times_us",
+           "ANNOTATION_PREFIX", "WAIT_HOST", "READY_WAIT", "GC"]
 
 ANNOTATION_PREFIX = "dttpu:"      # the spans' names in a profiler capture
+WAIT_HOST = "device.wait_host"    # the device's queue empty, the host busy
+READY_WAIT = "device.ready_wait"  # a read waiting for the device's result
+GC = "host.gc"                    # one collection of the garbage collector
 
 # perf_counter_ns is monotonic but has an arbitrary epoch; anchor it once
 # so ts values are comparable across tracers in one process.
@@ -158,6 +177,16 @@ class Tracer:
         self._spans: List[list] = []
         self._tls = threading.local()
         self.instant_counts: Dict[str, int] = {}
+        # rows the garbage collector's callback recorded: it may run while
+        # this thread holds the lock, so it appends here and the next
+        # holder of the lock takes them in
+        self._late: collections.deque = collections.deque()
+        # the newest dispatch ``[name, output array, earliest_us,
+        # drained_us, deleted_us]``, the ``device.wait_host`` time so far,
+        # and what the account found
+        self._newest: Optional[list] = None
+        self._waited_us = 0.0
+        self.device_queue = {"dispatches": 0, "unknown": 0}
         self._add_metadata()
 
     # ------------------------------------------------------------ record
@@ -187,7 +216,14 @@ class Tracer:
         row = [name, start_us, max(start_us, end_us), None, args,
                threading.get_ident() & 0xFFFFFFFF]
         with self._lock:
+            self._take_late()
             self._spans.append(row)
+
+    def _take_late(self) -> None:
+        """Move the collector's rows into the span list (lock held)."""
+        late = self._late
+        while late:
+            self._spans.append(late.popleft())
 
     def _open(self, name: str, start_us: float, args: Dict[str, Any]
               ) -> int:
@@ -197,6 +233,7 @@ class Tracer:
         row = [name, start_us, None, stack[-1] if stack else None, args,
                threading.get_ident() & 0xFFFFFFFF]
         with self._lock:
+            self._take_late()
             index = len(self._spans)
             self._spans.append(row)
         stack.append(index)
@@ -209,6 +246,67 @@ class Tracer:
         while stack and stack.pop() != index:
             pass
         self._spans[index][2] = end_us
+
+    # ------------------------------------------------- the device's queue
+
+    def dispatched(self, name: str, out: Any) -> None:
+        """Program ``name`` was just dispatched; ``out`` is one of its
+        outputs (an array, or a dict / list / tuple whose first leaf is
+        taken).  Settles the previous newest dispatch: if a poll found it
+        ready, the device waited from then to the start of the span open on
+        this thread (the one that holds this dispatch), a ``device.wait_host``
+        span with ``earliest_us`` (the last poll that found it busy),
+        ``after`` (that program), ``before`` (this one) and
+        ``dispatched_us`` (now: this program is queued).  If a poll found its
+        array deleted before that span began, a dispatch that was not
+        registered donated it: counted ``unknown``, no wait recorded."""
+        if not self.enabled:
+            return
+        while not hasattr(out, "is_ready"):
+            out = (next(iter(out.values())) if isinstance(out, dict)
+                   else out[0])
+        now = now_us()
+        stack = getattr(self._tls, "stack", None)
+        queue = self.device_queue
+        with self._lock:
+            self._take_late()
+            start = self._spans[stack[-1]][1] if stack else now
+            prev = self._newest
+            if prev is not None:
+                after, _, earliest, drained, deleted = prev
+                if deleted is not None and deleted < start:
+                    queue["unknown"] += 1
+                elif drained is not None and drained <= start:
+                    self._spans.append([
+                        WAIT_HOST, drained, start, None,
+                        {"earliest_us": earliest, "after": after,
+                         "before": name, "dispatched_us": now},
+                        threading.get_ident() & 0xFFFFFFFF])
+                    self._waited_us += start - drained
+            # queued now, so it cannot have finished before now
+            self._newest = [name, out, now, None, None]
+            queue["dispatches"] += 1
+
+    def _poll(self) -> None:
+        """Ask the newest dispatch's output whether it is ready, without
+        waiting: busy moves ``earliest_us`` up to the clock read before the
+        question, the first ready sets ``drained_us`` to the one after it,
+        and a deleted array (donated) sets ``deleted_us``.  Once drained it
+        is still asked, so that a donation is seen."""
+        with self._lock:
+            entry = self._newest
+        if entry is None or entry[4] is not None:
+            return
+        before = time.perf_counter_ns()
+        try:
+            ready = entry[1].is_ready()
+        except Exception:              # its buffer was donated
+            entry[4] = now_us()
+            return
+        if not ready:
+            entry[2] = (before - _EPOCH_NS) / 1e3
+        elif entry[3] is None:
+            entry[3] = now_us()
 
     def add_event(self, event: Dict[str, Any]) -> None:
         """Record a raw Chrome trace event (async ``b``/``n``/``e``
@@ -241,12 +339,14 @@ class Tracer:
     def spans(self) -> List[SpanRecord]:
         """Every span so far, in the order they opened."""
         with self._lock:
+            self._take_late()
             return [SpanRecord(*row) for row in self._spans]
 
     def events(self) -> List[Dict[str, Any]]:
         """The Chrome trace events: metadata, instants and raw events as
         recorded, then one complete ("X") event per closed span."""
         with self._lock:
+            self._take_late()
             out = list(self._events)
             rows = [tuple(row) for row in self._spans]
         for name, start, end, _, args, tid in rows:
@@ -296,6 +396,8 @@ class _Span:
             if cls is not None:
                 self._annotation = cls(ANNOTATION_PREFIX + self._name)
                 self._annotation.__enter__()
+            # before the body, which may donate the array polled
+            tracer._poll()
         self._t0 = time.perf_counter_ns()
         if tracer is not None:
             self._index = tracer._open(
@@ -306,6 +408,7 @@ class _Span:
         self._t1 = time.perf_counter_ns()
         if self._tracer is not None:
             self._tracer._close(self._index, (self._t1 - _EPOCH_NS) / 1e3)
+            self._tracer._poll()
             if self._annotation is not None:
                 self._annotation.__exit__(*exc)
         return False
@@ -331,21 +434,47 @@ class _Span:
 
 _ACTIVE: Optional[Tracer] = None
 _ACTIVE_LOCK = threading.Lock()
+_GC_START_US = [0.0]
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` entry while a tracer is active: each collection a
+    ``host.gc`` span with ``generation`` and ``collected``."""
+    t = _ACTIVE
+    if t is None or not t.enabled:
+        return
+    if phase == "start":
+        _GC_START_US[0] = now_us()
+        return
+    t._late.append([GC, _GC_START_US[0], now_us(), None,
+                    {"generation": info["generation"],
+                     "collected": info["collected"]},
+                    threading.get_ident() & 0xFFFFFFFF])
+
+
+def _set_active(tracer: Optional[Tracer]) -> None:
+    """Make ``tracer`` the active one; the collector's callback is
+    installed while there is one (``_ACTIVE_LOCK`` held)."""
+    global _ACTIVE
+    _ACTIVE = tracer
+    if tracer is None:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+    elif _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def activate(tracer: Tracer) -> Tracer:
-    global _ACTIVE
     with _ACTIVE_LOCK:
-        _ACTIVE = tracer
+        _set_active(tracer)
     return tracer
 
 
 def deactivate(tracer: Optional[Tracer] = None) -> None:
     """Clear the active tracer (only if it is ``tracer``, when given)."""
-    global _ACTIVE
     with _ACTIVE_LOCK:
         if tracer is None or _ACTIVE is tracer:
-            _ACTIVE = None
+            _set_active(None)
 
 
 def active_tracer() -> Optional[Tracer]:
@@ -365,6 +494,21 @@ def span(name: str, **args: Any):
     return t.span(name, **args)
 
 
+def dispatched(name: str, out: Any) -> None:
+    """``Tracer.dispatched`` on the active tracer; with none, one global
+    read."""
+    t = _ACTIVE
+    if t is not None:
+        t.dispatched(name, out)
+
+
+def waited_host_us() -> Optional[float]:
+    """The active tracer's ``device.wait_host`` time so far (µs), None with
+    no tracer: a reader tells a program without the account from a zero."""
+    t = _ACTIVE
+    return None if t is None else t._waited_us
+
+
 def timed(name: str, **args: Any) -> _Span:
     """A span that always measures — for an interval whose length the
     program itself uses (``.start_s`` / ``.end_s`` / ``.duration_s``
@@ -377,11 +521,11 @@ def timed(name: str, **args: Any) -> _Span:
 @contextlib.contextmanager
 def activated(tracer: Tracer):
     """Scoped activation (tests, bench): restores the previous tracer."""
-    global _ACTIVE
     with _ACTIVE_LOCK:
-        prev, _ACTIVE = _ACTIVE, tracer
+        prev = _ACTIVE
+        _set_active(tracer)
     try:
         yield tracer
     finally:
         with _ACTIVE_LOCK:
-            _ACTIVE = prev
+            _set_active(prev)

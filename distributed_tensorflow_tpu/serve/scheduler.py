@@ -1348,11 +1348,11 @@ class SlotScheduler:
         (``trace_ids``), over which its time is split."""
         did = False
         outbox: List[tuple] = []     # tick-ordered deliveries/finishes
+        waited_us = trace_lib.waited_host_us()     # None: no tracer
         with trace_lib.span("serve.housekeeping"):
             self._harvest_orphans()
             self._freeze_stale_rows()
             self._expire_deadlines()
-        admissions = 0
         while True:
             with self._lock:
                 req = None
@@ -1361,7 +1361,6 @@ class SlotScheduler:
                     req = self._queue.popleft()
             if req is None or not self._admit(req):
                 break
-            admissions += 1
         with self._lock:
             pending = list(self._prefills)
         # critpath (obs/critpath.py): prefill_s totals this tick's
@@ -1439,13 +1438,14 @@ class SlotScheduler:
             charge([st.req for st, _, _ in admitted], read.duration_s)
         if decoded is not None:
             decoded = self._decode_fetch(*decoded)
-        with trace_lib.span("serve.deliver") as deliver:
+        with trace_lib.span("serve.deliver"):
             if decoded is not None:
                 self._collect(outbox, decoded, prefill_s, win_by_req)
             tokens = self._flush(outbox)
-            deliver.set(tokens=tokens)
-        tick.set(windows=windows, admissions=admissions, active=active,
-                 tokens=tokens)
+        tick.set(windows=windows, tokens=tokens)
+        if waited_us is not None:
+            # the device's waits on the host that ended in this tick
+            tick.set(waited_host_us=trace_lib.waited_host_us() - waited_us)
         if did:
             self._report_depth()
         return did
@@ -1462,7 +1462,7 @@ class SlotScheduler:
                              trace_id=req.trace_id) as admit:
             try:
                 st = self._begin_prefill(req)
-                admit.set(outcome="ok", skipped_tokens=int(st.lease.skip))
+                admit.set(outcome="ok")
                 if self._stateful:
                     # it holds a ``serve.state_restore`` span iff True
                     admit.set(resumed=st.lease.restore is not None)
@@ -1513,8 +1513,14 @@ class SlotScheduler:
             for r in stale:
                 self._page_tab[r] = 0
         if stale:
-            self._finished = self._finished.at[np.asarray(stale)].set(
-                True)
+            self._freeze(np.asarray(stale))
+
+    def _freeze(self, rows) -> None:
+        """Mark ``rows`` finished on the device: an eager update, a program
+        of its own in the device's stream."""
+        with trace_lib.span("serve.freeze_rows"):
+            self._finished = self._finished.at[rows].set(True)
+            trace_lib.dispatched("freeze_rows", self._finished)
 
     def _cp_close_wait(self, req: Request, now: float,
                        reopen: Optional[str] = None) -> None:
@@ -1570,11 +1576,11 @@ class SlotScheduler:
                 row, src, dst = lease.restore
                 with trace_lib.span(
                         "serve.state_restore", trace_id=req.trace_id,
-                        slot=int(slot), depth=int(lease.skip),
-                        bytes=self.pages.state_row_bytes):
+                        depth=int(lease.skip)):
                     self._cache = self._state_restore(
                         self._cache, self._snaps,
                         np.asarray([slot, row, src, dst], np.int32))
+                    trace_lib.dispatched("state_restore", self._cache)
             # the windows: W tokens each from ``skip`` on, cut where
             # the pool asked for a snapshot (``snap_at``: the prompt
             # met a chain there), each stretch's last one padded
@@ -1633,11 +1639,11 @@ class SlotScheduler:
             return
         row, src, dst = booked
         with trace_lib.span("serve.state_snapshot", trace_id=req.trace_id,
-                            slot=int(slot), depth=int(len(context)),
-                            bytes=self.pages.state_row_bytes, at=at):
+                            depth=int(len(context)), at=at):
             self._cache, self._snaps = self._state_snapshot(
                 self._cache, self._snaps,
                 np.asarray([slot, row, src, dst], np.int32))
+            trace_lib.dispatched("state_snapshot", self._snaps)
 
     def _adapter_args(self):
         """(table arrays, the slots' rows) for the executables — (None,
@@ -1713,10 +1719,12 @@ class SlotScheduler:
                         self.params, self._cache, windows, page_rows,
                         told, self._key, self._tokens, self._finished,
                         self._remaining, ad, ad_rows)
+                trace_lib.dispatched("last_admit", toks)
             else:
                 self._cache = self._win_mid[rows](
                     self.params, self._cache, windows, page_rows, told,
                     ad, ad_rows)
+                trace_lib.dispatched("win_mid", self._cache)
         with self._lock:
             self._prefill_dispatches += 1
             self._prefill_rows_padded += rows - len(live)
@@ -1791,7 +1799,7 @@ class SlotScheduler:
         if cancelled:
             # cancel() raced the admission: retire the freshly armed row
             # (frozen rows never perturb the others) and deliver nothing
-            self._finished = self._finished.at[slot].set(True)
+            self._freeze(slot)
             return
         self.metrics.admitted(req)
         if req.trace_id:
@@ -1897,12 +1905,21 @@ class SlotScheduler:
              self._remaining, self._key), em, mask = self._tick(
                 self.params, self._cache, tab, self._tokens,
                 self._finished, self._remaining, self._key, ad, ad_rows)
+            trace_lib.dispatched("tick", em)
         return slots, em, mask, self._finished, dispatch.duration_s
 
     def _split_read(self, read, shape) -> tuple:
         """A program's tokens as a host array of ``shape`` (the host sync)
         and, for a counting model, the device's counters that rode behind
-        them in the same array (``hand_out_counters``), by leaf."""
+        them in the same array (``hand_out_counters``), by leaf.  With a
+        tracer, the wait for the device (``device.ready_wait``) is timed
+        apart from the copy, which stays in the caller's span."""
+        if trace_lib.active_tracer() is not None:
+            with trace_lib.span(trace_lib.READY_WAIT):
+                # the tick's host sync is inside the pump mutex by design
+                # (step()); this only times apart the wait that the
+                # np.asarray below would make
+                read.block_until_ready()  # dtlint: disable=DT304 -- see comment
         read = np.asarray(read)
         if not self._counted:
             return read, None
@@ -2010,7 +2027,7 @@ class SlotScheduler:
                     poisoned.add(id(req))
                     if row is not None:
                         self._drop_slot(row, req)
-                        self._finished = self._finished.at[row].set(True)
+                        self._freeze(row)
                     self._abort(req, "failed", error=e)
             else:                    # "finish"
                 if self._stateful and len(ev) > 2:
@@ -2066,7 +2083,7 @@ class SlotScheduler:
                     rows.append(r)
                     aborts.append(req)
         if rows:
-            self._finished = self._finished.at[np.asarray(rows)].set(True)
+            self._freeze(np.asarray(rows))
         for req in aborts:
             self._abort(req, "deadline_exceeded")
             if req.trace_id:
@@ -2358,7 +2375,9 @@ class SlotScheduler:
             # dispatch under the mutex — stream order puts the copy
             # ahead of any later donating tick — but WAIT for the
             # fresh output buffers after releasing it
-            view_dev = self._wire_gather(self._cache["kv"], idx)
+            with trace_lib.span("serve.wire_gather"):
+                view_dev = self._wire_gather(self._cache["kv"], idx)
+                trace_lib.dispatched("wire_gather", view_dev)
         finally:
             self._pump_lock.release()
         view = jax.device_get(view_dev)
@@ -2427,9 +2446,11 @@ class SlotScheduler:
                 # one redundant page write); the rest get the shipped
                 # payload spliced into their freshly leased pages
                 for j in range(lease.skip // pg, len(take)):
-                    kv = self._wire_splice(kv,
-                                           np.int32(int(lease.row[j])),
-                                           take[j].payload)
+                    with trace_lib.span("serve.wire_splice"):
+                        kv = self._wire_splice(kv,
+                                               np.int32(int(lease.row[j])),
+                                               take[j].payload)
+                        trace_lib.dispatched("wire_splice", kv)
             except BaseException:
                 # _wire_splice donates: rebind the latest buffers so
                 # the pool is never left holding freed device memory

@@ -68,7 +68,8 @@ class TestTrace:
             with obs_trace.span("s"):
                 pass
         obs_trace.instant("after")           # deactivated again
-        names = [e["name"] for e in t.events() if e["ph"] != "M"]
+        names = [e["name"] for e in t.events()
+                 if e["ph"] != "M" and e["name"] != obs_trace.GC]
         assert names == ["mark", "s"]
         assert t.instant_counts == {"mark": 1}
 

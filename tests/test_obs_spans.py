@@ -134,7 +134,7 @@ class TestSpine:
         with trace_lib.activated(t):
             with trace_lib.timed("x", a=1) as s:
                 pass
-        (row,) = t.spans()
+        (row,) = [r for r in t.spans() if r.name != trace_lib.GC]
         assert row.name == "x" and row.args == {"a": 1}
         assert (row.end_us - row.start_us) / 1e6 == pytest.approx(
             s.duration_s, abs=1e-9)
@@ -183,6 +183,139 @@ print("jax" in sys.modules)
         reqtrace.reset()
 
 
+# ------------------------------------------------- the device's queue
+
+class _Marker:
+    """Stands in for a program's output array: ``is_ready`` as set, and
+    raising once deleted (donated), as a ``jax.Array`` does."""
+
+    def __init__(self):
+        self.ready = self.deleted = False
+
+    def is_ready(self):
+        if self.deleted:
+            raise RuntimeError("Array has been deleted")
+        return self.ready
+
+
+def _named(rows, name):
+    return [r for r in rows if r.name == name]
+
+
+class TestDeviceQueue:
+    def test_the_polls_bracket_the_moment_the_queue_ran_dry(self):
+        t = trace_lib.Tracer()
+        with trace_lib.activated(t):
+            a = _Marker()
+            with trace_lib.span("dispatch_a"):
+                trace_lib.dispatched("a", a)
+            with trace_lib.span("busy"):
+                pass
+            a.ready = True
+            with trace_lib.span("ready"):
+                pass
+            with trace_lib.span("later"):
+                pass
+            with trace_lib.span("dispatch_b"):
+                trace_lib.dispatched("b", _Marker())
+            waited = trace_lib.waited_host_us()
+        rows = t.spans()
+        busy, ready, later, b = (_named(rows, n)[0] for n in (
+            "busy", "ready", "later", "dispatch_b"))
+        (wait,) = _named(rows, trace_lib.WAIT_HOST)
+        # the last poll that found it busy is busy's exit, the first that
+        # found it ready is ready's enter; the wait runs to b's span start
+        assert busy.end_us <= wait.args["earliest_us"] <= wait.start_us
+        assert wait.start_us <= ready.start_us < later.start_us
+        assert wait.end_us == b.start_us
+        assert wait.args["dispatched_us"] >= b.start_us
+        assert (wait.args["after"], wait.args["before"]) == ("a", "b")
+        assert waited == pytest.approx(wait.end_us - wait.start_us)
+        assert t.device_queue == {"dispatches": 2, "unknown": 0}
+
+    def test_only_the_newest_dispatch_is_asked(self):
+        """Programs run in dispatch order: an older one that is done says
+        nothing while a newer one is queued behind it."""
+        t = trace_lib.Tracer()
+        with trace_lib.activated(t):
+            a, b = _Marker(), _Marker()
+            with trace_lib.span("dispatch_a"):
+                trace_lib.dispatched("a", a)
+            with trace_lib.span("dispatch_b"):
+                trace_lib.dispatched("b", {"kv": [b]})   # a tree's leaf
+            a.ready = True
+            with trace_lib.span("between"):
+                pass
+            with trace_lib.span("dispatch_c"):
+                trace_lib.dispatched("c", _Marker())     # b still busy
+            assert _named(t.spans(), trace_lib.WAIT_HOST) == []
+            with trace_lib.span("dispatch_d"):
+                trace_lib.dispatched("d", _Marker())     # c never polled
+        assert _named(t.spans(), trace_lib.WAIT_HOST) == []
+        assert t.device_queue == {"dispatches": 4, "unknown": 0}
+
+    def test_a_donated_marker_is_unknown_and_records_no_wait(self):
+        t = trace_lib.Tracer()
+        with trace_lib.activated(t):
+            a = _Marker()
+            with trace_lib.span("dispatch_a"):
+                trace_lib.dispatched("a", a)
+            a.ready = True
+            with trace_lib.span("seen_ready"):
+                pass
+            a.deleted = True        # a dispatch nobody registered donated it
+            with trace_lib.span("dispatch_b"):
+                trace_lib.dispatched("b", b := _Marker())
+            # the registered dispatch's own donation, seen by a poll from
+            # another thread inside its span, is no unknown
+            b.ready = True
+            with trace_lib.span("seen_ready"):
+                pass
+            with trace_lib.span("dispatch_c"):
+                b.deleted = True
+                t._poll()
+                trace_lib.dispatched("c", _Marker())
+        (wait,) = _named(t.spans(), trace_lib.WAIT_HOST)
+        assert (wait.args["after"], wait.args["before"]) == ("b", "c")
+        assert t.device_queue == {"dispatches": 3, "unknown": 1}
+
+    def test_with_no_tracer_a_dispatch_records_and_allocates_nothing(self):
+        import itertools
+        import tracemalloc
+        trace_lib.deactivate()
+        marker = _Marker()
+        assert trace_lib.waited_host_us() is None
+        trace_lib.dispatched("x", marker)
+        calls = itertools.repeat(None, 1000)
+        tracemalloc.start()
+        try:
+            before = tracemalloc.get_traced_memory()[0]
+            tracemalloc.reset_peak()
+            for _ in calls:
+                trace_lib.dispatched("x", marker)
+            peak = tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+        assert peak - before == 0
+
+    def test_collections_are_spans_while_a_tracer_is_active(self):
+        import gc
+        t = trace_lib.Tracer()
+        with trace_lib.activated(t):
+            gc.collect()
+        gcs = _named(t.spans(), trace_lib.GC)
+        assert gcs and gcs[-1].args["generation"] == 2
+        assert gcs[-1].args["collected"] >= 0
+        assert all(r.end_us >= r.start_us and r.parent is None for r in gcs)
+        assert trace_lib._on_gc not in gc.callbacks
+        trace_lib.activate(t)
+        assert trace_lib._on_gc in gc.callbacks
+        trace_lib.deactivate()
+        assert trace_lib._on_gc not in gc.callbacks
+        gc.collect()
+        assert len(_named(t.spans(), trace_lib.GC)) == len(gcs)
+
+
 # --------------------------------------------------------- the serve tick
 
 def test_tick_tree_self_times_sum_and_heartbeat_is_the_span(model_params,
@@ -225,7 +358,10 @@ def test_tick_tree_self_times_sum_and_heartbeat_is_the_span(model_params,
     assert [a["tick"] for a in args] == list(range(1, len(ticks) + 1))
     stats = engine.stats()
     assert sum(a["windows"] for a in args) == stats.prefill_windows_total
-    assert sum(a["admissions"] for a in args) == 3
+    admitted = [r for r in rows if r.name == "serve.admit"
+                and r.args["outcome"] == "ok"]
+    assert len(admitted) == 3 and all(
+        rows[r.parent].name == "serve.tick" for r in admitted)
     assert sum(a["tokens"] for a in args) == 6 + 4 + 5
     # the heartbeat's stamps are the last tick span's two clock reads
     last = rows[ticks[-1]]
@@ -233,6 +369,60 @@ def test_tick_tree_self_times_sum_and_heartbeat_is_the_span(model_params,
         trace_lib.to_perf_counter_s(last.start_us), abs=1e-6)
     assert stats.last_tick_duration_s == pytest.approx(
         (last.end_us - last.start_us) / 1e6, abs=1e-6)
+
+
+def test_every_dispatch_of_an_engine_is_registered(model_params, tracer):
+    """Each program the scheduler dispatches, and each eager freeze of its
+    rows, is registered with the device queue's account, and no marker is
+    found donated by a dispatch that was not; each decode fetch and first
+    token read holds its ``device.ready_wait``, and each tick says what the
+    device waited on the host in it."""
+    import collections
+    engine = _engine(model_params)
+    sched = engine.scheduler
+    calls = collections.Counter()
+
+    def counting(name, fn):
+        def call(*args, **kw):
+            calls[name] += 1
+            return fn(*args, **kw)
+        return call
+
+    sched._win_mid = {r: counting("win_mid", f)
+                      for r, f in sched._win_mid.items()}
+    sched._last_admit = {r: counting("last_admit", f)
+                         for r, f in sched._last_admit.items()}
+    sched._tick = counting("tick", sched._tick)
+    before = dict(tracer.device_queue)
+    mark = len(tracer.spans())
+    handles = [engine.submit(_prompt(14, seed=3), 12),
+               engine.submit(_prompt(5, seed=4), 12)]
+    while not handles[0].tokens:
+        engine.step()
+    engine.cancel(handles[0])          # its row is frozen next tick
+    handles.append(engine.submit(_prompt(9, seed=5), 5))
+    engine.drain()
+    rows = tracer.spans()[mark:]
+    freezes = len(_named(rows, "serve.freeze_rows"))
+    assert calls["win_mid"] and calls["last_admit"] and calls["tick"]
+    assert freezes >= 1
+    assert tracer.device_queue["dispatches"] - before["dispatches"] == \
+        sum(calls.values()) + freezes
+    assert tracer.device_queue["unknown"] == 0
+    waits = _named(rows, trace_lib.WAIT_HOST)
+    assert waits and all(
+        w.args["earliest_us"] <= w.start_us <= w.end_us
+        <= w.args["dispatched_us"] for w in waits)
+    by_index = {id(r): i for i, r in enumerate(rows)}
+    for fetch in _named(rows, "serve.decode_fetch") + _named(
+            rows, "serve.first_token_read"):
+        kids = [r for r in rows if r.parent is not None
+                and r.parent - mark == by_index[id(fetch)]]
+        assert [k.name for k in kids] == [trace_lib.READY_WAIT]
+    ticks = _named(rows, "serve.tick")
+    assert sum(t.args["waited_host_us"] for t in ticks) == pytest.approx(
+        sum(w.end_us - w.start_us for w in waits
+            if ticks[0].start_us <= w.end_us <= ticks[-1].end_us))
 
 
 def test_counters_and_request_counts_repeat_exactly(model_params, tracer):
@@ -501,7 +691,8 @@ def test_train_step_spans_feed_goodput_and_the_save_histogram(tmp_path):
 
 def test_state_snapshot_spans_and_counters_of_a_recurrent_state_model(tracer):
     """A model with recurrent state beside K/V: every snapshot and restore
-    is a span inside the tick with slot, depth and bytes; the admit span
+    is a span inside the tick with its depth (and a snapshot its site); the
+    admit span
     says whether the turn resumed; the decode spans carry what a byte count
     of the step is made from; the counters agree with the spans and render
     in the registry.  With no tracer the same calls are null spans."""
@@ -535,11 +726,13 @@ def test_state_snapshot_spans_and_counters_of_a_recurrent_state_model(tracer):
     assert [s.args["depth"] for s in snaps] == [45, 50, 60, 63]
     assert len(restores) == 1 and restores[0].args["depth"] == 50
     for s in snaps + restores:
-        assert s.args["bytes"] == state_bytes and s.args["slot"] in (0, 1)
+        assert set(s.args) <= {"trace_id", "depth", "at"}
         assert spans[s.parent].name in ("serve.register", "serve.deliver",
                                         "serve.admit")
     assert [s.args["resumed"] for s in by_name["serve.admit"]] == \
         [False, True]
+    # the row copies are registered with the device queue's account
+    assert tracer.device_queue["unknown"] == 0
     for dispatch, fetch in zip(by_name["serve.decode_dispatch"],
                                by_name["serve.decode_fetch"]):
         assert dispatch.args["cached_tokens"] >= 45
